@@ -57,7 +57,7 @@ def main() -> None:
         return lax.all_to_all(x, AXIS, 0, 0, tiled=True)
 
     fn = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             lambda x: step(x), mesh=mesh, in_specs=P(AXIS), out_specs=P(AXIS)
         )
     )

@@ -32,10 +32,10 @@ Modes:
      test mode) must come back as a typed ``integrity`` error while the
      next clean request succeeds: per-request isolation.
 
-* ``--row`` (bench.py's serve row): spawn, warm, measure the batched
-  phase, emit ONE JSON bench row on stdout (p50/p99 + Mkeys/s) — the
-  regression-gated sort-as-a-service headline beside the 1-chip and
-  8-device rows.
+* ``--row``: spawn, warm, measure the batched phase, emit ONE JSON
+  bench row on stdout (p50/p99 + Mkeys/s).  bench.py's serve row calls
+  :func:`row` in its own process with :class:`InProcServer` instead —
+  it already holds the chip, which a spawned server could not get.
 
 The spawned server writes ``SORT_TRACE`` JSONL; ``python -m
 mpitest_tpu.report`` renders the p50/p99 SLO table from exactly that
@@ -96,7 +96,79 @@ def log(msg: str) -> None:
 
 # ------------------------------------------------------------- server mgmt
 
-class Server:
+class _ServerBase:
+    """What the load phases need of a server: its ports, its span
+    trace, a /metrics scrape, and stop()."""
+
+    trace: Path
+    port: int
+    metrics_port: "int | None"
+
+    def scrape_metrics(self) -> str:
+        """One /metrics scrape (Prometheus text)."""
+        import urllib.request
+
+        assert self.metrics_port is not None, "metrics port disabled"
+        with urllib.request.urlopen(
+                f"http://{HOST}:{self.metrics_port}/metrics",
+                timeout=30) as r:
+            return r.read().decode("utf-8")
+
+    def trace_cut(self) -> int:
+        """Current trace line count — the warm-window marker."""
+        try:
+            return len(self.trace.read_text().splitlines())
+        except FileNotFoundError:
+            return 0
+
+    def spans_after(self, cut: int) -> list[dict]:
+        rows = []
+        for ln in self.trace.read_text().splitlines()[cut:]:
+            try:
+                rows.append(json.loads(ln))
+            except json.JSONDecodeError:
+                pass
+        return rows
+
+
+class InProcServer(_ServerBase):
+    """The server core in THIS process, on threads — for a caller that
+    already holds the chip (bench.py's serve row): a child process
+    could not get the device."""
+
+    def __init__(self, out_dir: Path, tag: str,
+                 env_overrides: dict | None = None) -> None:
+        from mpitest_tpu.serve.server import ServerCore, SortServer
+        from mpitest_tpu.serve.telemetry import TelemetryServer
+
+        self.trace = out_dir / f"server_trace_{tag}.jsonl"
+        with knobs.scoped_env(SORT_TRACE=str(self.trace),
+                              **(env_overrides or {})):
+            self.core = ServerCore()
+        self.core.prewarm(lambda m: log(f"  server[{tag}]| {m}"))
+        self.core.start_watchdog()
+        self.server = SortServer(self.core, HOST, 0)
+        self.port = self.server.bound_port
+        self.telemetry = TelemetryServer(self.core, HOST, 0)
+        self.telemetry.start()
+        self.metrics_port = self.telemetry.bound_port
+        self._accept = threading.Thread(target=self.server.serve_forever,
+                                        name="serve-accept", daemon=True)
+        self._accept.start()
+        log(f"server[{tag}] up in-process on :{self.port} "
+            f"(metrics :{self.metrics_port})")
+
+    def stop(self) -> int:
+        drained = self.core.drain_and_stop(timeout=60.0)
+        self.server.shutdown()
+        self.server.server_close()
+        self.telemetry.shutdown()
+        self.telemetry.server_close()
+        self._accept.join(timeout=10.0)
+        return 0 if drained else 1
+
+
+class Server(_ServerBase):
     """One spawned sort_server subprocess (ephemeral port, own trace)."""
 
     #: Startup budget: jax import + prewarm compiles on a loaded
@@ -154,16 +226,6 @@ class Server:
             + (f" (metrics :{self.metrics_port})"
                if self.metrics_port else ""))
 
-    def scrape_metrics(self) -> str:
-        """One /metrics scrape (Prometheus text)."""
-        import urllib.request
-
-        assert self.metrics_port is not None, "metrics port disabled"
-        with urllib.request.urlopen(
-                f"http://{HOST}:{self.metrics_port}/metrics",
-                timeout=30) as r:
-            return r.read().decode("utf-8")
-
     def _await_listening_line(self) -> str:
         """Bounded wait for ONE sync line: select() + os.read on the
         raw fd with our own line buffer.  Two sync lines are read back
@@ -212,22 +274,6 @@ class Server:
         for ln in self._stderr_tail().strip().splitlines()[-3:]:
             log(f"  server| {ln}")
         return rc
-
-    def trace_cut(self) -> int:
-        """Current trace line count — the warm-window marker."""
-        try:
-            return len(self.trace.read_text().splitlines())
-        except FileNotFoundError:
-            return 0
-
-    def spans_after(self, cut: int) -> list[dict]:
-        rows = []
-        for ln in self.trace.read_text().splitlines()[cut:]:
-            try:
-                rows.append(json.loads(ln))
-            except json.JSONDecodeError:
-                pass
-        return rows
 
 
 # ------------------------------------------------------------ load driving
@@ -387,9 +433,11 @@ def phase_stats(name: str, st: dict) -> None:
 
 def measure_phase(out: Path, tag: str, window_ms: str, requests: int,
                   concurrency: int, seed: int,
+                  server_cls: type = Server,
                   ) -> tuple[dict, list[dict], int]:
-    """Spawn a server at the given batch window, warm it, run the
-    measured phase; returns (stats, measured-window spans, server rc).
+    """Start a server (a child process, or ``InProcServer``) at the
+    given batch window, warm it, run the measured phase; returns
+    (stats, measured-window spans, server rc).
     The default ``SORT_SERVE_SHAPE_BUCKETS`` prewarm covers every
     bucket the packed path can request, so the warm-cache gate holds
     with a default-config server.
@@ -399,7 +447,7 @@ def measure_phase(out: Path, tag: str, window_ms: str, requests: int,
     reconciled against the client's own accounting over BOTH phases
     (warmup + measured) — failures land in ``stats["metrics_errors"]``
     and fail the selftest leg."""
-    srv = Server(out, tag, {
+    srv = server_cls(out, tag, {
         "SORT_SERVE_BATCH_WINDOW_MS": window_ms,
     })
     try:
@@ -810,9 +858,19 @@ def main() -> int:
     if args.selftest:
         return selftest(out, args.requests, args.concurrency, args.seed)
     # --row (and the bare default): batched measurement + row
+    return row(out, args.requests, args.concurrency, args.seed,
+               chaos=args.chaos, planner=args.planner)
+
+
+def row(out: Path, requests: int = 160, concurrency: int = 8,
+        seed: int = 0, chaos: bool = False, planner: bool = False,
+        server_cls: type = Server) -> int:
+    """Measure the batched phase and print ONE JSON bench row; 0 on
+    success.  bench.py calls this in its own process with
+    ``server_cls=InProcServer``."""
     stats, spans, rc = measure_phase(out, "batched", BATCH_WINDOW_MS,
-                                     args.requests, args.concurrency,
-                                     args.seed)
+                                     requests, concurrency, seed,
+                                     server_cls)
     if rc != 0:
         log(f"server exited rc={rc}")
         return 1
@@ -824,17 +882,16 @@ def main() -> int:
         for e in stats["metrics_errors"]:
             log(f"[FAIL] {e}")
         return 1
-    extra = {"concurrency": args.concurrency,
+    extra = {"concurrency": concurrency,
              "dispatch_mkeys_per_s":
              round(dispatch_mkeys_per_s(spans), 3),
              # ISSUE 14: the planner column (the measured phase runs
              # whatever the spawn env set — off unless overridden)
              "planner": str(knobs.get("SORT_PLANNER"))}
-    if args.chaos:
-        extra.update(chaos_phase(out, args.seed))
-    if args.planner:
-        extra.update(planner_phase(out, args.requests,
-                                   args.concurrency, args.seed))
+    if chaos:
+        extra.update(chaos_phase(out, seed))
+    if planner:
+        extra.update(planner_phase(out, requests, concurrency, seed))
     emit_row(stats, extra)
     record_metrics(stats, None)
     return 0

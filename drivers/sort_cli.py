@@ -424,4 +424,9 @@ def _external_main(path: str, dtype, algo: str, mem_budget: int,
 
 
 if __name__ == "__main__":
+    # the persistent compile cache is a process-level choice: set by the
+    # entry point, never by main() (in-process callers keep their own)
+    from mpitest_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

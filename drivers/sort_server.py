@@ -246,4 +246,9 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    # the persistent compile cache is a process-level choice: set by the
+    # entry point, never by main() (in-process callers keep their own)
+    from mpitest_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -36,7 +36,7 @@ both algorithms):
                     fingerprint folded it (streamed ingest only)
 ``dispatch_stall``  block the dispatch thread for ``SORT_FAULT_STALL_MS``
                     before launching (the serving watchdog's drill:
-                    models the TPU-compiler tunnel hang)
+                    models a hung device call)
 ``result_swap``     swap the first/last keys of the sorted result
                     (breaks sortedness — caught by the order check)
 ``result_dup``      overwrite key[1] with key[0] (stays sorted — caught

@@ -23,7 +23,7 @@ without teaching the SPMD kernels anything about payloads:
 3. **1-word fusion** — for 1-word codecs (int32/uint32/float32, the
    common case) the ``(key, idx)`` pair fuses into ONE uint64
    ``(key << 32) | idx`` single-key sort, lowered under a scoped
-   ``compat.enable_x64`` exactly like the segmented (seg,key) fusion
+   ``jax.enable_x64`` exactly like the segmented (seg,key) fusion
    (XLA:CPU's multi-operand comparator sort measured 2-4x slower than
    the single-key form); inputs and outputs stay uint32.
 
@@ -148,8 +148,6 @@ def _compile_record_sort(n_key_words: int, n_payload_words: int,
     import jax.numpy as jnp
     from jax import lax
 
-    from mpitest_tpu import compat
-
     specs = tuple(jax.ShapeDtypeStruct((n,), jnp.uint32)
                   for _ in range(n_key_words + n_payload_words))
 
@@ -167,7 +165,7 @@ def _compile_record_sort(n_key_words: int, n_payload_words: int,
             return ((s >> np.uint64(32)).astype(jnp.uint32),), \
                 gather(perm, payload), perm
 
-        with compat.enable_x64(True):
+        with jax.enable_x64(True):
             return jax.jit(f1).lower(*specs).compile()
 
     def f(*arrs: Any) -> Any:

@@ -152,7 +152,6 @@ def compile_packed_sort(n_words_total: int,
     import jax.numpy as jnp
     from jax import lax
 
-    from mpitest_tpu import compat
     from mpitest_tpu.ops import kernels
 
     specs = tuple(jax.ShapeDtypeStruct((bucket,), jnp.uint32)
@@ -165,7 +164,7 @@ def compile_packed_sort(n_words_total: int,
             return ((s >> np.uint64(32)).astype(jnp.uint32),
                     s.astype(jnp.uint32))
 
-        with compat.enable_x64(True):
+        with jax.enable_x64(True):
             return jax.jit(f2).lower(*specs).compile()
 
     def f(*words: Any) -> Any:

@@ -40,7 +40,7 @@ Three pieces:
   scatter histogram would need the per-element cross-tile addressing
   the VPU lacks (see ``ops/pallas_kernels.py`` module docstring).
 
-Interpret-mode contract (this image: CPU-only, jax 0.4.37): the Pallas
+Interpret-mode contract (the CPU test mesh): the Pallas
 interpreter cannot simulate a cross-device DMA (``make_async_remote_copy``
 rejects traced ``device_id`` outside a real TPU lowering), so
 ``interpret=True`` routes the transport through ``lax.all_to_all`` —
@@ -64,7 +64,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpitest_tpu import compat
 from mpitest_tpu.ops.pallas_kernels import (
     CHUNK, LANES, ROWS, chunk_geometry)
 
@@ -160,8 +159,9 @@ def fused_pass_pack(
         functools.partial(_fused_pack_kernel, n, fills, n_arrays),
         grid_spec=grid_spec,
         out_shape=tuple(
-            compat.shape_dtype_struct(
-                (n_ranks, cap // CHUNK, ROWS, LANES), a.dtype, vma=vma,
+            jax.ShapeDtypeStruct(
+                (n_ranks, cap // CHUNK, ROWS, LANES), a.dtype,
+                vma=frozenset(vma),
             )
             for a in arrays
         ),
@@ -192,7 +192,7 @@ def _remote_a2a_kernel(n_ranks: int, axis: str, x_ref, out_ref,
     for k in range(1, n_ranks):
         peer = (me + k) % n_ranks
         pltpu.semaphore_signal(
-            barrier, inc=1, device_id=(peer,),
+            barrier, inc=1, device_id=peer,
             device_id_type=pltpu.DeviceIdType.LOGICAL)
     pltpu.semaphore_wait(barrier, n_ranks - 1)
 
@@ -210,7 +210,7 @@ def _remote_a2a_kernel(n_ranks: int, axis: str, x_ref, out_ref,
             dst_ref=out_ref.at[me],
             send_sem=send_sems.at[k],
             recv_sem=recv_sems.at[k],
-            device_id=(dst,),
+            device_id=dst,
             device_id_type=pltpu.DeviceIdType.LOGICAL,
         )
         rc.start()
@@ -245,14 +245,14 @@ def remote_a2a(
         functools.partial(_remote_a2a_kernel, n_ranks, axis),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=compat.shape_dtype_struct(
-            (n_ranks, cap // LANES, LANES), x.dtype, vma=(axis,)),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_ranks, cap // LANES, LANES), x.dtype, vma=frozenset((axis,))),
         scratch_shapes=[
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA((n_ranks,)),
             pltpu.SemaphoreType.DMA((n_ranks,)),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=_A2A_COLLECTIVE_ID),
         # never interpreted: interpret=True returned above via the
         # bit-identical lax transport — the interpreter cannot simulate

@@ -39,7 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpitest_tpu import compat
 
 LANES = 128
 ROWS = 8                    # (8, 128) = one int32 tile
@@ -139,8 +138,9 @@ def segment_pack(
     out = pl.pallas_call(
         functools.partial(_pack_kernel, n, fill),
         grid_spec=grid_spec,
-        out_shape=compat.shape_dtype_struct(
-            (n_ranks, cap // CHUNK, ROWS, LANES), data.dtype, vma=vma,
+        out_shape=jax.ShapeDtypeStruct(
+            (n_ranks, cap // CHUNK, ROWS, LANES), data.dtype,
+            vma=frozenset(vma),
         ),
         interpret=interpret,
     )(starts.astype(jnp.int32), cnts.astype(jnp.int32), data_2d)

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mpitest_tpu import compat, faults
+from mpitest_tpu import faults
 from mpitest_tpu.parallel.mesh import AXIS
 from mpitest_tpu.utils import spans
 
@@ -43,11 +43,10 @@ def _emit_collective(name: str, x: jax.Array, axis: str,
     if log is None:
         return
     b_in = int(x.size) * x.dtype.itemsize
-    P_ = compat.axis_size(axis)
-    if P_ is not None:
-        attrs.setdefault("ranks", P_)
-        if name == "all_gather":
-            attrs.setdefault("bytes_out", b_in * P_)
+    P_ = int(lax.axis_size(axis))
+    attrs.setdefault("ranks", P_)
+    if name == "all_gather":
+        attrs.setdefault("bytes_out", b_in * P_)
     # sortlint: disable=SL003 -- name is each wrapper's literal (all registered)
     log.event(name, bytes=b_in, axis=axis, **attrs)
 

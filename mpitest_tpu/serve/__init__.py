@@ -8,9 +8,8 @@ requests.  This package is the layer that amortizes all of it:
 * :mod:`~mpitest_tpu.serve.executor_cache` — AOT-compiles and memoizes
   executables per (shape-bucket, dtype, word-count, mesh) with
   power-of-two shape bucketing, so warm requests never touch the
-  compiler; startup prewarm degrades to jit-on-first-use behind the
-  bounded topology probe (``utils/topology_probe.py``) instead of
-  wedging on a tunnel-less TPU image.
+  compiler; startup prewarm compiles the configured buckets in the
+  server's own process.
 * :mod:`~mpitest_tpu.serve.admission` — bounds in-flight requests and
   payload bytes; over-limit requests get a TYPED backpressure
   rejection, never a queue that grows until the process dies.

@@ -861,6 +861,10 @@ register("FIX_PARTS", "csv", ("uniform", "runs16", "exact"),
 register("XLA_FLAGS", "str", "", "XLA flag string (pass-through)",
          "Extra XLA flags; utils/platform.py appends the device-count flag.",
          _passthrough)
+register("JAX_COMPILATION_CACHE_DIR", "str", "", "a directory path",
+         "JAX's own persistent compile-cache directory; when unset, "
+         "utils/platform.py enable_compile_cache uses <repo>/.jax_cache.",
+         _passthrough)
 register("COMM_RANKS", "int", None, "a positive integer",
          "Rank count for the native pthreads (local) comm backend.",
          _passthrough, consumer="native")

@@ -18,7 +18,6 @@ from jax.sharding import PartitionSpec as P
 
 from mpitest_tpu.parallel import collectives as coll
 from mpitest_tpu.parallel.mesh import AXIS
-from mpitest_tpu import compat
 
 P_ = 8  # mesh8 fixture (conftest.py) provides the 8-device virtual mesh
 
@@ -26,7 +25,7 @@ P_ = 8  # mesh8 fixture (conftest.py) provides the 8-device virtual mesh
 def spmd(mesh, f, in_specs, out_specs, check_vma=True):
     # pallas_call internals mix varying/unvarying operands in ways the
     # vma checker rejects (same exemption as models/api.py's compiles)
-    return jax.jit(compat.shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=check_vma))
 
 

@@ -8,7 +8,6 @@ import pytest
 from mpitest_tpu.models.api import sort
 from mpitest_tpu.ops import kernels
 from mpitest_tpu.parallel.mesh import make_mesh
-from mpitest_tpu import compat
 
 
 def test_piecewise_fill_basic():
@@ -65,7 +64,7 @@ def test_device_resident_64bit_input(algo, dtype, mesh8, rng):
     info = np.iinfo(np.dtype(dtype))
     x = rng.integers(info.min, info.max, size=8 * 256 + 5, dtype=dtype,
                      endpoint=True)
-    with compat.enable_x64(True):
+    with jax.enable_x64(True):
         x_dev = jnp.asarray(x)
         assert x_dev.dtype == np.dtype(dtype)
         got = sort(x_dev, algorithm=algo, mesh=mesh8)
@@ -100,7 +99,7 @@ def test_device_resident_float64_host_fallback(n_mesh, rng, monkeypatch):
     monkeypatch.setattr(api, "_compile_encode_pad", boom)
     monkeypatch.setattr(api, "_compile_local_device", boom)
     x = (rng.standard_normal(8 * 200 + 3) * 1e9).astype(np.float64)
-    with compat.enable_x64(True):
+    with jax.enable_x64(True):
         x_dev = jnp.asarray(x)
         tracer = Tracer()
         got = sort(x_dev, algorithm="radix", mesh=make_mesh(n_mesh),
@@ -137,7 +136,7 @@ def test_device_resident_float64_host_fallback(n_mesh, rng, monkeypatch):
 
         monkeypatch.setattr(api, "_compile_encode_pad", other)
         monkeypatch.setattr(api, "_compile_local_device", other)
-        with compat.enable_x64(True):
+        with jax.enable_x64(True):
             with pytest.raises(jax.errors.JaxRuntimeError,
                                match=msg.split()[0].split(":")[0]):
                 sort(jnp.asarray(x), algorithm="radix", mesh=make_mesh(n_mesh))

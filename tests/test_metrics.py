@@ -113,6 +113,7 @@ def test_bench_driver_contract(tmp_path):
         BENCH_LOG2N="14",
         BENCH_REPEATS="1",
         BENCH_NATIVE_RANKS="0",
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
     )
     r = subprocess.run(
         [sys.executable, str(repo / "bench.py")],

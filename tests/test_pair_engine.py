@@ -15,7 +15,6 @@ import pytest
 from mpitest_tpu.models.api import sort
 from mpitest_tpu.parallel.mesh import make_mesh
 from mpitest_tpu.utils.trace import Tracer
-from mpitest_tpu import compat
 
 N = 15_000  # > MIN_SORT_LOG2 and past the pad break-even (pow2 = 16384)
 
@@ -177,7 +176,7 @@ def test_device_resident_pair_engine(mesh1, rng, monkeypatch):
 
     monkeypatch.setenv("SORT_LOCAL_ENGINE", "bitonic")
     x = rng.integers(-(2**62), 2**62, size=N, dtype=np.int64)
-    with compat.enable_x64(True):
+    with jax.enable_x64(True):
         dev = jax.device_put(x, jax.devices()[0])
         tracer = Tracer()
         got = sort(dev, algorithm="radix", mesh=mesh1, tracer=tracer)

@@ -143,7 +143,6 @@ def test_remote_a2a_interpret_contract(mesh8):
     recv[s] = row-sent-by-s contract on the virtual mesh."""
     from jax.sharding import PartitionSpec as P
 
-    from mpitest_tpu import compat
     from mpitest_tpu.parallel.mesh import AXIS
 
     n_ranks, cap = 8, 1024
@@ -153,7 +152,7 @@ def test_remote_a2a_interpret_contract(mesh8):
     def f(block):
         return xeng.remote_a2a(block, n_ranks, AXIS, interpret=True)
 
-    out = jax.jit(compat.shard_map(
+    out = jax.jit(jax.shard_map(
         f, mesh=mesh8, in_specs=P(AXIS), out_specs=P(AXIS),
         check_vma=False))(x)
     got = np.asarray(out).reshape(n_ranks, n_ranks, cap)

@@ -254,29 +254,6 @@ def test_serve_knob_validation():
         assert knobs.get("SORT_SERVE_SHAPE_BUCKETS") == (10, 14)
 
 
-# ------------------------------------------------------- topology probe
-
-def test_topology_probe_bounded_and_cached(monkeypatch):
-    import subprocess as sp
-
-    from mpitest_tpu.utils import topology_probe as tp
-
-    tp.reset_cache()
-    calls = []
-
-    def fake_run(*a, **kw):
-        calls.append(1)
-        raise sp.TimeoutExpired(cmd="probe", timeout=kw.get("timeout"))
-
-    monkeypatch.setattr(tp.subprocess, "run", fake_run)
-    reason = tp.probe_tpu_compiler(timeout_s=1.0)
-    assert "timed out" in reason
-    # the verdict is cached: no second child process
-    assert tp.probe_tpu_compiler() == reason
-    assert len(calls) == 1
-    tp.reset_cache()
-
-
 # ------------------------------------------- wire + SIGTERM drain drill
 
 def test_server_driver_wire_and_sigterm_drain(tmp_path):

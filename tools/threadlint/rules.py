@@ -211,7 +211,7 @@ def _tl005(program: Program, registry: Registry) -> list:
                 "TL005", fi.path, t.line,
                 f"GIL-wedge call `{t.label}` outside the bounded-"
                 "subprocess probe (can block forever holding the GIL; "
-                "route through topology_probe)"))
+                "run it in a killable child process)"))
     return sorted(out, key=lambda f: (f.path, f.line))
 
 

@@ -29,6 +29,7 @@ recompile.  This replaces the reference's silent bucket overflow
 
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass
 from functools import lru_cache
@@ -65,32 +66,12 @@ from mpitest_tpu.ops.keys import KeyCodec, codec_for
 from mpitest_tpu.parallel.mesh import AXIS, key_sharding, make_mesh
 from mpitest_tpu.utils import io as kio
 from mpitest_tpu.utils import knobs
+from mpitest_tpu.utils.programs import layer, program, traced_call
 from mpitest_tpu.utils.trace import Tracer
 
 
-#: jit callables that have executed at least once — the compile-vs-
-#: execute split of the span layer: a callable's FIRST invocation pays
-#: tracing + XLA compile and is recorded as ``jit_compile_execute``;
-#: warm calls are ``jit_execute``.  Keyed by id(); the lru_caches above
-#: keep the callables alive, so collisions need an eviction first (and
-#: cost only a mislabeled span, never a wrong result).
-_warm_jits: set[int] = set()
-
-
-def _traced_call(tracer: Tracer, label: str, fn: Callable[..., Any],
-                 *args: Any, **attrs: object) -> Any:
-    """Call a jit program under a span that separates first-call (compile
-    included) from warm-call wall time — the split ISSUE/SURVEY §5 needs
-    to attribute 'slow run' to compile vs execute."""
-    first = id(fn) not in _warm_jits
-    name = "jit_compile_execute" if first else "jit_execute"
-    # sortlint: disable=SL003 -- both branches above are registered schema names
-    with tracer.spans.span(name, label=label, **attrs):
-        out = fn(*args)
-    if first:
-        _warm_jits.add(id(fn))
-        tracer.count("jit_first_calls", 1)
-    return out
+def _no_span(name: str, **attrs: object) -> Any:
+    return contextlib.nullcontext()
 
 
 @dataclass
@@ -104,9 +85,16 @@ class DistributedSortResult:
     shard_slots: int | None = None    # slots per shard for ragged layouts
 
     def to_numpy(self, tracer: "Tracer | None" = None) -> np.ndarray:
+        """The sorted keys on the host.  With a tracer, the device→host
+        copy and the decode record ``egress.fetch`` / ``egress.decode``
+        (the streamed path records its own, per shard)."""
         if self.n_valid == 0:
             return np.empty(0, self.dtype)
         codec = codec_for(self.dtype)
+
+        stage = tracer.span if tracer is not None else _no_span
+        fetched = sum(int(w.nbytes) for w in self.words)
+
         if self.counts is None:
             # Streamed egress (models/ingest.py): decode shard k while
             # shard k+1 is still fetching — on by default above the
@@ -128,9 +116,12 @@ class DistributedSortResult:
             ):
                 return stream_result_to_numpy(
                     self.words, self.n_valid, self.dtype, tracer=tracer)
+            with stage("egress.fetch", bytes=fetched):
+                host = tuple(np.asarray(w) for w in self.words)
+            with stage("egress.decode", n=self.n_valid):
+                return codec.decode(tuple(w[: self.n_valid] for w in host))
+        with stage("egress.fetch", bytes=fetched):
             host = tuple(np.asarray(w) for w in self.words)
-            return codec.decode(tuple(w[: self.n_valid] for w in host))
-        host = tuple(np.asarray(w) for w in self.words)
         # ragged: concatenate the valid prefix of each shard's slot range,
         # then drop the padding sentinels (global max ⇒ they sit at the tail)
         parts = []
@@ -140,7 +131,8 @@ class DistributedSortResult:
                 for i, c in enumerate(self.counts)
             ]
             parts.append((np.concatenate(segs) if segs else w[:0])[: self.n_valid])
-        return codec.decode(tuple(parts))
+        with stage("egress.decode", n=self.n_valid):
+            return codec.decode(tuple(parts))
 
     def median_probe_raw(self) -> Any:
         """The (n/2)-th sorted element as a native-dtype scalar (exact
@@ -271,7 +263,7 @@ def _compile_word_range(dtype_name: str) -> Callable[..., Any]:
         words = codec.encode_jax(x)
         return tuple((jnp.min(w), jnp.max(w)) for w in words)
 
-    return jax.jit(f)
+    return jax.jit(program("word_range", "ingest", f))
 
 
 #: Memo: platforms whose device-side float64 encode failed to lower
@@ -360,7 +352,7 @@ def _compile_pair_sort(impl: str) -> Callable[..., Any]:
     def f(hi, lo):
         return kernels.sort_two_words_bitonic(hi, lo, interpret=interpret)
 
-    return jax.jit(f)
+    return jax.jit(program("pair_sort", "local_sort", f))
 
 
 #: Engine codes returned by the fused device program (scalar, one fetch).
@@ -394,7 +386,8 @@ def _compile_pair_fused(dtype_name: str,
         return kernels.local_sort((w,), engine=impl)[0]
 
     def f(x):
-        hi, lo = codec.encode_jax(x.reshape(-1))
+        with layer("ingest"):
+            hi, lo = codec.encode_jax(x.reshape(-1))
         d0 = jnp.min(hi) ^ jnp.max(hi)
         d1 = jnp.min(lo) ^ jnp.max(lo)
         n = hi.shape[0]
@@ -439,7 +432,7 @@ def _compile_pair_fused(dtype_name: str,
             lambda a, b: jlax.cond(d1 == jnp.uint32(0), b_loc, b_var, a, b),
             hi, lo)
 
-    return jax.jit(f)
+    return jax.jit(program("pair_fused", "local_sort", f))
 
 
 def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
@@ -472,8 +465,8 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
         # fused dispatch (see _compile_pair_fused) — host-side branching
         # would cost a host round trip per decision.
         try:
-            with tracer.phase("sort"):
-                hi_s, lo_s, code = _traced_call(
+            with tracer.span("phase:sort"):
+                hi_s, lo_s, code = traced_call(
                     tracer, "pair_fused", _compile_pair_fused(dtype.name, impl), x)
                 code = int(code)
         except jax.errors.JaxRuntimeError as e:
@@ -491,7 +484,7 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
                 tracer.count("pair_residual_fallback", 1)
             return (hi_s, lo_s)
     if not is_device:
-        with tracer.phase("encode"):
+        with tracer.span("phase:encode"):
             # caller may have encoded already (the verification
             # fingerprint needs the words too — don't pay O(n) twice)
             if words_np is None:
@@ -499,7 +492,7 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
             rng = np.array([words_np[0].min(), words_np[0].max(),
                             words_np[1].min(), words_np[1].max()])
             dup = _host_hi_dup_sniff(words_np[0])
-        with tracer.phase("device_put"):
+        with tracer.span("phase:device_put"):
             dev = mesh.devices.flat[0]
             words = tuple(checked_device_put(w, dev) for w in words_np)
     diffs = (int(rng[0]) ^ int(rng[1]), int(rng[2]) ^ int(rng[3]))
@@ -510,29 +503,29 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
         if diffs[const_w] == 0:
             # the constant word never moves; 1-word engine on the other
             tracer.counters["local_engine"] = f"bitonic_1w{sort_w}"
-            with tracer.phase("sort"):
-                s_out = _traced_call(
+            with tracer.span("phase:sort"):
+                s_out = traced_call(
                     tracer, "local_1w", _compile_local(1, engine), words[sort_w])[0]
             return (words[0], s_out) if sort_w == 1 else (s_out, words[1])
     if dup:
         tracer.counters["local_engine"] = "lax"
         tracer.count("pair_dup_reroute", 1)
-        with tracer.phase("sort"):
-            return _traced_call(tracer, "local_2w_lax",
-                                _compile_local(2, "lax"), *words)
+        with tracer.span("phase:sort"):
+            return traced_call(tracer, "local_2w_lax",
+                               _compile_local(2, "lax"), *words)
     tracer.counters["local_engine"] = "bitonic_pair"
-    with tracer.phase("sort"):
-        hi_s, lo_s, bad = _traced_call(tracer, "pair_sort",
-                                       _compile_pair_sort(impl), *words)
+    with tracer.span("phase:sort"):
+        hi_s, lo_s, bad = traced_call(tracer, "pair_sort",
+                                      _compile_pair_sort(impl), *words)
         bad = bool(bad)
     if bad:
         tracer.verbose(
             "pair engine left residual runs (hi duplication the sniff "
             "missed); falling back to lax.sort")
         tracer.count("pair_residual_fallback", 1)
-        with tracer.phase("sort"):
-            return _traced_call(tracer, "local_2w_lax",
-                                _compile_local(2, "lax"), *words)
+        with tracer.span("phase:sort"):
+            return traced_call(tracer, "local_2w_lax",
+                               _compile_local(2, "lax"), *words)
     return (hi_s, lo_s)
 
 
@@ -601,11 +594,12 @@ def _compile_local_device(dtype_name: str,
     codec = codec_for(np.dtype(dtype_name))
 
     def f(x):
-        words = codec.encode_jax(x)
+        with layer("ingest"):
+            words = codec.encode_jax(x)
         eng = _resolve_local_engine(engine, len(words), x.size)
         return kernels.local_sort(words, engine=eng)
 
-    return jax.jit(f)
+    return jax.jit(program("local_device_sort", "local_sort", f))
 
 
 @lru_cache(maxsize=16)
@@ -639,9 +633,10 @@ def _compile_encode_pad(dtype_name: str, total: int,
             )
         return words
 
+    body = program("encode_pad", "ingest", f)
     if mesh is None:
-        return jax.jit(f)
-    return jax.jit(f, out_shardings=key_sharding(mesh))
+        return jax.jit(body)
+    return jax.jit(body, out_shardings=key_sharding(mesh))
 
 
 @lru_cache(maxsize=16)
@@ -667,7 +662,7 @@ def _compile_local(n_words: int,
             diffs = tuple((1 << w) - 1 for w in widths)
         return kernels.local_sort(words, engine=eng, diffs=diffs)
 
-    return jax.jit(f)
+    return jax.jit(program("local_sort", "local_sort", f))
 
 
 @lru_cache(maxsize=64)
@@ -690,7 +685,7 @@ def _compile_radix(mesh: Mesh, n_words: int, n: int, digit_bits: int,
 
     return jax.jit(
         jax.shard_map(
-            f,
+            program("radix_sort", "exchange", f),
             mesh=mesh,
             in_specs=(P(AXIS),) * n_words,
             out_specs=((P(AXIS),) * n_words, P()),
@@ -730,7 +725,7 @@ def _compile_sample(mesh: Mesh, n_words: int, n: int, cap: int,
 
     return jax.jit(
         jax.shard_map(
-            f,
+            program("sample_sort", "exchange", f),
             mesh=mesh,
             in_specs=(P(AXIS),) * n_words,
             out_specs=((P(AXIS),) * n_words, P(AXIS), P()),
@@ -758,7 +753,8 @@ def _compile_radix_probe(mesh: Mesh, n_words: int, n: int,
 
     return jax.jit(
         jax.shard_map(
-            f, mesh=mesh, in_specs=(P(AXIS),) * n_words, out_specs=P(),
+            program("radix_probe", "exchange", f), mesh=mesh,
+            in_specs=(P(AXIS),) * n_words, out_specs=P(),
             # the [P, P] matrix is replicated by construction (it comes
             # out of an all_gather) but the vma checker cannot prove it
             check_vma=False,
@@ -777,7 +773,8 @@ def _compile_sample_probe(mesh: Mesh, n_words: int, n: int,
 
     return jax.jit(
         jax.shard_map(
-            f, mesh=mesh, in_specs=(P(AXIS),) * n_words, out_specs=P(),
+            program("sample_probe", "exchange", f), mesh=mesh,
+            in_specs=(P(AXIS),) * n_words, out_specs=P(),
             check_vma=False,  # see _compile_radix_probe
         )
     )
@@ -801,7 +798,8 @@ def _compile_interleave(mesh: Mesh, n_words: int,
     def f(*words: jax.Array) -> tuple[jax.Array, ...]:
         return tuple(w.reshape(n, P_).T.reshape(-1) for w in words)
 
-    return jax.jit(f, out_shardings=key_sharding(mesh))
+    return jax.jit(program("interleave", "exchange", f),
+                   out_shardings=key_sharding(mesh))
 
 
 #: Safety margin on the sample probe's ESTIMATED per-peer counts (its
@@ -911,7 +909,7 @@ def _compile_skew_sniff(mesh: Mesh, n_words: int, n_valid: int,
             eq &= q[:-1] == q[1:]
         return jnp.any(eq)
 
-    return jax.jit(f)
+    return jax.jit(program("skew_sniff", "exchange", f))
 
 
 def _host_pad_words(codec: KeyCodec, flat: np.ndarray, dtype: np.dtype,
@@ -1408,8 +1406,8 @@ def _sort_impl(
         """Run the on-device verifier on a result; True = verified.
         Emits the ``verify`` span event (ok / sorted_ok / fp_ok) the
         report CLI's robustness table aggregates."""
-        with tracer.phase("verify"):
-            sorted_ok, fp_ok = vfy.verify_result(res_v, fp_v)
+        with tracer.span("phase:verify"), tracer.span("verify.check"):
+            sorted_ok, fp_ok = vfy.verify_result(res_v, fp_v, tracer)
         tracer.count("verify_runs", 1)
         tracer.spans.event("verify", ok=bool(sorted_ok and fp_ok),
                            sorted_ok=bool(sorted_ok), fp_ok=bool(fp_ok),
@@ -1426,7 +1424,7 @@ def _sort_impl(
         lowering gap degrades to sortedness-only verification (fp None)
         rather than breaking the sort."""
         try:
-            return vfy.fingerprint_device_input(x.reshape(-1), dtype)
+            return vfy.fingerprint_device_input(x.reshape(-1), dtype, tracer)
         except jax.errors.JaxRuntimeError:
             tracer.verbose("input fingerprint unavailable on this backend; "
                            "verifying sortedness only")
@@ -1446,7 +1444,7 @@ def _sort_impl(
                 "single-device sort result failed verification")
         if return_result:
             return res_l
-        with tracer.phase("decode"):
+        with tracer.span("phase:decode"):
             return res_l.to_numpy(tracer=tracer)
 
     if staged is not None and n_ranks == 1:
@@ -1460,8 +1458,8 @@ def _sort_impl(
                     and staged.word_diffs is not None else None)
         tracer.counters["local_engine"] = _resolve_local_engine(
             leng0, codec.n_words, staged.words[0].size)
-        with tracer.phase("sort"):
-            out = _traced_call(
+        with tracer.span("phase:sort"):
+            out = traced_call(
                 tracer, "local",
                 _compile_local(codec.n_words, leng0, s_widths),
                 *staged.words)
@@ -1482,10 +1480,11 @@ def _sort_impl(
                 # encode ONCE: the fingerprint and the pair sort share
                 # the words (a second O(n) encode pass would bill the
                 # verifier for work the sort needs anyway)
-                with tracer.phase("encode"):
+                with tracer.span("phase:encode"):
                     pair_words = codec.encode(np.asarray(x).reshape(-1))
                 if verify_on:
-                    with tracer.phase("verify"):
+                    with tracer.span("phase:verify"), \
+                            tracer.span("verify.fingerprint"):
                         fp_in = vfy.fingerprint_host(pair_words)
             elif verify_on:
                 fp_in = _local_device_fp()
@@ -1501,8 +1500,8 @@ def _sort_impl(
             if verify_on:
                 fp_in = _local_device_fp()
             try:
-                with tracer.phase("sort"):
-                    out = _traced_call(
+                with tracer.span("phase:sort"):
+                    out = traced_call(
                         tracer, "local_device",
                         _compile_local_device(dtype.name, engine),
                         x.reshape(-1))
@@ -1517,12 +1516,13 @@ def _sort_impl(
                     raise
                 x, is_device = _f64_host_input(x, tracer), False
         if not is_device:
-            with tracer.phase("encode"):
+            with tracer.span("phase:encode"):
                 words_np = codec.encode(x.reshape(-1))
             if verify_on:
-                with tracer.phase("verify"):
+                with tracer.span("phase:verify"), \
+                        tracer.span("verify.fingerprint"):
                     fp_in = vfy.fingerprint_host(words_np)
-            with tracer.phase("device_put"):
+            with tracer.span("phase:device_put"):
                 words = tuple(
                     checked_device_put(w, mesh.devices.flat[0])
                     for w in words_np
@@ -1546,7 +1546,7 @@ def _sort_impl(
                         plan.decide("ladder", chosen="passthrough")
                     if return_result:
                         return cand
-                    with tracer.phase("decode"):
+                    with tracer.span("phase:decode"):
                         return cand.to_numpy(tracer=tracer)
                 tracer.count("planner_passthrough_miss", 1)
                 if plan is not None:
@@ -1557,10 +1557,10 @@ def _sort_impl(
             l_widths = (tuple(int(d).bit_length()
                               for d in _word_diffs(words_np))
                         if engine.startswith("radix_pallas") else None)
-            with tracer.phase("sort"):
-                out = _traced_call(tracer, "local",
-                                   _compile_local(codec.n_words, engine,
-                                                  l_widths), *words)
+            with tracer.span("phase:sort"):
+                out = traced_call(tracer, "local",
+                                  _compile_local(codec.n_words, engine,
+                                                 l_widths), *words)
         return _finish_local(DistributedSortResult(out, N, dtype), fp_in)
 
     #: per-word max^min already known without touching the data again
@@ -1596,12 +1596,12 @@ def _sort_impl(
                 # otherwise conflict with the jit's mesh-wide
                 # out_shardings.
                 x_flat = checked_device_put(x_flat, key_sharding(mesh))
-                return _traced_call(
+                return traced_call(
                     tracer, "encode_pad",
                     _compile_encode_pad(dtype.name, N, mesh), x_flat)
             # Uneven N cannot be mesh-sharded directly; encode+pad
             # wherever the input lives, then land the even result.
-            ws = _traced_call(
+            ws = traced_call(
                 tracer, "encode_pad",
                 _compile_encode_pad(dtype.name, n_ranks * n, None),
                 x_flat)
@@ -1609,7 +1609,7 @@ def _sort_impl(
                          for w in ws)
 
         try:
-            with tracer.phase("encode"):
+            with tracer.span("phase:encode"):
                 words = _device_encode_words()
             rebuild_words = _device_encode_words
         except jax.errors.JaxRuntimeError as e:
@@ -1627,7 +1627,7 @@ def _sort_impl(
             # overlapped with per-shard DMA, bounded host memory, and
             # the pass-planner diffs folded in flight — no second host
             # pass over the keys.
-            with tracer.phase("ingest"):
+            with tracer.span("phase:ingest"):
                 st = stream_to_mesh(flat, mesh, tracer=tracer)
             words = st.words
             words_np = None
@@ -1636,11 +1636,11 @@ def _sort_impl(
             rebuild_words = lambda: stream_to_mesh(  # noqa: E731
                 flat, mesh, tracer=tracer).words
         else:
-            with tracer.phase("encode"):
+            with tracer.span("phase:encode"):
                 words_np = codec.encode(flat)
                 pad = _host_pad_words(codec, flat, dtype, n_ranks * n)
 
-            with tracer.phase("device_put"):
+            with tracer.span("phase:device_put"):
                 words = _shard_input(words_np, mesh, n, pad)
             rebuild_words = lambda: _shard_input(  # noqa: E731
                 words_np, mesh, n, pad)
@@ -1669,17 +1669,18 @@ def _sort_impl(
     sup = SortSupervisor(tracer, registry=reg, plan=plan)
     input_fp = None
     if verify_on:
-        with tracer.phase("verify"):
+        with tracer.span("phase:verify"):
             if staged is not None:
                 # folded chunk-by-chunk during streamed ingest — free
                 input_fp = staged.fingerprint
             elif stream_fp is not None:
                 input_fp = stream_fp  # in-sort streamed ingest, same fold
             elif words_np is not None:
-                input_fp = vfy.fingerprint_host(words_np)
+                with tracer.span("verify.fingerprint"):
+                    input_fp = vfy.fingerprint_host(words_np)
             else:
                 # device-resident padded words: one tiny fused reduction
-                input_fp = vfy.fingerprint_device(words, N)
+                input_fp = vfy.fingerprint_device(words, N, tracer)
 
     #: fair per-peer share of a shard — the ONE definition behind the
     #: sample cap bound, the skew-reroute cap, and every scale-out
@@ -1712,7 +1713,7 @@ def _sort_impl(
     _wstate = {"words": words, "dead": False}
 
     def _interleave(ws: tuple) -> tuple:
-        return _traced_call(
+        return traced_call(
             tracer, "interleave",
             _compile_interleave(mesh, codec.n_words, n), *ws)
 
@@ -1760,7 +1761,7 @@ def _sort_impl(
 
     def radix_plan():
         if not _plan:
-            with tracer.phase("plan"):
+            with tracer.span("phase:plan"):
                 if plan_diffs is not None:
                     # Streamed ingest already folded per-word max^min
                     # chunk-by-chunk — planning is free.
@@ -1811,8 +1812,8 @@ def _sort_impl(
         fn = (_compile_radix_probe(mesh, codec.n_words, n, db)
               if kind == "radix" else
               _compile_sample_probe(mesh, codec.n_words, n, oversample))
-        with tracer.phase("plan"):
-            return np.asarray(_traced_call(
+        with tracer.span("phase:plan"):
+            return np.asarray(traced_call(
                 tracer, f"{kind}_probe", fn, *live_words()))
 
     def _negotiate(kind: str, db: int | None = None) -> np.ndarray:
@@ -1884,7 +1885,7 @@ def _sort_impl(
                                 eff_pack, donate, sup.arm_exchange(),
                                 exchange_engine=eng,
                                 local_engine=radix_leng)
-            with tracer.phase("sort"):
+            with tracer.span("phase:sort"):
                 out, max_cnt = sup.dispatch(
                     "radix_spmd", fn, live_words, on_retry=mark_dead,
                     n=n, cap=c, passes=passes, digit_bits=db, ranks=n_ranks)
@@ -1986,7 +1987,7 @@ def _sort_impl(
                                  eff_pack, spmd_engine, donate,
                                  sup.arm_exchange(),
                                  exchange_engine=eng)
-            with tracer.phase("sort"):
+            with tracer.span("phase:sort"):
                 out, counts, max_cnt = sup.dispatch(
                     "sample_spmd", fn, live_words, on_retry=mark_dead,
                     n=n, cap=c, ranks=n_ranks)
@@ -2042,13 +2043,14 @@ def _sort_impl(
             arr = np.asarray(staged.source).reshape(-1)
         else:
             arr = np.asarray(x).reshape(-1)
-        with tracer.phase("sort"):
+        with tracer.span("phase:sort"):
             w = codec.encode(arr)
             # np.lexsort: last key is primary -> feed words lsw-first
             order = np.lexsort(tuple(reversed(w)))
             sorted_w = tuple(wi[order] for wi in w)
         if verify_on and input_fp is not None:
-            with tracer.phase("verify"):
+            with tracer.span("phase:verify"), \
+                    tracer.span("verify.fingerprint"):
                 out_fp = vfy.fingerprint_host(sorted_w)
             tracer.count("verify_runs", 1)
             if out_fp != input_fp:
@@ -2224,6 +2226,6 @@ def _sort_impl(
         tracer.counters["degraded_to"] = level
     if return_result:
         return res
-    with tracer.phase("decode"):
+    with tracer.span("phase:decode"):
         out_np = res.to_numpy(tracer=tracer)
     return out_np

@@ -56,6 +56,7 @@ before the first DMA — and then pipeline from the in-memory array.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import queue
 import threading
@@ -63,7 +64,7 @@ import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING
+from typing import TYPE_CHECKING, Any, Callable
 
 import jax
 import numpy as np
@@ -75,8 +76,8 @@ from mpitest_tpu.ops.keys import codec_for
 from mpitest_tpu.parallel.mesh import assemble_sharded, shard_bounds
 from mpitest_tpu.utils import io as kio
 from mpitest_tpu.utils import native_encode
-from mpitest_tpu.utils.spans import (SpanLog, merge_intervals,
-                                     overlap_seconds)
+from mpitest_tpu.utils.spans import (SpanLog, annotation_type,
+                                     merge_intervals, overlap_seconds)
 
 if TYPE_CHECKING:
     from jax.sharding import Mesh
@@ -255,6 +256,16 @@ def _spans_of(tracer: "Tracer | None") -> "SpanLog | None":
     return tracer.spans if tracer is not None else None
 
 
+def _stage_type(spans: "SpanLog | None") -> Callable[[str], Any]:
+    """What a stage that ``spans`` records after the fact
+    (:meth:`SpanLog.record`) opens around its work, on its own thread:
+    the same-named profiler annotation, constructed in the stage's frame
+    (``with stage("ingest.parse"):``); a no-op without a span log."""
+    if spans is None:
+        return lambda name: contextlib.nullcontext()
+    return annotation_type()
+
+
 def stream_to_mesh(x: np.ndarray, mesh: "Mesh",
                    tracer: "Tracer | None" = None,
                    chunk_elems: int | None = None,
@@ -285,6 +296,7 @@ def stream_to_mesh(x: np.ndarray, mesh: "Mesh",
     total = n_ranks * n
     bounds = shard_bounds(mesh, n)
     spans = _spans_of(tracer)
+    stage = _stage_type(spans)
     state = _StreamState(codec.n_words, fold_fp=verify_enabled())
     state.stats.n = N
     state.stats.encode_engine = eng
@@ -328,9 +340,10 @@ def stream_to_mesh(x: np.ndarray, mesh: "Mesh",
             k = 0
             while off < N:
                 t0 = time.perf_counter()
-                c = x[off:off + chunk_elems]
-                if materialize:
-                    c = np.array(c)   # fault the pages in, off-thread
+                with stage("ingest.parse"):
+                    c = x[off:off + chunk_elems]
+                    if materialize:
+                        c = np.array(c)   # fault the pages in, off-thread
                 dt = time.perf_counter() - t0
                 with state.lock:
                     state.stats.parse_s += dt
@@ -355,8 +368,9 @@ def stream_to_mesh(x: np.ndarray, mesh: "Mesh",
         # The timed interval covers the WHOLE stage for both engines,
         # so encode_s / encode_gb_per_s compare like for like.
         t0 = time.perf_counter()
-        words, los, his, m, chunk_fp = native_encode.encode_and_fold(
-            chunk, codec, state.fold_fp, eng)
+        with stage("ingest.encode"):
+            words, los, his, m, chunk_fp = native_encode.encode_and_fold(
+                chunk, codec, state.fold_fp, eng)
         dt = time.perf_counter() - t0
         state.apply_fold(los, his, m, chunk_fp, t0, dt)
         # fault injection (SORT_FAULTS=ingest_poison): corrupt AFTER the
@@ -370,36 +384,37 @@ def stream_to_mesh(x: np.ndarray, mesh: "Mesh",
         return words
 
     def transfer_one(k: int, off: int, words, pad: bool = False):
-        t0 = time.perf_counter()
-        clen = words[0].size
-        # issue EVERY per-device put before blocking on any: a chunk
-        # spanning k shard boundaries then runs its k DMAs concurrently
-        # instead of serializing device-by-device
-        placed = []
-        for d, (dev, start, stop) in enumerate(bounds):
-            a = max(off, start)
-            b = min(off + clen, stop)
-            if a >= b:
-                continue
-            placed.append((d, tuple(
-                checked_device_put(w[a - off:b - off], dev) for w in words
-            )))
-        nbytes = 0
-        for d, piece in placed:
-            for p in piece:
-                p.block_until_ready()
-                nbytes += p.nbytes
-            per_dev[d].append(piece)
-        dt = time.perf_counter() - t0
-        with state.lock:
-            state.stats.transfer_s += dt
-            state.stats.xfer_iv.append((t0, t0 + dt))
-            state.stats.device_bytes += nbytes
-        if spans is not None:
-            attrs = {"chunk": k, "bytes": int(nbytes)}
-            if pad:
-                attrs["pad"] = True
-            spans.record("ingest.transfer", t0, dt, **attrs)
+        with stage("ingest.transfer"):
+            t0 = time.perf_counter()
+            clen = words[0].size
+            # issue EVERY per-device put before blocking on any: a chunk
+            # spanning k shard boundaries then runs its k DMAs concurrently
+            # instead of serializing device-by-device
+            placed = []
+            for d, (dev, start, stop) in enumerate(bounds):
+                a = max(off, start)
+                b = min(off + clen, stop)
+                if a >= b:
+                    continue
+                placed.append((d, tuple(
+                    checked_device_put(w[a - off:b - off], dev) for w in words
+                )))
+            nbytes = 0
+            for d, piece in placed:
+                for p in piece:
+                    p.block_until_ready()
+                    nbytes += p.nbytes
+                per_dev[d].append(piece)
+            dt = time.perf_counter() - t0
+            with state.lock:
+                state.stats.transfer_s += dt
+                state.stats.xfer_iv.append((t0, t0 + dt))
+                state.stats.device_bytes += nbytes
+            if spans is not None:
+                attrs = {"chunk": k, "bytes": int(nbytes)}
+                if pad:
+                    attrs["pad"] = True
+                spans.record("ingest.transfer", t0, dt, **attrs)
 
     q: queue.Queue = queue.Queue(maxsize=2)
     # threadlint TL010: named like its registered root (ingest-parse)
@@ -523,14 +538,16 @@ def stream_result_to_numpy(words: tuple[jax.Array, ...], n_valid: int,
             "streamed egress requires fully addressable result shards; "
             "on a multi-process mesh gather per-process results instead")
     spans = _spans_of(tracer)
+    stage = _stage_type(spans)
     out = np.empty(n_valid, np.dtype(dtype))
     shard_lists = [w.addressable_shards for w in words]
     n_shards = len(shard_lists[0])
 
     def fetch(i: int):
         t0 = time.perf_counter()
-        sl = shard_lists[0][i].index[0]
-        host = tuple(np.asarray(sl_w[i].data) for sl_w in shard_lists)
+        with stage("egress.fetch"):
+            sl = shard_lists[0][i].index[0]
+            host = tuple(np.asarray(sl_w[i].data) for sl_w in shard_lists)
         dt = time.perf_counter() - t0
         if spans is not None:
             spans.record("egress.fetch", t0, dt, shard=i,
@@ -543,7 +560,8 @@ def stream_result_to_numpy(words: tuple[jax.Array, ...], n_valid: int,
         if a >= b:
             return
         t0 = time.perf_counter()
-        out[a:b] = codec.decode(tuple(h[: b - a] for h in host))
+        with stage("egress.decode"):
+            out[a:b] = codec.decode(tuple(h[: b - a] for h in host))
         dt = time.perf_counter() - t0
         if spans is not None:
             spans.record("egress.decode", t0, dt, shard=i,

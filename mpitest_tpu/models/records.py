@@ -52,6 +52,7 @@ from mpitest_tpu.models.ingest import checked_device_put
 from mpitest_tpu.models.segmented import lex_sorted_host
 from mpitest_tpu.models.supervisor import SortIntegrityError, verify_enabled
 from mpitest_tpu.ops.keys import KeyCodec, codec_for
+from mpitest_tpu.utils.programs import program
 
 #: Hard bound on records per sort: the lane-index word is uint32 and
 #: the (key<<32|idx) fusion gives the index the low 32 bits.
@@ -166,7 +167,8 @@ def _compile_record_sort(n_key_words: int, n_payload_words: int,
                 gather(perm, payload), perm
 
         with jax.enable_x64(True):
-            return jax.jit(f1).lower(*specs).compile()
+            return jax.jit(program("record_sort", "local_sort", f1)
+                           ).lower(*specs).compile()
 
     def f(*arrs: Any) -> Any:
         kw, payload = arrs[:n_key_words], arrs[n_key_words:]
@@ -176,7 +178,8 @@ def _compile_record_sort(n_key_words: int, n_payload_words: int,
         perm = out[-1]
         return tuple(out[:n_key_words]), gather(perm, payload), perm
 
-    return jax.jit(f).lower(*specs).compile()
+    return jax.jit(program("record_sort", "local_sort", f)
+                   ).lower(*specs).compile()
 
 
 def _dispatch(codec: KeyCodec, key_words: tuple[np.ndarray, ...],

@@ -43,6 +43,7 @@ import numpy as np
 
 from mpitest_tpu.models.verify import Fingerprint, fingerprint_host
 from mpitest_tpu.ops.keys import KeyCodec, codec_for
+from mpitest_tpu.utils.programs import program
 
 #: Segment id of pad lanes — the uint32 maximum, strictly above any real
 #: segment id (the batcher caps segments per batch far below it), so
@@ -165,12 +166,14 @@ def compile_packed_sort(n_words_total: int,
                     s.astype(jnp.uint32))
 
         with jax.enable_x64(True):
-            return jax.jit(f2).lower(*specs).compile()
+            return jax.jit(program("segmented_sort", "local_sort", f2)
+                           ).lower(*specs).compile()
 
     def f(*words: Any) -> Any:
         return kernels.local_sort(words)
 
-    return jax.jit(f).lower(*specs).compile()
+    return jax.jit(program("segmented_sort", "local_sort", f)
+                   ).lower(*specs).compile()
 
 
 def executable_stats(exe: Any) -> dict[str, float]:

@@ -206,13 +206,13 @@ class SortSupervisor:
         caller's rebuild kicks in)."""
         import jax
 
-        from mpitest_tpu.models.api import _traced_call
+        from mpitest_tpu.utils.programs import traced_call
 
         attempt = 0
         while True:
             try:
                 self._inject_dispatch_fault()
-                return _traced_call(self.tracer, label, fn, *args_fn(),
+                return traced_call(self.tracer, label, fn, *args_fn(),
                                     **attrs)
             except jax.errors.JaxRuntimeError as e:
                 # an exchange fault armed for THIS dispatch may not have

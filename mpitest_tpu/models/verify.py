@@ -43,10 +43,13 @@ from typing import TYPE_CHECKING, Callable
 
 import numpy as np
 
+from mpitest_tpu.utils.programs import program, traced_call
+
 if TYPE_CHECKING:
     import jax
 
     from mpitest_tpu.models.api import DistributedSortResult
+    from mpitest_tpu.utils.trace import Tracer
 
 _U32 = 0xFFFFFFFF
 
@@ -171,7 +174,7 @@ def _compile_contig(n_words: int, n_valid: int, total: int,
         ok = jnp.all(lt | eq)
         return ok, tuple(xors), tuple(sums)
 
-    return jax.jit(f)
+    return jax.jit(program("verify_contig", "verify", f))
 
 
 @lru_cache(maxsize=64)
@@ -249,7 +252,7 @@ def _compile_ragged(n_words: int, n_valid: int, slots: int,
         cross = jnp.all(~lex_lt(nxt, prev) | empty[1:])
         return within & cross, n_in, tuple(xors), tuple(sums)
 
-    return jax.jit(f)
+    return jax.jit(program("verify_ragged", "verify", f))
 
 
 @lru_cache(maxsize=16)
@@ -270,47 +273,56 @@ def _compile_encode_fp(dtype_name: str) -> "Callable[..., object]":
         sums = tuple(jnp.sum(w, dtype=jnp.uint32) for w in words)
         return xors, sums
 
-    return jax.jit(f)
+    return jax.jit(program("verify_encode_fp", "verify", f))
 
 
-def fingerprint_device_input(x: "jax.Array",
-                             dtype: "np.dtype | str") -> Fingerprint:
-    """Fingerprint of raw device-resident keys (encode fused in)."""
-    xors, sums = _compile_encode_fp(np.dtype(dtype).name)(x)
+def fingerprint_device_input(x: "jax.Array", dtype: "np.dtype | str",
+                             tracer: "Tracer") -> Fingerprint:
+    """Fingerprint of raw device-resident keys (encode fused in); the
+    program runs under ``tracer``'s compile-vs-execute span."""
+    xors, sums = traced_call(tracer, "verify_encode_fp",
+                             _compile_encode_fp(np.dtype(dtype).name), x)
     return Fingerprint(int(x.size),
                        tuple(int(v) for v in xors),
                        tuple(int(s) for s in sums))
 
 
-def fingerprint_device(words: "tuple[jax.Array, ...]",
-                       n_valid: int) -> Fingerprint:
+def fingerprint_device(words: "tuple[jax.Array, ...]", n_valid: int,
+                       tracer: "Tracer") -> Fingerprint:
     """Input-side device fingerprint over a contiguous padded layout
     (one tiny fused reduction, one scalar sync)."""
     n_words = len(words)
     total = int(words[0].shape[0])
-    _, xors, sums = _compile_contig(n_words, n_valid, total, False)(*words)
+    _, xors, sums = traced_call(
+        tracer, "verify_contig",
+        _compile_contig(n_words, n_valid, total, False), *words)
     return Fingerprint(n_valid,
                        tuple(int(x) for x in xors),
                        tuple(int(s) for s in sums))
 
 
 def verify_result(res: "DistributedSortResult",
-                  input_fp: Fingerprint | None) -> tuple[bool, bool]:
+                  input_fp: Fingerprint | None,
+                  tracer: "Tracer") -> tuple[bool, bool]:
     """Verify a DistributedSortResult on device: returns
     ``(sorted_ok, fp_ok)``.  ``fp_ok`` is True when no input fingerprint
-    is available (nothing to compare — sortedness still gates)."""
+    is available (nothing to compare — sortedness still gates).  The
+    programs run under ``tracer``'s compile-vs-execute spans."""
     n_words = len(res.words)
     if res.counts is None:
         total = int(res.words[0].shape[0])
-        ok, xors, sums = _compile_contig(
-            n_words, min(res.n_valid, total), total, True)(*res.words)
+        ok, xors, sums = traced_call(
+            tracer, "verify_contig",
+            _compile_contig(n_words, min(res.n_valid, total), total, True),
+            *res.words)
         out_fp = Fingerprint(res.n_valid,
                              tuple(int(x) for x in xors),
                              tuple(int(s) for s in sums))
     else:
         n_ranks = len(res.counts)
-        ok, n_in, xors, sums = _compile_ragged(
-            n_words, res.n_valid, res.shard_slots, n_ranks)(
+        ok, n_in, xors, sums = traced_call(
+            tracer, "verify_ragged",
+            _compile_ragged(n_words, res.n_valid, res.shard_slots, n_ranks),
             np.asarray(res.counts, np.int32), *res.words)
         out_fp = Fingerprint(int(n_in),
                              tuple(int(x) for x in xors),

@@ -315,6 +315,7 @@ def _compile_block_sort(nblk: int, s_rows: int, b_log2: int, interpret: bool):
         # Raised budget: admits the B=17 block experiment (the unrolled
         # chain holds ~34 live block copies); no effect at B=16.
         compiler_params=_COMPILER_PARAMS,
+        name="bitonic.block_sort",
         interpret=interpret,
     )
 
@@ -352,6 +353,7 @@ def _compile_cross(nblk: int, s_rows: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((nblk, s_rows, LANES), jnp.int32),
         grid_spec=grid_spec,
         compiler_params=_COMPILER_PARAMS,
+        name="bitonic.cross",
         interpret=interpret,
     )
 
@@ -381,6 +383,7 @@ def _compile_merge(n_members: int, nblk: int, s_rows: int, b_log2: int,
         # the revolving-window pipeline serializes).  The extra buffer is
         # the cheaper trade.  The cross kernel could not alias anyway:
         # both (q, 0) and (q, 1) steps read the pair.
+        name="bitonic.merge",
         interpret=interpret,
     )
 
@@ -516,6 +519,7 @@ def _compile_relayout_cross(n_members: int, nblk: int, s_rows: int,
         out_shape=jax.ShapeDtypeStruct((nblk, s_rows, LANES), jnp.int32),
         grid_spec=grid_spec,
         compiler_params=_COMPILER_PARAMS,
+        name="bitonic.relayout_cross",
         interpret=interpret,
     )
 
@@ -573,6 +577,7 @@ def _compile_rot_merge(nblk: int, s_rows: int, b_log2: int, tail: int,
         out_shape=jax.ShapeDtypeStruct((nblk, s_rows, LANES), jnp.int32),
         grid_spec=grid_spec,
         compiler_params=_COMPILER_PARAMS,
+        name="bitonic.rot_merge",
         interpret=interpret,
     )
 
@@ -707,6 +712,7 @@ def _compile_block_sort_pair(nblk: int, s_rows: int, b_log2: int,
         in_specs=[spec, spec],
         out_specs=[spec, spec],
         compiler_params=_COMPILER_PARAMS,
+        name="bitonic.block_sort_pair",
         interpret=interpret,
     )
 
@@ -738,6 +744,7 @@ def _compile_cross_pair(nblk: int, s_rows: int, interpret: bool):
         out_shape=[shape, shape],
         grid_spec=grid_spec,
         compiler_params=_COMPILER_PARAMS,
+        name="bitonic.cross_pair",
         interpret=interpret,
     )
 
@@ -762,6 +769,7 @@ def _compile_merge_pair(n_members: int, nblk: int, s_rows: int, b_log2: int,
         # Raised budget: the 8-member shape (tail_bits=3 experiment)
         # needs 25.6 MiB scoped vmem; no effect on the 2/4-member forms.
         compiler_params=_COMPILER_PARAMS,
+        name="bitonic.merge_pair",
         interpret=interpret,
     )
 
@@ -972,6 +980,7 @@ def _compile_relayout_cross_pair(n_members: int, nblk: int, s_rows: int,
         out_shape=[shape, shape],
         grid_spec=grid_spec,
         compiler_params=_COMPILER_PARAMS,
+        name="bitonic.relayout_cross_pair",
         interpret=interpret,
     )
 
@@ -1040,6 +1049,7 @@ def _compile_rot_merge_pair(nblk: int, s_rows: int, b_log2: int,
         out_shape=[shape, shape],
         grid_spec=grid_spec,
         compiler_params=_COMPILER_PARAMS,
+        name="bitonic.rot_merge_pair",
         interpret=interpret,
     )
 
@@ -1105,6 +1115,7 @@ def _compile_fix_runs(nblk: int, s_rows: int, passes: int, interpret: bool):
         in_specs=[spec, spec],
         out_specs=spec,
         compiler_params=_COMPILER_PARAMS,
+        name="bitonic.fix_runs",
         interpret=interpret,
     )
 

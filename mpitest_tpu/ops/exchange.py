@@ -66,6 +66,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from mpitest_tpu.ops.pallas_kernels import (
     CHUNK, LANES, ROWS, chunk_geometry)
+from mpitest_tpu.utils.programs import layer, tagged
 
 #: Engine names accepted by the exchange dispatch (the knob adds "auto").
 ENGINES = ("lax", "pallas", "pallas_interpret")
@@ -155,18 +156,20 @@ def fused_pass_pack(
             + [pltpu.SemaphoreType.DMA(())] * n_arrays
         ),
     )
-    outs = pl.pallas_call(
-        functools.partial(_fused_pack_kernel, n, fills, n_arrays),
-        grid_spec=grid_spec,
-        out_shape=tuple(
-            jax.ShapeDtypeStruct(
-                (n_ranks, cap // CHUNK, ROWS, LANES), a.dtype,
-                vma=frozenset(vma),
-            )
-            for a in arrays
-        ),
-        interpret=interpret,
-    )(starts.astype(jnp.int32), cnts.astype(jnp.int32), *data_2d)
+    with layer("exchange"):
+        outs = pl.pallas_call(
+            functools.partial(_fused_pack_kernel, n, fills, n_arrays),
+            grid_spec=grid_spec,
+            out_shape=tuple(
+                jax.ShapeDtypeStruct(
+                    (n_ranks, cap // CHUNK, ROWS, LANES), a.dtype,
+                    vma=frozenset(vma),
+                )
+                for a in arrays
+            ),
+            name="exchange.fused_pass_pack",
+            interpret=interpret,
+        )(starts.astype(jnp.int32), cnts.astype(jnp.int32), *data_2d)
     if n_arrays == 1 and not isinstance(outs, (tuple, list)):
         outs = (outs,)
     return tuple(o.reshape(n_ranks, cap) for o in outs)
@@ -224,6 +227,7 @@ def _remote_a2a_kernel(n_ranks: int, axis: str, x_ref, out_ref,
         rc.wait()
 
 
+@tagged("exchange")
 def remote_a2a(
     x: jax.Array,           # [P, cap] — row p is my bucket for rank p
     n_ranks: int,
@@ -254,6 +258,7 @@ def remote_a2a(
         ],
         compiler_params=pltpu.CompilerParams(
             collective_id=_A2A_COLLECTIVE_ID),
+        name="exchange.remote_a2a",
         # never interpreted: interpret=True returned above via the
         # bit-identical lax transport — the interpreter cannot simulate
         # the cross-device DMA this kernel exists for

@@ -14,9 +14,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from mpitest_tpu.utils.programs import tagged
+
 Words = tuple[jax.Array, ...]
 
 
+@tagged("local_sort")
 def local_sort(words: Words, engine: str = "lax",
                diffs: tuple[int, ...] | None = None) -> Words:
     """Lexicographic stable sort of multi-word keys (msw first).
@@ -142,6 +145,7 @@ def _fix_boundary(hi: jax.Array, lo: jax.Array, passes: int,
     return lb.reshape(-1)
 
 
+@tagged("local_sort")
 def sort_two_words_bitonic(hi: jax.Array, lo: jax.Array,
                            interpret: bool = False, fix_passes: int = 16):
     """64-bit local sort via the pair bitonic engine — the MSD-hybrid

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mpitest_tpu.utils.programs import layer
+
 
 LANES = 128
 ROWS = 8                    # (8, 128) = one int32 tile
@@ -135,13 +137,15 @@ def segment_pack(
             pltpu.SemaphoreType.DMA(()),
         ],
     )
-    out = pl.pallas_call(
-        functools.partial(_pack_kernel, n, fill),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (n_ranks, cap // CHUNK, ROWS, LANES), data.dtype,
-            vma=frozenset(vma),
-        ),
-        interpret=interpret,
-    )(starts.astype(jnp.int32), cnts.astype(jnp.int32), data_2d)
-    return out.reshape(n_ranks, cap)
+    with layer("exchange"):
+        out = pl.pallas_call(
+            functools.partial(_pack_kernel, n, fill),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (n_ranks, cap // CHUNK, ROWS, LANES), data.dtype,
+                vma=frozenset(vma),
+            ),
+            name="exchange.segment_pack",
+            interpret=interpret,
+        )(starts.astype(jnp.int32), cnts.astype(jnp.int32), data_2d)
+        return out.reshape(n_ranks, cap)

@@ -39,6 +39,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mpitest_tpu.utils.programs import program, tagged
+
 Words = tuple[jax.Array, ...]
 
 #: Digit width of one fused pass.  Bins per kernel = 2**bits + 1: the
@@ -189,11 +191,13 @@ def _fused_pass(planes: Words, n: int, widx: int, shift: int, bits: int,
         out_shape=[jax.ShapeDtypeStruct((n_pad, 1), jnp.uint32)
                    for _ in range(n_words)],
         scratch_shapes=[pltpu.VMEM((SORT_CHUNK, 1), jnp.int32)],
+        name="radix_pallas.pass",
         interpret=interpret,
     )(*planes)
     return tuple(out)
 
 
+@tagged("local_sort")
 def fused_radix_sort(words: Words,
                      diffs: tuple[int, ...] | None = None,
                      digit_bits: int = DIGIT_BITS,
@@ -297,11 +301,12 @@ def _compile_merge_order(n_planes: int, n_pad: int, interpret: bool):
             functools.partial(_order_kernel, n_planes, MERGE_CHUNK),
             out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
             scratch_shapes=[pltpu.VMEM((MERGE_CHUNK, 1), jnp.int32)],
+            name="radix_pallas.merge_order",
             interpret=interpret,
         )(*cols, *rows)
         return order.reshape(-1)
 
-    return jax.jit(run)
+    return jax.jit(program("merge_order", "local_sort", run))
 
 
 def merge_order(planes: Words, interpret: bool = False) -> jax.Array:

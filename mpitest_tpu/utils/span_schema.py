@@ -15,8 +15,9 @@ Two name classes:
 
 * **exact names** (:data:`SPAN_NAMES`) — every key maps to a one-line
   doc of what the span means and who emits it;
-* **phase names** (:data:`PHASE_NAMES`) — ``Tracer.phase(name)`` emits
-  ``phase:<name>``; the report's per-phase table keys on the suffix.
+* **phase names** (:data:`PHASE_NAMES`) — a span named ``phase:<name>``
+  is a phase (``Tracer.phases`` sums it); the report's per-phase table
+  keys on the suffix.
 
 This module is import-light on purpose (stdlib only): sortlint loads it
 without pulling jax/numpy, so the lint CI job needs no device stack.
@@ -24,8 +25,8 @@ without pulling jax/numpy, so the lint CI job needs no device stack.
 
 from __future__ import annotations
 
-#: ``Tracer.phase(name)`` vocabulary → ``phase:<name>`` spans, summed
-#: into the report's per-phase wall-time table.
+#: Phase vocabulary → ``phase:<name>`` spans, summed into
+#: ``Tracer.phases`` and the report's per-phase wall-time table.
 PHASE_NAMES: frozenset[str] = frozenset({
     "sort",        # SPMD program dispatch + execution
     "encode",      # host-side key codec encode
@@ -36,7 +37,7 @@ PHASE_NAMES: frozenset[str] = frozenset({
     "plan",        # pass/splitter planning
 })
 
-#: Prefix of every phase span (``Tracer.phase`` is the only producer).
+#: Prefix of every phase span.
 PHASE_PREFIX = "phase:"
 
 #: Exact span/event names → one-line doc.  Grouped by producer.
@@ -44,7 +45,8 @@ SPAN_NAMES: dict[str, str] = {
     # models/api.py — run umbrellas and the jit split
     "sort": "one sort() run (umbrella span; device-mem high-water attr)",
     "ingest": "one ingest_to_mesh() run (umbrella span)",
-    "jit_compile_execute": "first call of a jit program (trace+compile+run)",
+    "jit_compile_execute": ("call of a jit program in which JAX compiled "
+                            "(trace+compile+run; a new shape included)"),
     "jit_execute": "warm call of a jit program",
     # models/* — trace-time algorithm structure
     "radix_pass": "one LSD radix pass (trace-time, per compile)",
@@ -58,6 +60,11 @@ SPAN_NAMES: dict[str, str] = {
     "fault": "one injected fault firing (site, seq)",
     "supervisor_retry": "one retried SPMD dispatch (label, attempt, error)",
     "verify": "one output verification (ok, sorted_ok, fp_ok)",
+    # models/api.py — the parts of phase:verify
+    "verify.fingerprint": ("host fold of an input (or host-fallback "
+                           "output) fingerprint, inside phase:verify"),
+    "verify.check": ("device verifier of a result and its readback, "
+                     "inside phase:verify (holds the wait for the sort)"),
     # scale-out vocabulary (ISSUE 7)
     "exchange_balance": ("negotiated exchange capacity + per-rank "
                          "send/recv byte balance (host count probe)"),

@@ -15,11 +15,18 @@ three ways:
   produces the ``{"traceEvents": [...]}`` JSON that chrome://tracing and
   Perfetto open directly.
 * **In-process** — ``SpanLog.spans`` for tests and the report CLI.
+* **On the profiler's clock** — while a ``jax.profiler`` trace runs
+  (``SORT_PROFILE``), every timed span is also the same-named
+  ``TraceAnnotation`` on its thread, opened in the caller's frame
+  (``SpanLog.span``), so the device trace shows the program's spans
+  beside the device ops they wait on, nested among the Python frames as
+  the spans nest.
 
 Device-side granularity contract: collectives and radix passes execute
 inside ONE fused XLA program, so they are not individually host-timable
 — their wall time lives in the enclosing ``jit`` span, and per-op device
-timing remains ``SORT_PROFILE``'s job (``jax.profiler``).  What IS
+timing remains ``SORT_PROFILE``'s job (``jax.profiler``; each op there
+carries its layer, :mod:`mpitest_tpu.utils.programs`).  What IS
 knowable per collective — and what the MPI-vs-ICI comparison needs — is
 the static byte/shape accounting, so ``parallel/collectives.py`` and the
 SPMD models emit **trace-time point events** (``dt == 0``) carrying
@@ -164,13 +171,69 @@ def emit(name: str, **attrs: object) -> None:
         log.event(name, **attrs)
 
 
+#: The span scope type (:func:`_scope_type`), built on first use: JAX's
+#: profiler is imported lazily, once.
+_SCOPE: "type | None" = None
+
+
+def annotation_type() -> type:
+    """``jax.profiler.TraceAnnotation``.  Construct it in the frame that
+    runs the annotated work — ``with annotation_type()(name):`` — so that
+    on the profiler's trace the annotation nests inside that frame and
+    around the work (a helper that constructs it and returns would leave
+    the annotation open past its own frame)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+def _scope_type() -> type:
+    global _SCOPE
+    if _SCOPE is None:
+        from jax.profiler import TraceAnnotation
+
+        class SpanScope(TraceAnnotation):
+            """One open span; each :class:`SpanLog` binds a subclass of
+            its own as ``SpanLog.span``.  Calling it opens the span:
+            ``__new__`` does the log's bookkeeping and returns, then
+            ``TraceAnnotation``'s own ``__init__`` (native code, no
+            Python frame) starts the same-named profiler annotation in
+            the caller's frame.  So on the trace the annotation nests
+            inside the caller and around the body, as the span does.
+            While no trace runs the annotation costs its enabled-check,
+            and the span's attributes are not formatted."""
+
+            log: "SpanLog"
+
+            def __new__(cls, name: str, **attrs: object) -> "SpanScope":
+                scope = super().__new__(cls)
+                scope.span, scope.outermost = cls.log._open(name, attrs)
+                return scope
+
+            def __enter__(self) -> Span:
+                return self.span
+
+            def __exit__(self, *exc: object) -> None:
+                # close the span, then the annotation: this frame starts
+                # inside the annotation, and ends just after it
+                try:
+                    type(self).log._close(self.span, self.outermost)
+                finally:
+                    TraceAnnotation.__exit__(self, *exc)
+
+        # threadlint: disable=TL004 -- idempotent: racing writers bind equal types
+        _SCOPE = SpanScope
+    return _SCOPE
+
+
 def maybe_span(
     name: str, **attrs: object,
 ) -> "contextlib.AbstractContextManager[Span | None]":
     """Span twin of :func:`emit`: a span on the active log, or a no-op
     context manager when tracing is off — what instrumented SPMD model
     code opens around trace-time regions (radix passes, splitter
-    rounds)."""
+    rounds).  Opened inside this helper, its profiler annotation starts
+    in this frame; those regions run only while JAX traces a program."""
     log = current_log()
     if log is None:
         return contextlib.nullcontext()
@@ -264,6 +327,13 @@ class SpanLog:
         #: pipeline worker threads share with the driver thread.  The
         #: nesting stack stays driver-thread-only by contract.
         self._lock = threading.Lock()
+        #: ``span(name, **attrs)``: a timed interval that nests under the
+        #: enclosing open span, for a ``with`` statement (yields the
+        #: :class:`Span`).  The outermost span activates this log for
+        #: module-level :func:`emit`.  While a profiler trace runs, the
+        #: same-named annotation covers the span's extent on the trace's
+        #: clock (see ``SpanScope``).
+        self.span = type("SpanScope", (_scope_type(),), {"log": self})
 
     # -- recording ----------------------------------------------------
     def _new(self, name: str, attrs: dict[str, object],
@@ -325,10 +395,10 @@ class SpanLog:
         self._flush(s)
         return s
 
-    @contextmanager
-    def span(self, name: str, **attrs: object) -> Iterator[Span]:
-        """Timed interval; nests under the enclosing open span.  The
-        outermost span activates this log for module-level `emit()`."""
+    def _open(self, name: str, attrs: dict[str, object]
+              ) -> tuple[Span, bool]:
+        """Open a span (``span``'s entry): returns it and whether it is
+        the outermost open span of this log."""
         s = self._new(name, attrs)
         self._retain(s)
         # stack mutations under the SAME lock _new reads them under:
@@ -346,17 +416,18 @@ class SpanLog:
             outermost = len(self._stack) == 1
         if outermost:
             _ACTIVE.append(self)
-        try:
-            yield s
-        finally:
-            s.dt = time.perf_counter() - s.t0
-            with self._lock:
-                self._stack.pop()
-                self._drop_stack.pop()
-                self._ctx_stack.pop()
-            if outermost and _ACTIVE and _ACTIVE[-1] is self:
-                _ACTIVE.pop()
-            self._flush(s)
+        return s, outermost
+
+    def _close(self, s: Span, outermost: bool) -> None:
+        """Close the span :meth:`_open` opened (``span``'s exit)."""
+        s.dt = time.perf_counter() - s.t0
+        with self._lock:
+            self._stack.pop()
+            self._drop_stack.pop()
+            self._ctx_stack.pop()
+        if outermost and _ACTIVE and _ACTIVE[-1] is self:
+            _ACTIVE.pop()
+        self._flush(s)
 
     #: serializes stream appends across threads (O_APPEND writes of one
     #: line are atomic on Linux, but don't bet a JSONL schema on it).
@@ -391,10 +462,10 @@ class SpanLog:
         the same process-relative clock the spans were recorded on.
 
         The host driver lane is tid 1; ``utils/timeline.py`` then adds
-        one stable tid per rank (estimated activity lanes from the
-        exchange byte accounting — ISSUE 16: overlapping passes render
-        side by side instead of flattening onto the host lane), a disk
-        track, and counter tracks for inflight bytes / cap regrowth.
+        the measured extras: a disk-IO track and counter tracks for
+        inflight bytes / cap regrowth.  Per-rank device time is not
+        drawn here: the ``SORT_PROFILE`` device trace holds the real
+        device ops beside the program's own spans.
         """
         events: list[dict] = [{
             "name": "process_name", "ph": "M", "pid": 1, "tid": 1,

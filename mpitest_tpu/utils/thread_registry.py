@@ -310,6 +310,10 @@ LOCKS: tuple[LockDecl, ...] = (
     LockDecl("ingest.stream", 88,
              "mpitest_tpu.models.ingest._StreamState.lock",
              "Streamed-ingest shared fold/stats state."),
+    LockDecl("programs.listen", 89,
+             "mpitest_tpu.utils.programs._listen_lock",
+             "One-time registration of the compile-event listener "
+             "(leaf)."),
     LockDecl("metrics.registry", 90,
              "mpitest_tpu.utils.metrics_live.LiveMetrics._lock",
              "The live metric registry + every series update; ranks "

@@ -17,16 +17,18 @@ ways:
   fractions on the shared interval math of ``utils/spans.py``.
 * :func:`bench_fold` — the two trajectory scalars bench rows carry
   (``straggler_factor``, ``critical_path_phase``).
-* :func:`chrome_events` — the Perfetto enrichment: one track per rank
-  (stable tid), a disk-IO track, and counter tracks for inflight DMA
-  bytes and exchange-capacity regrowth, appended to
-  ``SpanLog.to_chrome_trace``'s host lane.
+* :func:`chrome_events` — the Perfetto enrichment: a disk-IO track and
+  counter tracks for inflight DMA bytes and exchange-capacity regrowth,
+  appended to ``SpanLog.to_chrome_trace``'s host lane.  Only measured
+  intervals and counts are drawn there.
 
 Lanes are *estimates* and say so (``"estimated": true`` on every
 derived event): collectives execute inside one fused XLA program, so
 per-rank wall time is not host-observable — but per-rank bytes are
 exact, and time-proportional-to-bytes is precisely the model the
-capacity negotiation already plans with.
+capacity negotiation already plans with.  The doctor rules and the
+sentinel read them; the Chrome export does not draw them, because the
+``SORT_PROFILE`` device trace holds each chip's measured ops.
 
 Input is duck-typed: span dicts (``report.py`` rows, flight-recorder
 snapshots) or live :class:`~mpitest_tpu.utils.spans.Span` objects
@@ -41,11 +43,9 @@ from typing import Any
 from mpitest_tpu.utils.spans import merge_intervals, overlap_seconds
 
 #: Stable Perfetto tid layout: host driver on tid 1 (the historical
-#: lane), disk IO on 900, rank R on 1000+R — ranks render side by side
-#: instead of interleaved on the host lane (the ISSUE 16 satellite fix).
+#: lane), disk IO on 900.
 HOST_TID = 1
 DISK_TID = 900
-RANK_TID_BASE = 1000
 
 #: Span names folded into each activity class (registered names —
 #: utils/span_schema.py; consumed by string match like report.py).
@@ -290,26 +290,12 @@ def bench_fold(spans: list[Any]) -> dict[str, Any]:
 
 def chrome_events(spans: list[Any]) -> list[dict]:
     """Perfetto enrichment events for ``SpanLog.to_chrome_trace``:
-    thread-name metadata + one estimated activity track per rank, a
-    disk-IO track, and ``"ph": "C"`` counter tracks (inflight DMA
-    bytes, negotiated exchange capacity, cumulative cap regrows)."""
+    thread-name metadata, a disk-IO track, and ``"ph": "C"`` counter
+    tracks (inflight DMA bytes, negotiated exchange capacity, cumulative
+    cap regrows) — measured intervals and counts only; the estimated
+    rank lanes of :func:`build_timeline` are not drawn."""
     tl = build_timeline(spans)
     events: list[dict] = []
-    for rank in tl["ranks"]:
-        tid = RANK_TID_BASE + int(rank)
-        events.append({"name": "thread_name", "ph": "M", "pid": 1,
-                       "tid": tid,
-                       "args": {"name": f"rank {rank} (estimated)"}})
-        for ev in tl["lanes"][rank]:
-            if ev["dt"] <= 0:
-                continue
-            events.append({
-                "name": f"exchange pass {ev['seq']}", "ph": "X",
-                "pid": 1, "tid": tid, "ts": ev["t0"] * 1e6,
-                "dur": ev["dt"] * 1e6,
-                "args": {"bytes": ev["bytes"], "estimated": True,
-                         "seq": ev["seq"]},
-            })
     disk = [(_as_dict(s)) for s in spans
             if str(_as_dict(s).get("name")) in DISK_SPANS]
     if disk:

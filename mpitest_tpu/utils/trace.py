@@ -13,11 +13,12 @@ wall timers and a structured metrics sidecar hook (see
 from __future__ import annotations
 
 import sys
-import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+from typing import Any
 
-from mpitest_tpu.utils.spans import SpanLog
+from mpitest_tpu.utils.span_schema import PHASE_PREFIX
+from mpitest_tpu.utils.spans import Span, SpanLog
 
 
 @dataclass
@@ -36,8 +37,8 @@ class Tracer:
     level: int = 0
     phases: dict[str, float] = field(default_factory=dict)
     counters: dict[str, float] = field(default_factory=dict)
-    #: Structured span log (utils/spans.py): every ``phase()`` opens a
-    #: nested span here too, and sort() adds jit/collective/pass spans.
+    #: Structured span log (utils/spans.py): the ``phase:*`` spans that
+    #: feed :attr:`phases`, and sort()'s jit/collective/pass spans.
     #: ``SORT_TRACE=<path>`` streams it as JSONL (wired in models/api.py).
     spans: SpanLog = field(default_factory=SpanLog)
     #: The LAST finished decision record (models/plan.py SortPlan) —
@@ -72,23 +73,23 @@ class Tracer:
         self.counters[name] = self.counters.get(name, 0.0) + value
 
     # -- additions: per-phase timers ----------------------------------
-    @contextmanager
-    def phase(self, name: str):
-        t0 = time.perf_counter()
-        with self.spans.span(f"phase:{name}"):
-            try:
-                yield
-            finally:
-                dt = time.perf_counter() - t0
-                self.phases[name] = self.phases.get(name, 0.0) + dt
-                if self.level >= 1:
-                    print(f"[VERBOSE] phase {name}: {dt*1e3:.3f} ms")
+    def __post_init__(self) -> None:
+        self.spans.observers.append(self._phase_closed)
 
-    def span(self, name: str, **attrs):
-        """Nested structured span (see :mod:`mpitest_tpu.utils.spans`) —
-        the finer-grained sibling of :meth:`phase` for events that need
-        identity and attributes, not just accumulated wall time."""
-        return self.spans.span(name, **attrs)
+    @property
+    def span(self) -> Any:
+        """``span(name, **attrs)``: a nested structured span on
+        :attr:`spans` (see :mod:`mpitest_tpu.utils.spans`), for a
+        ``with`` statement.  A span named ``phase:<name>`` is a phase:
+        its wall time also adds up in :attr:`phases` under ``<name>``."""
+        return self.spans.span
+
+    def _phase_closed(self, s: Span) -> None:
+        if s.name.startswith(PHASE_PREFIX) and s.dt:
+            name = s.name[len(PHASE_PREFIX):]
+            self.phases[name] = self.phases.get(name, 0.0) + s.dt
+            if self.level >= 1:
+                print(f"[VERBOSE] phase {name}: {s.dt*1e3:.3f} ms")
 
 
 @contextmanager

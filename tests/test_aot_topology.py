@@ -137,6 +137,21 @@ def test_pair_local_fused_one_chip(one_chip):
         assert _compile_pair_fused("int64", "bitonic").lower(x).compile()
 
 
+def test_bitonic_kernels_carry_names_and_layer(one_chip):
+    """Every 1-word bitonic kernel is named (``bitonic.*``) and tagged
+    ``layer="local_sort"``, so the chip trace can tell it apart; no
+    local-sort kernel names a mesh axis (the exchange kernel's mark)."""
+    x = jax.ShapeDtypeStruct((1 << 20,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(lambda w: kernels.local_sort((w,), engine="bitonic")
+                   ).lower(x).as_text()
+    for name in ("bitonic.block_sort", "bitonic.merge",
+                 "bitonic.relayout_cross", "bitonic.rot_merge"):
+        assert f'kernel_name = "{name}"' in text, name
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert calls and all('layer = "local_sort"' in ln for ln in calls)
+    assert "mesh_axes" not in text
+
+
 # -------------------------------------- v5e:2x2, the Pallas exchange
 
 def test_sample_pallas_exchange_2p24_per_chip(mesh4):

@@ -5,8 +5,8 @@ Three layers, smallest fixtures that pin the math:
 * **timeline** — straggler factor (incl. ragged / missing-rank byte
   lists and the median-zero fallback), bytes-proportional rank lanes
   scaled into the anchor span, critical-path phase attribution,
-  compute/DMA overlap, and the Chrome enrichment's stable per-rank
-  tids (the rank-attribution satellite fix).
+  compute/DMA overlap, and a Chrome enrichment that draws no estimated
+  rank lane.
 * **doctor rules** — one minimal fixture per registered pathology in
   ``DOCTOR_RULES``; each must produce EXACTLY its finding with the
   evidence cited and a knob suggested, and a clean evidence snapshot
@@ -112,30 +112,30 @@ def test_bench_fold_keys_only_when_signal_present():
 
 
 def test_chrome_events_stable_rank_tids():
+    """The Chrome enrichment draws measured tracks only: no estimated
+    per-rank lane, while the counter tracks stay."""
     events = timeline.chrome_events(_rows_fixture())
-    names = {(e.get("tid"), e["args"].get("name")) for e in events
-             if e.get("ph") == "M"}
-    for rank in range(4):
-        assert (timeline.RANK_TID_BASE + rank,
-                f"rank {rank} (estimated)") in names
-    lanes = [e for e in events if e.get("ph") == "X"]
-    assert lanes and all(e["tid"] >= timeline.RANK_TID_BASE
-                         and e["args"]["estimated"] for e in lanes)
+    assert not any("estimated" in str(e["args"].get("name", ""))
+                   or e["args"].get("estimated") for e in events)
+    assert not [e for e in events if e.get("ph") == "X"
+                and e.get("tid") != timeline.DISK_TID]
     counters = {e["name"] for e in events if e.get("ph") == "C"}
     assert {"inflight bytes", "exchange cap"} <= counters
 
 
 def test_chrome_trace_export_carries_rank_lanes():
-    """SpanLog.to_chrome_trace appends the enrichment (the rank-
-    attribution satellite): per-rank tids alongside the host lane."""
+    """SpanLog.to_chrome_trace keeps the host lane and its measured
+    extras, and draws no estimated rank lane beside them."""
     log = SpanLog()
     with log.span("sort_pass"):
         log.event("exchange_balance", recv_bytes=[10, 20, 30, 40])
     trace = log.to_chrome_trace()
     events = trace["traceEvents"] if isinstance(trace, dict) else trace
     json.dumps(events)  # must stay valid trace-event JSON
-    tids = {e.get("tid") for e in events}
-    assert {timeline.RANK_TID_BASE + r for r in range(4)} <= tids
+    assert {e.get("tid") for e in events} == {timeline.HOST_TID}
+    assert not any(e.get("args", {}).get("estimated") for e in events)
+    assert [e["name"] for e in events if e.get("ph") == "X"] == \
+        ["sort_pass"]
 
 
 # -- doctor rules: one fixture per registered pathology ---------------

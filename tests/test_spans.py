@@ -187,10 +187,13 @@ def test_sort_trace_env_streams_jsonl(tmp_path, mesh8, rng, monkeypatch):
 
 def test_tracer_phase_spans():
     t = Tracer()
-    with t.phase("alpha"):
-        with t.phase("beta"):
+    with t.span("phase:alpha"):
+        with t.span("phase:beta"):
             pass
-    assert "alpha" in t.phases and "beta" in t.phases
+        with t.span("not_a_phase"):
+            pass
+    assert set(t.phases) == {"alpha", "beta"}
+    assert t.phases["alpha"] == t.spans.spans[0].dt
     names = [s.name for s in t.spans.spans]
-    assert names == ["phase:alpha", "phase:beta"]
+    assert names == ["phase:alpha", "phase:beta", "not_a_phase"]
     assert t.spans.spans[1].parent == t.spans.spans[0].id

@@ -475,6 +475,7 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
             x, is_device = _f64_host_input(x, tracer), False
         else:
             tracer.counters["local_engine"] = _PAIR_CODES[code]
+            tracer.counters["encode_site"] = "device"
             if code == 3:
                 tracer.count("pair_dup_reroute", 1)
             elif code == 5:
@@ -484,6 +485,7 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
                 tracer.count("pair_residual_fallback", 1)
             return (hi_s, lo_s)
     if not is_device:
+        tracer.counters["encode_site"] = "host"
         with tracer.span("phase:encode"):
             # caller may have encoded already (the verification
             # fingerprint needs the words too — don't pay O(n) twice)
@@ -1458,6 +1460,7 @@ def _sort_impl(
                     and staged.word_diffs is not None else None)
         tracer.counters["local_engine"] = _resolve_local_engine(
             leng0, codec.n_words, staged.words[0].size)
+        tracer.counters["encode_site"] = "host"
         with tracer.span("phase:sort"):
             out = traced_call(
                 tracer, "local",
@@ -1515,7 +1518,33 @@ def _sort_impl(
                                             _device_platform(x)):
                     raise
                 x, is_device = _f64_host_input(x, tracer), False
-        if not is_device:
+            else:
+                tracer.counters["encode_site"] = "device"
+        # the planner's passthrough rung (below) and the fused radix
+        # engine's pass widths need the encoded host words
+        passthrough = (pchoice is not None and planner_mode == "on"
+                       and verify_on
+                       and pchoice.policy == "verify_passthrough")
+        if (not is_device and codec.raw_bias is not None and not passthrough
+                and not engine.startswith("radix_pallas")):
+            # one-word constant-XOR codec: ship the raw keys and let the
+            # fused encode+sort program encode them on the device; the
+            # host folds the words' fingerprint from the raw keys while
+            # the transfer and the sort run (it reads x, never the
+            # device copy, so the transfer stays covered)
+            tracer.counters["encode_site"] = "device"
+            with tracer.span("phase:device_put"):
+                raw = checked_device_put(x.reshape(-1), mesh.devices.flat[0])
+            with tracer.span("phase:sort"):
+                out = traced_call(
+                    tracer, "local_device",
+                    _compile_local_device(dtype.name, engine), raw)
+            if verify_on:
+                with tracer.span("phase:verify"), \
+                        tracer.span("verify.fingerprint"):
+                    fp_in = vfy.fingerprint_raw(x, codec.raw_bias)
+        elif not is_device:
+            tracer.counters["encode_site"] = "host"
             with tracer.span("phase:encode"):
                 words_np = codec.encode(x.reshape(-1))
             if verify_on:
@@ -1536,9 +1565,7 @@ def _sort_impl(
             # the only 1-rank site the policy can reach: device-resident
             # and staged inputs take no host profile, so the scorer
             # already chose `static` for them.
-            if (pchoice is not None and planner_mode == "on" and verify_on
-                    and pchoice.policy == "verify_passthrough"
-                    and fp_in is not None):
+            if passthrough and fp_in is not None:
                 cand = DistributedSortResult(words, N, dtype)
                 if _check_result(cand, fp_in):
                     tracer.count("planner_passthrough", 1)

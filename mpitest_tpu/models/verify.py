@@ -12,8 +12,9 @@ easily leave intact.  Here every ``sort()`` proves its own result:
 2. **Multiset fingerprint**: per encoded word, the XOR and the wrapping
    uint32 SUM over the *valid* keys, plus the exact count.  The input
    side is folded where the keys are first touched — chunk-by-chunk
-   during streamed ingest, during the host encode otherwise, or by one
-   tiny on-device reduction for device-resident input — so no extra
+   during streamed ingest, from the raw keys for one-device int32/uint32
+   input that encodes on the device, during the host encode otherwise,
+   or by one tiny on-device reduction for device-resident input — so no extra
    pass over the data ever happens.  The output side is computed by the
    same reduction over the result and compared host-side.  Truncation
    moves the count, duplication moves the sum, corruption moves the
@@ -82,6 +83,19 @@ def fingerprint_host(words: "tuple[np.ndarray, ...]") -> Fingerprint:
         tuple(int(np.bitwise_xor.reduce(w)) if w.size else 0 for w in words),
         tuple(int(w.sum(dtype=np.uint64)) & _U32 for w in words),
     )
+
+
+def fingerprint_raw(x: np.ndarray, bias: int) -> Fingerprint:
+    """Fingerprint of the encoded words ``x.view(uint32) ^ bias`` of
+    32-bit keys, folded from ``x`` itself without writing the words.
+    ``bias`` is 0 or the sign bit, so ``u ^ bias == u + bias`` (mod
+    2^32): the sum gains ``n * bias``, the XOR gains ``bias`` when the
+    count is odd, and the count is the same."""
+    assert bias in (0, 0x80000000), bias
+    fp = fingerprint_host((np.asarray(x).reshape(-1).view(np.uint32),))
+    n = fp.count
+    return Fingerprint(n, (fp.xors[0] ^ (bias if n & 1 else 0),),
+                       ((fp.sums[0] + n * bias) & _U32,))
 
 
 # ---------------------------------------------------------------- records

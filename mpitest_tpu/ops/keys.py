@@ -54,6 +54,12 @@ class KeyCodec:
     #: (floats: np.max is NaN-poisoned and NaN payloads break max-key
     #: padding; the sentinel is the totalOrder maximum by construction).
     sentinel_pad: bool = False
+    #: ``b`` where the encode is the one-word constant XOR
+    #: ``x.view(uint32) ^ b`` (int32: the sign bit; uint32: 0), else
+    #: None.  Such keys can ship raw and encode on the device, with the
+    #: words' fingerprint folded from the raw keys
+    #: (``verify.fingerprint_raw``).
+    raw_bias: int | None = None
 
     def _split64(self, u: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         return (
@@ -166,8 +172,9 @@ _CODECS = {
     np.dtype(np.uint8): KeyCodec(np.dtype(np.uint8), 1),
     np.dtype(np.int16): KeyCodec(np.dtype(np.int16), 1),
     np.dtype(np.uint16): KeyCodec(np.dtype(np.uint16), 1),
-    np.dtype(np.int32): KeyCodec(np.dtype(np.int32), 1),
-    np.dtype(np.uint32): KeyCodec(np.dtype(np.uint32), 1),
+    np.dtype(np.int32): KeyCodec(np.dtype(np.int32), 1,
+                                 raw_bias=int(_SIGN32)),
+    np.dtype(np.uint32): KeyCodec(np.dtype(np.uint32), 1, raw_bias=0),
     np.dtype(np.int64): KeyCodec(np.dtype(np.int64), 2),
     np.dtype(np.uint64): KeyCodec(np.dtype(np.uint64), 2),
     np.dtype(np.float32): KeyCodec(np.dtype(np.float32), 1, sentinel_pad=True),

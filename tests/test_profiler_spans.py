@@ -73,12 +73,15 @@ def test_span_is_its_profiler_annotation():
 def test_sort_spans_nest_on_the_calling_threads_host_line(traced_sort):
     tr, lines = traced_sort
     (line,) = [ln for ln in lines
-               if any(n == "phase:encode" for n, _, _ in ln)]
+               if any(n == "phase:sort" for n, _, _ in ln)]
     ev = [e for e in line if not e[0].startswith("$")]
     names = [n for n, _, _ in ev]
-    for phase in ("phase:encode", "phase:device_put", "phase:sort",
-                  "phase:verify", "phase:decode"):
+    for phase in ("phase:device_put", "phase:sort", "phase:verify",
+                  "phase:decode"):
         assert phase in names
+    # one-word int32 keys ship raw and encode on the device
+    assert "phase:encode" not in names
+    assert tr.counters["encode_site"] == "device"
     # each annotation starts in the frame that runs its body: no Python
     # frame that encloses an annotation's start ends before it does
     frames = [e for e in line if e[0].startswith("$")]
@@ -93,8 +96,8 @@ def test_sort_spans_nest_on_the_calling_threads_host_line(traced_sort):
     # the span log holds the same names, and the phase totals are kept
     logged = {s.name for s in tr.spans.spans}
     assert set(SPLIT) <= logged
-    assert {"encode", "device_put", "sort", "verify", "decode"} <= \
-        set(tr.phases)
+    assert {"device_put", "sort", "verify", "decode"} <= set(tr.phases)
+    assert "encode" not in tr.phases
 
 
 @pytest.mark.parametrize("name", sorted(SPLIT))

@@ -336,13 +336,14 @@ def _f64_host_input(x: jax.Array, tracer: Tracer) -> np.ndarray:
     return np.asarray(x)
 
 
-def _host_hi_dup_sniff(hi: np.ndarray) -> bool:
-    """Host twin of the hi-duplication sniff (same ~1024-key sample)."""
+def _host_hi_dup_sniff(hi: np.ndarray) -> int:
+    """Host twin of the hi-duplication sniff (same ~1024-key sample):
+    the number of equal adjacent pairs in the sorted sample."""
     n = hi.size
     s = min(1024, n)
     idx = np.linspace(0, n - 1, s).astype(np.int64)
     samp = np.sort(hi[idx])
-    return bool(np.any(samp[1:] == samp[:-1]))
+    return int(np.count_nonzero(samp[1:] == samp[:-1]))
 
 
 @lru_cache(maxsize=4)
@@ -490,10 +491,14 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
             # caller may have encoded already (the verification
             # fingerprint needs the words too — don't pay O(n) twice)
             if words_np is None:
-                words_np = codec.encode(np.asarray(x).reshape(-1))
-            rng = np.array([words_np[0].min(), words_np[0].max(),
-                            words_np[1].min(), words_np[1].max()])
-            dup = _host_hi_dup_sniff(words_np[0])
+                with tracer.span("encode.words"):
+                    words_np = codec.encode(np.asarray(x).reshape(-1))
+            with tracer.span("pair.plan"):
+                rng = np.array([words_np[0].min(), words_np[0].max(),
+                                words_np[1].min(), words_np[1].max()])
+                dups = _host_hi_dup_sniff(words_np[0])
+        tracer.counters["pair_sniff_dups"] = dups
+        dup = dups > 0
         with tracer.span("phase:device_put"):
             dev = mesh.devices.flat[0]
             words = tuple(checked_device_put(w, dev) for w in words_np)
@@ -1483,7 +1488,8 @@ def _sort_impl(
                 # encode ONCE: the fingerprint and the pair sort share
                 # the words (a second O(n) encode pass would bill the
                 # verifier for work the sort needs anyway)
-                with tracer.span("phase:encode"):
+                with tracer.span("phase:encode"), \
+                        tracer.span("encode.words"):
                     pair_words = codec.encode(np.asarray(x).reshape(-1))
                 if verify_on:
                     with tracer.span("phase:verify"), \
@@ -1545,7 +1551,7 @@ def _sort_impl(
                     fp_in = vfy.fingerprint_raw(x, codec.raw_bias)
         elif not is_device:
             tracer.counters["encode_site"] = "host"
-            with tracer.span("phase:encode"):
+            with tracer.span("phase:encode"), tracer.span("encode.words"):
                 words_np = codec.encode(x.reshape(-1))
             if verify_on:
                 with tracer.span("phase:verify"), \

@@ -83,7 +83,9 @@ def local_sort(words: Words, engine: str = "lax",
         return tuple(lax.cond(bad, _lax2w, lambda h, l: (hi_s, lo_s), hi, lo))
     if len(words) == 1:
         return (jnp.sort(words[0]),)
-    return tuple(lax.sort(list(words), num_keys=len(words), is_stable=True))
+    # every operand is a key, so stability is unobservable; a stable sort
+    # would make XLA carry an iota operand to break the ties
+    return tuple(lax.sort(list(words), num_keys=len(words), is_stable=False))
 
 
 def _fix_runs_oe(hi: jax.Array, lo: jax.Array, passes: int) -> jax.Array:

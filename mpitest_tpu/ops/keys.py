@@ -31,6 +31,17 @@ import numpy as np
 
 _SIGN32 = np.uint32(0x80000000)
 
+#: where the high and the low uint32 half of a 64-bit key lie in memory
+_HI, _LO = (1, 0) if np.little_endian else (0, 1)
+
+
+def _halves(x: np.ndarray) -> np.ndarray:
+    """64-bit keys as a ``x.shape + (2,)`` uint32 view of their halves.
+    The 64-bit codecs read and write the halves in place, so a 1 GiB
+    batch costs its two word arrays and no 64-bit temporaries."""
+    return np.ascontiguousarray(x).view(np.uint32).reshape(x.shape + (2,))
+
+
 #: All-ones uint32 word — exchange-lane fill that sorts to the tail.
 MAX_WORD = 0xFFFFFFFF
 
@@ -83,9 +94,11 @@ class KeyCodec:
             u = x.view(np.uint32)
             return (np.where(u & _SIGN32, ~u, u ^ _SIGN32),)
         if self.dtype == np.dtype(np.int64):
-            return self._split64(x.view(np.uint64) ^ np.uint64(0x8000000000000000))
+            h = _halves(x)
+            return (h[..., _HI] ^ _SIGN32, h[..., _LO].copy())
         if self.dtype == np.dtype(np.uint64):
-            return self._split64(x)
+            h = _halves(x)
+            return (h[..., _HI].copy(), h[..., _LO].copy())
         if self.dtype == np.dtype(np.float64):
             u = x.view(np.uint64)
             s = np.uint64(0x8000000000000000)
@@ -108,13 +121,19 @@ class KeyCodec:
         if self.dtype == np.dtype(np.float32):
             e = words[0]
             return np.where(e & _SIGN32, e ^ _SIGN32, ~e).view(np.float32)
-        u = (words[0].astype(np.uint64) << np.uint64(32)) | words[1].astype(np.uint64)
-        if self.dtype == np.dtype(np.int64):
-            return (u ^ np.uint64(0x8000000000000000)).view(np.int64)
         if self.dtype == np.dtype(np.float64):
+            u = ((words[0].astype(np.uint64) << np.uint64(32))
+                 | words[1].astype(np.uint64))
             s = np.uint64(0x8000000000000000)
             return np.where(u & s, u ^ s, ~u).view(np.float64)
-        return u  # uint64
+        # int64 / uint64: write the halves in place
+        halves = np.empty(words[0].shape + (2,), np.uint32)
+        if self.dtype == np.dtype(np.int64):
+            np.bitwise_xor(words[0], _SIGN32, out=halves[..., _HI])
+        else:
+            halves[..., _HI] = words[0]
+        halves[..., _LO] = words[1]
+        return halves.view(self.dtype)[..., 0]
 
     def encode_jax(self, x):
         """Device-side encode: bitcast + sign-bias XOR, elementwise — XLA

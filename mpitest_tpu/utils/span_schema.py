@@ -65,6 +65,12 @@ SPAN_NAMES: dict[str, str] = {
                            "output) fingerprint, inside phase:verify"),
     "verify.check": ("device verifier of a result and its readback, "
                      "inside phase:verify (holds the wait for the sort)"),
+    # models/api.py — the parts of phase:encode on the one-device routes
+    "encode.words": ("host codec encode of a key array into its uint32 "
+                     "words, inside phase:encode"),
+    "pair.plan": ("host min/max of both words and the hi-word duplicate "
+                  "sniff of the one-device 64-bit route, inside "
+                  "phase:encode"),
     # scale-out vocabulary (ISSUE 7)
     "exchange_balance": ("negotiated exchange capacity + per-rank "
                          "send/recv byte balance (host count probe)"),

@@ -40,6 +40,32 @@ def test_order_preserved(dtype, rng):
     np.testing.assert_array_equal(lex, native)
 
 
+@pytest.mark.parametrize("layout", ["strided", "2d", "big_endian", "one"])
+@pytest.mark.parametrize("dtype", [np.int64, np.uint64])
+def test_64bit_words_of_any_layout(dtype, layout, rng):
+    """The 64-bit codecs read the keys' halves in place: a strided,
+    2-D, byte-swapped or one-key array gives the words of the shifted
+    and masked key, as contiguous arrays of their own."""
+    info = np.iinfo(np.dtype(dtype))
+    base = rng.integers(info.min, info.max, size=600, dtype=dtype,
+                        endpoint=True)
+    x = {"strided": base[::3], "2d": base.reshape(20, 30),
+         "big_endian": base.astype(np.dtype(dtype).newbyteorder(">")),
+         "one": base[:1]}[layout]
+    codec = codec_for(dtype)
+    hi, lo = codec.encode(x)
+    u = np.asarray(x, dtype=dtype).view(np.uint64)
+    if dtype == np.int64:
+        u = u ^ np.uint64(1 << 63)
+    np.testing.assert_array_equal(hi, (u >> np.uint64(32)).astype(np.uint32))
+    np.testing.assert_array_equal(lo, (u & np.uint64(0xFFFFFFFF))
+                                  .astype(np.uint32))
+    assert all(w.flags.c_contiguous and w.flags.owndata for w in (hi, lo))
+    back = codec.decode((hi.reshape(-1), lo.reshape(-1)))
+    assert back.dtype == np.dtype(dtype)
+    np.testing.assert_array_equal(back, np.asarray(x).reshape(-1))
+
+
 def test_sentinel_is_max():
     for dtype in DTYPES:
         codec = codec_for(dtype)

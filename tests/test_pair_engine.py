@@ -90,7 +90,7 @@ def test_mid_runs_in_vmem_fix(mesh1, rng, monkeypatch):
     NO fallback."""
     from mpitest_tpu.models import api
 
-    monkeypatch.setattr(api, "_host_hi_dup_sniff", lambda hi: False)
+    monkeypatch.setattr(api, "_host_hi_dup_sniff", lambda hi: 0)
     n_runs = -(-N // 16)
     hi = np.repeat(np.arange(n_runs, dtype=np.int64) * 37 + 5, 16)[:N]
     x = (hi << 32) | rng.integers(0, 2**32, size=N).astype(np.int64)
@@ -108,7 +108,7 @@ def test_mid_runs_residual_fallback(mesh1, rng, monkeypatch):
     depend on the sniff's sensitivity."""
     from mpitest_tpu.models import api
 
-    monkeypatch.setattr(api, "_host_hi_dup_sniff", lambda hi: False)
+    monkeypatch.setattr(api, "_host_hi_dup_sniff", lambda hi: 0)
     n_runs = -(-N // 24)
     hi = np.repeat(np.arange(n_runs, dtype=np.int64) * 37 + 5, 24)[:N]
     x = (hi << 32) | rng.integers(0, 2**32, size=N).astype(np.int64)

@@ -336,13 +336,15 @@ def _f64_host_input(x: jax.Array, tracer: Tracer) -> np.ndarray:
     return np.asarray(x)
 
 
+def _sniff_indices(n: int) -> np.ndarray:
+    """The hi-duplication sniff's sample: ~1024 evenly spaced keys."""
+    return np.linspace(0, n - 1, min(1024, n)).astype(np.int64)
+
+
 def _host_hi_dup_sniff(hi: np.ndarray) -> int:
     """Host twin of the hi-duplication sniff (same ~1024-key sample):
     the number of equal adjacent pairs in the sorted sample."""
-    n = hi.size
-    s = min(1024, n)
-    idx = np.linspace(0, n - 1, s).astype(np.int64)
-    samp = np.sort(hi[idx])
+    samp = np.sort(hi[_sniff_indices(hi.size)])
     return int(np.count_nonzero(samp[1:] == samp[:-1]))
 
 
@@ -354,6 +356,30 @@ def _compile_pair_sort(impl: str) -> Callable[..., Any]:
         return kernels.sort_two_words_bitonic(hi, lo, interpret=interpret)
 
     return jax.jit(program("pair_sort", "local_sort", f))
+
+
+@lru_cache(maxsize=4)
+def _compile_pair_split(dtype_name: str) -> Callable[..., Any]:
+    """Word split and plan of raw 64-bit integer keys shipped as their
+    own bytes (traced and called under a scoped ``jax.enable_x64``):
+    the ``(hi, lo)`` uint32 words, ``[min hi, max hi, min lo, max lo]``
+    and the hi-word sniff count over :func:`_sniff_indices`' sample (a
+    trace-time constant), so the plan is the host plan's exactly.  The
+    shift-and-convert split lowers to the TPU's own 64-bit halves (a
+    ``bitcast_convert_type`` to uint32 interleaves them first)."""
+    bias = codec_for(np.dtype(dtype_name)).raw_bias
+
+    def f(x):
+        u = jax.lax.bitcast_convert_type(x, jnp.uint64)
+        hi = (u >> jnp.uint64(32)).astype(jnp.uint32) ^ jnp.uint32(bias[0])
+        lo = u.astype(jnp.uint32) ^ jnp.uint32(bias[1])
+        # one device holds < 2^31 keys: int32 indices suffice
+        samp = jnp.sort(hi[_sniff_indices(hi.shape[0]).astype(np.int32)])
+        dups = jnp.sum(samp[1:] == samp[:-1], dtype=jnp.int32)
+        rng = jnp.stack([jnp.min(hi), jnp.max(hi), jnp.min(lo), jnp.max(lo)])
+        return hi, lo, rng, dups
+
+    return jax.jit(program("pair_split", "ingest", f))
 
 
 #: Engine codes returned by the fused device program (scalar, one fetch).
@@ -438,8 +464,8 @@ def _compile_pair_fused(dtype_name: str,
 
 def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
                      dtype: np.dtype, mesh: Mesh, tracer: Tracer,
-                     words_np: tuple[np.ndarray, ...] | None = None,
-                     ) -> tuple[jax.Array, ...]:
+                     verify_on: bool,
+                     ) -> tuple[tuple[jax.Array, ...], vfy.Fingerprint | None]:
     """Single-device 64-bit sort orchestration — the MSD-hybrid structure
     (VERDICT r3 #1), adaptive like the skew fallback:
 
@@ -455,7 +481,14 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
        (runs the sniff missed) falls back to ``lax.sort`` — correctness
        never depends on the sniff.
 
-    Returns the sorted device word tuple.
+    Device-resident input runs the whole tree in one fused program.
+    Host input is planned on the host's terms either way: integer keys
+    ship as their own bytes and are split and planned on the device
+    (:func:`_compile_pair_split`) while the host folds their fingerprint;
+    float64 keys are encoded and planned on the host.
+
+    Returns the sorted device word tuple and, for host input with
+    ``verify_on``, the input fingerprint (else None).
     """
     engine = _local_engine()
     impl = _bitonic_impl()
@@ -484,28 +517,51 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
                     "pair engine left residual runs (hi duplication the "
                     "sniff missed); lax fallback ran on device")
                 tracer.count("pair_residual_fallback", 1)
-            return (hi_s, lo_s)
-    if not is_device:
+            return (hi_s, lo_s), None
+    fp_in = None
+    dev = mesh.devices.flat[0]
+    if codec.raw_bias is not None:
+        # the keys' own bytes go to the device, which splits and plans
+        # them; the host folds the fingerprint from x (never the device
+        # copy, so the fold covers the transfer) while the DMA runs.
+        # The fold goes first: on a v5e a dispatch made while the
+        # transfer is still starting blocks the host for ~30 ms.
+        tracer.counters["encode_site"] = "device"
+        with jax.enable_x64(True):
+            with tracer.span("phase:device_put"):
+                raw = checked_device_put(np.asarray(x).reshape(-1), dev)
+            if verify_on:
+                with tracer.span("phase:verify"), \
+                        tracer.span("verify.fingerprint"):
+                    fp_in = vfy.fingerprint_raw(x, codec.raw_bias)
+            with tracer.span("phase:encode"), tracer.span("encode.words"):
+                hi, lo, rng, dups = traced_call(
+                    tracer, "pair_split", _compile_pair_split(dtype.name), raw)
+        del raw  # freed once the split has run: the peak stays the sort's
+        words = (hi, lo)
+        with tracer.span("phase:encode"), tracer.span("pair.plan"):
+            rng, dups = jax.device_get((rng, dups))
+    else:
         tracer.counters["encode_site"] = "host"
         with tracer.span("phase:encode"):
-            # caller may have encoded already (the verification
-            # fingerprint needs the words too — don't pay O(n) twice)
-            if words_np is None:
-                with tracer.span("encode.words"):
-                    words_np = codec.encode(np.asarray(x).reshape(-1))
+            with tracer.span("encode.words"):
+                words_np = codec.encode(np.asarray(x).reshape(-1))
             with tracer.span("pair.plan"):
                 rng = np.array([words_np[0].min(), words_np[0].max(),
                                 words_np[1].min(), words_np[1].max()])
                 dups = _host_hi_dup_sniff(words_np[0])
-        tracer.counters["pair_sniff_dups"] = dups
-        dup = dups > 0
+        if verify_on:
+            with tracer.span("phase:verify"), \
+                    tracer.span("verify.fingerprint"):
+                fp_in = vfy.fingerprint_host(words_np)
         with tracer.span("phase:device_put"):
-            dev = mesh.devices.flat[0]
             words = tuple(checked_device_put(w, dev) for w in words_np)
+    dups = int(dups)
+    tracer.counters["pair_sniff_dups"] = dups
     diffs = (int(rng[0]) ^ int(rng[1]), int(rng[2]) ^ int(rng[3]))
     if diffs == (0, 0):  # all keys identical: already sorted
         tracer.counters["local_engine"] = "constant"
-        return words
+        return words, fp_in
     for const_w, sort_w in ((0, 1), (1, 0)):
         if diffs[const_w] == 0:
             # the constant word never moves; 1-word engine on the other
@@ -513,13 +569,14 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
             with tracer.span("phase:sort"):
                 s_out = traced_call(
                     tracer, "local_1w", _compile_local(1, engine), words[sort_w])[0]
-            return (words[0], s_out) if sort_w == 1 else (s_out, words[1])
-    if dup:
+            return ((words[0], s_out) if sort_w == 1
+                    else (s_out, words[1])), fp_in
+    if dups > 0:
         tracer.counters["local_engine"] = "lax"
         tracer.count("pair_dup_reroute", 1)
         with tracer.span("phase:sort"):
             return traced_call(tracer, "local_2w_lax",
-                               _compile_local(2, "lax"), *words)
+                               _compile_local(2, "lax"), *words), fp_in
     tracer.counters["local_engine"] = "bitonic_pair"
     with tracer.span("phase:sort"):
         hi_s, lo_s, bad = traced_call(tracer, "pair_sort",
@@ -532,8 +589,8 @@ def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
         tracer.count("pair_residual_fallback", 1)
         with tracer.span("phase:sort"):
             return traced_call(tracer, "local_2w_lax",
-                               _compile_local(2, "lax"), *words)
-    return (hi_s, lo_s)
+                               _compile_local(2, "lax"), *words), fp_in
+    return (hi_s, lo_s), fp_in
 
 
 def _local_engine() -> str:
@@ -1482,24 +1539,11 @@ def _sort_impl(
             # 64-bit local path: the adaptive pair-engine orchestration
             # (constant-word shortcut / dup sniff / pair bitonic + run
             # fix-up / lax fallback) — see _local_pair_sort.
-            fp_in = None
-            pair_words = None
-            if not is_device:
-                # encode ONCE: the fingerprint and the pair sort share
-                # the words (a second O(n) encode pass would bill the
-                # verifier for work the sort needs anyway)
-                with tracer.span("phase:encode"), \
-                        tracer.span("encode.words"):
-                    pair_words = codec.encode(np.asarray(x).reshape(-1))
-                if verify_on:
-                    with tracer.span("phase:verify"), \
-                            tracer.span("verify.fingerprint"):
-                        fp_in = vfy.fingerprint_host(pair_words)
-            elif verify_on:
-                fp_in = _local_device_fp()
-            out = _local_pair_sort(x, is_device, codec, dtype, mesh, tracer,
-                                   words_np=pair_words)
-            return _finish_local(DistributedSortResult(out, N, dtype), fp_in)
+            fp_in = _local_device_fp() if is_device and verify_on else None
+            out, fp_host = _local_pair_sort(x, is_device, codec, dtype, mesh,
+                                            tracer, verify_on)
+            return _finish_local(DistributedSortResult(out, N, dtype),
+                                 fp_host if fp_in is None else fp_in)
         tracer.counters["local_engine"] = _resolve_local_engine(
             engine, codec.n_words, N)
         if is_device and _f64_known_broken(_device_platform(x), dtype, codec):
@@ -1531,7 +1575,8 @@ def _sort_impl(
         passthrough = (pchoice is not None and planner_mode == "on"
                        and verify_on
                        and pchoice.policy == "verify_passthrough")
-        if (not is_device and codec.raw_bias is not None and not passthrough
+        if (not is_device and codec.raw_bias is not None
+                and codec.n_words == 1 and not passthrough
                 and not engine.startswith("radix_pallas")):
             # one-word constant-XOR codec: ship the raw keys and let the
             # fused encode+sort program encode them on the device; the

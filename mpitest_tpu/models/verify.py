@@ -12,8 +12,9 @@ easily leave intact.  Here every ``sort()`` proves its own result:
 2. **Multiset fingerprint**: per encoded word, the XOR and the wrapping
    uint32 SUM over the *valid* keys, plus the exact count.  The input
    side is folded where the keys are first touched — chunk-by-chunk
-   during streamed ingest, from the raw keys for one-device int32/uint32
-   input that encodes on the device, during the host encode otherwise,
+   during streamed ingest, from the raw keys for one-device 32- and
+   64-bit integer input that encodes on the device, during the host
+   encode otherwise,
    or by one tiny on-device reduction for device-resident input — so no extra
    pass over the data ever happens.  The output side is computed by the
    same reduction over the result and compared host-side.  Truncation
@@ -85,17 +86,44 @@ def fingerprint_host(words: "tuple[np.ndarray, ...]") -> Fingerprint:
     )
 
 
-def fingerprint_raw(x: np.ndarray, bias: int) -> Fingerprint:
-    """Fingerprint of the encoded words ``x.view(uint32) ^ bias`` of
-    32-bit keys, folded from ``x`` itself without writing the words.
-    ``bias`` is 0 or the sign bit, so ``u ^ bias == u + bias`` (mod
-    2^32): the sum gains ``n * bias``, the XOR gains ``bias`` when the
-    count is odd, and the count is the same."""
-    assert bias in (0, 0x80000000), bias
-    fp = fingerprint_host((np.asarray(x).reshape(-1).view(np.uint32),))
-    n = fp.count
-    return Fingerprint(n, (fp.xors[0] ^ (bias if n & 1 else 0),),
-                       ((fp.sums[0] + n * bias) & _U32,))
+#: Keys per chunk of the 64-bit raw fold (2 MiB): its three reductions
+#: read each chunk from cache after the first, so memory is read once.
+_RAW_FOLD_CHUNK = 1 << 18
+
+
+def fingerprint_raw(x: np.ndarray, bias: "tuple[int, ...]") -> Fingerprint:
+    """Fingerprint of the encoded words of 32- or 64-bit integer keys,
+    folded from ``x`` itself without writing the words.  Word ``i``
+    (msw first) is the key's ``i``-th uint32 half XOR ``bias[i]``, and
+    each bias is 0 or the sign bit, so ``u ^ b == u + b`` (mod 2^32):
+    the sum gains ``n * b``, the XOR gains ``b`` when the count is odd,
+    and the count is the same.
+
+    64-bit keys need no strided pass over the halves: the uint64 XOR
+    holds both halves' XORs, the uint64 sum's low 32 bits are the low
+    halves' sum, and the uint32 sum over every half, less that, is the
+    high halves' sum (all mod 2^32)."""
+    assert len(bias) in (1, 2) and set(bias) <= {0, 0x80000000}, bias
+    u = np.ascontiguousarray(x).reshape(-1)
+    n = int(u.size)
+    if len(bias) == 1:
+        fp = fingerprint_host((u.view(np.uint32),))
+        xors, sums = fp.xors, fp.sums
+    else:
+        u64, u32 = u.view(np.uint64), u.view(np.uint32)
+        x64 = s64 = s32 = 0
+        for i in range(0, n, _RAW_FOLD_CHUNK):
+            c = u64[i:i + _RAW_FOLD_CHUNK]
+            x64 ^= int(np.bitwise_xor.reduce(c))
+            s64 += int(c.sum(dtype=np.uint64))
+            s32 += int(u32[2 * i:2 * (i + _RAW_FOLD_CHUNK)]
+                       .sum(dtype=np.uint32))
+        xors = (x64 >> 32, x64 & _U32)
+        sums = ((s32 - s64) & _U32, s64 & _U32)
+    odd = n & 1
+    return Fingerprint(n,
+                       tuple(v ^ (b if odd else 0) for v, b in zip(xors, bias)),
+                       tuple((s + n * b) & _U32 for s, b in zip(sums, bias)))
 
 
 # ---------------------------------------------------------------- records

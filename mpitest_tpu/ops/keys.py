@@ -65,12 +65,13 @@ class KeyCodec:
     #: (floats: np.max is NaN-poisoned and NaN payloads break max-key
     #: padding; the sentinel is the totalOrder maximum by construction).
     sentinel_pad: bool = False
-    #: ``b`` where the encode is the one-word constant XOR
-    #: ``x.view(uint32) ^ b`` (int32: the sign bit; uint32: 0), else
-    #: None.  Such keys can ship raw and encode on the device, with the
-    #: words' fingerprint folded from the raw keys
+    #: One ``b`` per word (msw first) where the encode is the constant
+    #: XOR of each uint32 half with its ``b`` (int32: the sign bit;
+    #: uint32: 0; int64: the sign bit on the high half; uint64: 0, 0),
+    #: else None.  Such keys can ship raw and encode on the device, with
+    #: the words' fingerprint folded from the raw keys
     #: (``verify.fingerprint_raw``).
-    raw_bias: int | None = None
+    raw_bias: tuple[int, ...] | None = None
 
     def _split64(self, u: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         return (
@@ -192,10 +193,11 @@ _CODECS = {
     np.dtype(np.int16): KeyCodec(np.dtype(np.int16), 1),
     np.dtype(np.uint16): KeyCodec(np.dtype(np.uint16), 1),
     np.dtype(np.int32): KeyCodec(np.dtype(np.int32), 1,
-                                 raw_bias=int(_SIGN32)),
-    np.dtype(np.uint32): KeyCodec(np.dtype(np.uint32), 1, raw_bias=0),
-    np.dtype(np.int64): KeyCodec(np.dtype(np.int64), 2),
-    np.dtype(np.uint64): KeyCodec(np.dtype(np.uint64), 2),
+                                 raw_bias=(int(_SIGN32),)),
+    np.dtype(np.uint32): KeyCodec(np.dtype(np.uint32), 1, raw_bias=(0,)),
+    np.dtype(np.int64): KeyCodec(np.dtype(np.int64), 2,
+                                 raw_bias=(int(_SIGN32), 0)),
+    np.dtype(np.uint64): KeyCodec(np.dtype(np.uint64), 2, raw_bias=(0, 0)),
     np.dtype(np.float32): KeyCodec(np.dtype(np.float32), 1, sentinel_pad=True),
     np.dtype(np.float64): KeyCodec(np.dtype(np.float64), 2, sentinel_pad=True),
 }

@@ -67,10 +67,14 @@ SPAN_NAMES: dict[str, str] = {
                      "inside phase:verify (holds the wait for the sort)"),
     # models/api.py — the parts of phase:encode on the one-device routes
     "encode.words": ("host codec encode of a key array into its uint32 "
-                     "words, inside phase:encode"),
-    "pair.plan": ("host min/max of both words and the hi-word duplicate "
-                  "sniff of the one-device 64-bit route, inside "
-                  "phase:encode"),
+                     "words, or on the one-device 64-bit integer route "
+                     "the dispatch of the device split, inside "
+                     "phase:encode"),
+    "pair.plan": ("min/max of both words and the hi-word duplicate "
+                  "sniff of the one-device 64-bit route: on the host, or "
+                  "for integer keys the readback of the device split's "
+                  "plan (holding the transfer the fold leaves uncovered), "
+                  "inside phase:encode"),
     # scale-out vocabulary (ISSUE 7)
     "exchange_balance": ("negotiated exchange capacity + per-rank "
                          "send/recv byte balance (host count probe)"),

@@ -137,6 +137,20 @@ def test_pair_local_fused_one_chip(one_chip):
         assert _compile_pair_fused("int64", "bitonic").lower(x).compile()
 
 
+@pytest.mark.parametrize("dtype", [jnp.int64, jnp.uint64])
+def test_pair_split_2p27_one_chip(one_chip, dtype):
+    """The split and plan of raw 64-bit host keys at 2^27 (1 GiB in,
+    two 512 MiB words out): it lowers, and writes the words straight
+    from the chip's 64-bit halves, with no interleaved temporary (a
+    ``bitcast_convert_type`` split holds 1 GiB of one)."""
+    from mpitest_tpu.models.api import _compile_pair_split
+
+    with jax.enable_x64(True):
+        x = jax.ShapeDtypeStruct((1 << 27,), dtype, sharding=one_chip)
+        c = _compile_pair_split(np.dtype(dtype).name).lower(x).compile()
+    assert c.memory_analysis().temp_size_in_bytes <= 1 << 20
+
+
 def test_bitonic_kernels_carry_names_and_layer(one_chip):
     """Every 1-word bitonic kernel is named (``bitonic.*``) and tagged
     ``layer="local_sort"``, so the chip trace can tell it apart; no

@@ -6,8 +6,9 @@ The generator is checked against a plain scalar port of YCSB's
 (the hottest key holds 1/ZETAN of the draws).  The sort runs the real
 orchestration on a 1-device CPU mesh with ``SORT_LOCAL_ENGINE=bitonic``,
 as ``tests/test_pair_engine.py`` does, so the same route as on the chip
-runs: host 2-word encode, the hi-word duplicate sniff, and on skewed
-keys the reroute to the variadic ``lax.sort``.
+runs: the raw keys shipped and split into their two words on the
+device, the hi-word duplicate sniff, and on skewed keys the reroute to
+the variadic ``lax.sort``.
 """
 
 import numpy as np
@@ -117,7 +118,7 @@ def test_ycsb_sort_takes_the_dup_reroute(ycsb_sorted):
     assert c["local_engine"] == "lax"
     assert c["pair_dup_reroute"] == 1
     assert c["pair_sniff_dups"] > 0
-    assert c["encode_site"] == "host"
+    assert c["encode_site"] == "device"
     assert not [k for k in c if "fallback" in k or "degraded" in k]
 
 

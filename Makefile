@@ -93,14 +93,6 @@
 #                  bit-identical, plan digest resumed:true, ZERO
 #                  external.run spans in the restart's trace, and the
 #                  manifest retired afterwards.
-#   make localsort-selftest — the fused local-engine gate (ISSUE 17):
-#                  interpret-mode bit-identity vs the lax engine across
-#                  every codec dtype x input class (kernel + api level,
-#                  ladder pinned off), one pallas_call per planned
-#                  radix pass, narrow key-width plans shorter than
-#                  full width, external-sort merge device-vs-host
-#                  bit-identical, and the radix_compact policy's pass
-#                  prediction honest (lying profiles stamp regret).
 #   make lint    — static analysis (ISSUE 4): sortlint (the project's
 #                  custom AST rules — env-knob registry, span schema,
 #                  SPMD safety, fault coverage, typed core), threadlint
@@ -129,8 +121,7 @@ PYTHON ?= python3
 .PHONY: test native native-encode chip-test telemetry-selftest \
     ingest-selftest fault-selftest multichip-selftest serve-selftest \
     chaos-serve-selftest planner-selftest external-selftest \
-    spillperf-selftest durability-selftest doctor-selftest \
-    localsort-selftest lint \
+    spillperf-selftest durability-selftest doctor-selftest lint \
     threadlint-fixtures cwarn-check typecheck tidy-check knob-docs \
     sanitize-selftest bench-history clean
 
@@ -297,24 +288,6 @@ durability-selftest:
 	rm -rf $(DURABILITY_TMP) && mkdir -p $(DURABILITY_TMP)
 	JAX_PLATFORMS=cpu \
 	    $(PYTHON) -u bench/durability_selftest.py --out $(DURABILITY_TMP)
-
-# The fused local-sort gate (ISSUE 17) — see bench/localsort_selftest.py.
-# The third local engine (fused per-pass radix kernel + device-side
-# merge-order kernel + planner key-width compaction) proven TPU-free:
-# interpret-mode bit-identity vs lax across every codec dtype x input
-# class (kernel AND api level, SORT_FALLBACK=0 so no silent degrade),
-# one pallas_call per planned pass, narrow plans shorter than full
-# width, the external-sort merge device-vs-host bit-identical, and the
-# radix_compact policy's pass prediction honest (lying profiles stamp
-# regret).  The final report pass schema-checks the emitted spans.
-LOCALSORT_TMP := /tmp/mpitest_localsort_selftest
-localsort-selftest:
-	rm -rf $(LOCALSORT_TMP) && mkdir -p $(LOCALSORT_TMP)
-	JAX_PLATFORMS=cpu \
-	    SORT_TRACE=$(LOCALSORT_TMP)/trace.jsonl \
-	    $(PYTHON) -u bench/localsort_selftest.py
-	$(PYTHON) -m mpitest_tpu.report --check --require-registered-spans \
-	    $(LOCALSORT_TMP)/trace.jsonl
 
 # The sort-doctor gate (ISSUE 16) — see bench/doctor_selftest.py.
 # Every DOCTOR_RULES pathology is planted deterministically and must be

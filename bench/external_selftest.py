@@ -225,10 +225,9 @@ def row_main() -> int:
     from mpitest_tpu.store import external
     from mpitest_tpu.utils import knobs
 
-    # ISSUE 17: the merge rounds' order computation is engine-knobbed
-    # (store/merge._order_for); the row says which engine ran so the
+    # ISSUE 17: the row says which local engine sorted the runs so the
     # trajectory column can attribute a throughput move to an engine
-    # flip.  Measured rows pin the host path.
+    # flip.  Measured rows pin lax.
     os.environ.setdefault("SORT_LOCAL_ENGINE", "lax")
 
     rng = np.random.default_rng(17)

@@ -204,53 +204,6 @@ def main() -> int:
             finally:
                 faults.install(None)
 
-    print("local-sort engine ladder (ISSUE 17): fused rung -> lax, loud")
-    # The third engine's rung in the fault grid: a fused-kernel failure
-    # must degrade ONLY the local engine (pallas -> lax, counted, plan-
-    # stamped) and re-run verified; with the ladder pinned off it must
-    # be a typed error — never a silent lax re-run.  Injected by
-    # monkeypatch (no faults.SITES entry: the generic grid above runs
-    # under engines where the fused path never traces, and a site that
-    # cannot fire everywhere would report FAULT NEVER FIRED).  Odd key
-    # counts: the fault fires at TRACE time, so these cells must miss
-    # every compile-cache entry the grid populated.
-    import jax
-
-    from mpitest_tpu.ops import radix_pallas as rp
-
-    orig_fused = rp.fused_radix_sort
-
-    def boom(*a, **kw):
-        raise jax.errors.JaxRuntimeError(
-            "INTERNAL: injected fused local-sort fault (drill)")
-
-    x_l = rng.integers(-(2**31), 2**31 - 1, size=31_337, dtype=np.int32)
-    rp.fused_radix_sort = boom
-    try:
-        tr = Tracer()
-        with knobs.scoped_env(SORT_LOCAL_ENGINE="radix_pallas",
-                              SORT_FALLBACK="1", SORT_MAX_RETRIES="0"):
-            got = sort(x_l, algorithm="radix", mesh=mesh, tracer=tr)
-        cell("fused local fault, fallback=1",
-             bool(np.array_equal(got, np.sort(x_l)))
-             and tr.counters.get("local_engine_degraded") == 1
-             and tr.counters.get("local_engine") == "lax",
-             f"degrades={tr.counters.get('local_engine_degraded')} "
-             f"engine={tr.counters.get('local_engine')}")
-        try:
-            with knobs.scoped_env(SORT_LOCAL_ENGINE="radix_pallas",
-                                  SORT_FALLBACK="0",
-                                  SORT_MAX_RETRIES="0"):
-                sort(rng.integers(0, 100, size=7_771, dtype=np.int32),
-                     algorithm="radix", mesh=mesh)
-            cell("fused local fault, fallback=0", False,
-                 "returned instead of raising typed")
-        except SortRetryExhausted:
-            cell("fused local fault, fallback=0", True,
-                 "SortRetryExhausted (typed, loud)")
-    finally:
-        rp.fused_radix_sort = orig_fused
-
     print("CLI exit codes: typed errors -> distinct nonzero exits")
     keyfile = "/tmp/fault_selftest_keys.txt"
     with open(keyfile, "w") as f:

@@ -69,10 +69,6 @@ DOCTOR_RULES: dict[str, str] = {
     "deadline_burn":
         "the serve SLO budget is burning: errors/expired deadlines "
         "or drifting p99 exceed the error-budget burn-rate allowance",
-    "local_sort_lax":
-        "the local sort dominates the critical path while the engine "
-        "resolved to generic lax.sort on a TPU backend — the fused "
-        "radix engine is one knob away",
     "spill_churn":
         "the external sort keeps re-spilling or crash-resuming — "
         "repeated integrity recoveries / manifest replays in one "
@@ -97,10 +93,6 @@ BREAKER_TRIP_GATE = 2
 BURN_RATE_GATE = 1.0
 BURN_MIN_REQUESTS = 8
 DEFAULT_SLO_TARGET_PCT = 99.9
-# local_sort_lax (ISSUE 17): the sort phase must both be the critical
-# path's dominant phase AND carry at least this fraction of the phase
-# wall before a lax-on-TPU local engine is worth a knob suggestion
-LOCAL_SORT_PHASE_GATE = 0.4
 # spill_churn (ISSUE 18): integrity recoveries + manifest resumes in
 # one trace before the spill volume itself is the suspect (one of
 # either is normal operation: a single blamed run, a single restart)
@@ -384,45 +376,6 @@ def _r_verify(ev: dict) -> Finding | None:
                    direction="lower (sampled or off once the fallback "
                              "ladder is trusted)",
                    value=round(ratio, 4), threshold=VERIFY_RATIO_GATE)
-
-
-@_rule("local_sort_lax")
-def _r_local_sort_lax(ev: dict) -> Finding | None:
-    tl = ev.get("timeline") or {}
-    if tl.get("critical_path_phase") != "sort":
-        return None
-    phases = tl.get("phases") or {}
-    sort_s = float(phases.get("sort", 0.0) or 0.0)
-    total = sum(float(v) for v in phases.values())
-    if total <= 0:
-        return None
-    frac = sort_s / total
-    if frac < LOCAL_SORT_PHASE_GATE:
-        return None
-    hits: list[str] = []
-    for attrs in ev.get("plans") or []:
-        eng = (attrs.get("decisions") or {}).get("engine") \
-            if isinstance(attrs.get("decisions"), dict) else None
-        actual = eng.get("actual") if isinstance(eng, dict) else None
-        if not isinstance(actual, dict):
-            continue
-        if (actual.get("local_engine") == "lax"
-                and actual.get("backend") == "tpu"):
-            hits.append("sort.plan: decisions.engine.actual"
-                        ".local_engine=lax backend=tpu")
-    if not hits:
-        return None
-    return Finding(
-        "local_sort_lax", "warn",
-        f"local sort is the critical-path phase ({100 * frac:.0f}% of "
-        "phase wall) and lowered through generic lax.sort on a TPU "
-        "backend",
-        evidence=[f"timeline: critical_path_phase=sort "
-                  f"({sort_s:.3f}s of {total:.3f}s)"] + hits[:3],
-        knob="SORT_LOCAL_ENGINE",
-        direction="set radix_pallas (fused per-pass local radix "
-                  "kernel; re-baseline on first TPU use)",
-        value=round(frac, 4), threshold=LOCAL_SORT_PHASE_GATE)
 
 
 @_rule("spill_churn")

@@ -46,6 +46,14 @@ from mpitest_tpu.models import planner as planner_mod
 from mpitest_tpu.models import radix_sort, sample_sort
 from mpitest_tpu.models import supervisor as supervision
 from mpitest_tpu.models import verify as vfy
+from mpitest_tpu.models.local import (
+    _f64_host_input,
+    _f64_known_broken,
+    _is_f64_lowering_gap,
+    _local_engine,
+    _resolve_local_engine,
+    sort_one_device,
+)
 from mpitest_tpu.models.supervisor import (  # re-exported: public errors
     ExchangeCapExceeded,
     SortFaultError,
@@ -61,12 +69,11 @@ from mpitest_tpu.models.ingest import (
     stream_to_mesh,
     use_stream,
 )
-from mpitest_tpu.ops import bitonic, kernels, radix_pallas
 from mpitest_tpu.ops.keys import KeyCodec, codec_for
 from mpitest_tpu.parallel.mesh import AXIS, key_sharding, make_mesh
 from mpitest_tpu.utils import io as kio
 from mpitest_tpu.utils import knobs
-from mpitest_tpu.utils.programs import layer, program, traced_call
+from mpitest_tpu.utils.programs import program, traced_call
 from mpitest_tpu.utils.trace import Tracer
 
 
@@ -190,7 +197,7 @@ def _resolve_exchange_engine(engine: str | None) -> str:
     ``SORT_EXCHANGE_ENGINE`` knob.  ``auto`` = the remote-DMA Pallas
     engine on real TPU backends, the XLA collective elsewhere; a forced
     ``pallas`` without a TPU runs the engine's interpreter form (same
-    convention as the bitonic local engine, :func:`_bitonic_impl`) —
+    convention as the bitonic local engine, ``local._bitonic_impl``) —
     the remote-copy hop itself then rides the bit-identical
     ``lax.all_to_all``, see ``ops/exchange.py``."""
     from mpitest_tpu.ops import exchange as xeng
@@ -266,404 +273,14 @@ def _compile_word_range(dtype_name: str) -> Callable[..., Any]:
     return jax.jit(program("word_range", "ingest", f))
 
 
-#: Memo: platforms whose device-side float64 encode failed to lower
-#: (XLA x64-rewrite gap, see sort() docstring) — later calls on the SAME
-#: platform route f64 device input straight to the host fallback instead
-#: of re-attempting a doomed (and slow) XLA compile every time.  Keyed
-#: per platform so one broken backend never degrades another (e.g. a CPU
-#: mesh in the same process, whose lowering is fine).
-_f64_encode_broken_platforms: set[str] = set()
-
-
-def _device_platform(x: jax.Array) -> str:
-    """Platform string of the device(s) holding ``x`` — the memo key for
-    the single-device path, whose encode compiles where ``x`` lives."""
-    try:
-        return next(iter(x.devices())).platform
-    except Exception:
-        return jax.default_backend()
-
-
 def _mesh_platform(mesh: Mesh) -> str:
     """Platform the mesh compiles for — the memo key for the sharded
-    path: the failing compile is ``_compile_encode_pad(..., mesh)``, so
+    path (``local._f64_encode_broken_platforms``): the failing compile
+    is ``_compile_encode_pad(..., mesh)``, so
     keying on the *input's* platform would both poison a healthy backend
     (CPU input, broken TPU mesh) and miss the memo (TPU input, same
     broken mesh)."""
     return mesh.devices.flat[0].platform
-
-#: Error-text markers of the known f64 lowering gap ("While rewriting
-#: computation to not contain X64 element types ... %bitcast-convert").
-#: Anything else (OOM, preemption) must re-raise, not masquerade as it.
-_F64_GAP_MARKERS = ("bitcast-convert", "X64 element types")
-
-
-def _f64_gap_applies(dtype: np.dtype, codec: KeyCodec) -> bool:
-    return dtype.kind == "f" and codec.n_words == 2
-
-
-def _is_f64_lowering_gap(e: Exception, dtype: np.dtype, codec: KeyCodec,
-                         platform: str) -> bool:
-    """True iff ``e`` is the known f64 device-encode lowering gap for a
-    2-word float dtype; memoizes the verdict for later calls on the same
-    platform.  The markers are fragments of ONE message and must all be
-    present — a different x64-rewrite failure or an unrelated bitcast
-    error is not this gap and must re-raise."""
-    if not _f64_gap_applies(dtype, codec):
-        return False
-    msg = str(e)
-    if not all(m in msg for m in _F64_GAP_MARKERS):
-        return False
-    _f64_encode_broken_platforms.add(platform)
-    return True
-
-
-def _f64_known_broken(platform: str, dtype: np.dtype,
-                      codec: KeyCodec) -> bool:
-    """Memoized verdict: ``platform`` already tripped the f64 gap."""
-    return (_f64_gap_applies(dtype, codec)
-            and platform in _f64_encode_broken_platforms)
-
-
-def _f64_host_input(x: jax.Array, tracer: Tracer) -> np.ndarray:
-    """Engage the documented f64 host fallback: tracer breadcrumbs plus
-    the host copy of the device array."""
-    tracer.verbose(
-        "device-side float64 encode unsupported by this backend; "
-        "falling back to one host round-trip"
-    )
-    tracer.count("f64_host_fallback", 1)
-    return np.asarray(x)
-
-
-def _sniff_indices(n: int) -> np.ndarray:
-    """The hi-duplication sniff's sample: ~1024 evenly spaced keys."""
-    return np.linspace(0, n - 1, min(1024, n)).astype(np.int64)
-
-
-def _host_hi_dup_sniff(hi: np.ndarray) -> int:
-    """Host twin of the hi-duplication sniff (same ~1024-key sample):
-    the number of equal adjacent pairs in the sorted sample."""
-    samp = np.sort(hi[_sniff_indices(hi.size)])
-    return int(np.count_nonzero(samp[1:] == samp[:-1]))
-
-
-@lru_cache(maxsize=4)
-def _compile_pair_sort(impl: str) -> Callable[..., Any]:
-    interpret = impl == "bitonic_interpret"
-
-    def f(hi, lo):
-        return kernels.sort_two_words_bitonic(hi, lo, interpret=interpret)
-
-    return jax.jit(program("pair_sort", "local_sort", f))
-
-
-@lru_cache(maxsize=4)
-def _compile_pair_split(dtype_name: str) -> Callable[..., Any]:
-    """Word split and plan of raw 64-bit integer keys shipped as their
-    own bytes (traced and called under a scoped ``jax.enable_x64``):
-    the ``(hi, lo)`` uint32 words, ``[min hi, max hi, min lo, max lo]``
-    and the hi-word sniff count over :func:`_sniff_indices`' sample (a
-    trace-time constant), so the plan is the host plan's exactly.  The
-    shift-and-convert split lowers to the TPU's own 64-bit halves (a
-    ``bitcast_convert_type`` to uint32 interleaves them first)."""
-    bias = codec_for(np.dtype(dtype_name)).raw_bias
-
-    def f(x):
-        u = jax.lax.bitcast_convert_type(x, jnp.uint64)
-        hi = (u >> jnp.uint64(32)).astype(jnp.uint32) ^ jnp.uint32(bias[0])
-        lo = u.astype(jnp.uint32) ^ jnp.uint32(bias[1])
-        # one device holds < 2^31 keys: int32 indices suffice
-        samp = jnp.sort(hi[_sniff_indices(hi.shape[0]).astype(np.int32)])
-        dups = jnp.sum(samp[1:] == samp[:-1], dtype=jnp.int32)
-        rng = jnp.stack([jnp.min(hi), jnp.max(hi), jnp.min(lo), jnp.max(lo)])
-        return hi, lo, rng, dups
-
-    return jax.jit(program("pair_split", "ingest", f))
-
-
-#: Engine codes returned by the fused device program (scalar, one fetch).
-_PAIR_CODES = {0: "constant", 1: "bitonic_1w1", 2: "bitonic_1w0",
-               3: "lax", 4: "bitonic_pair", 5: "bitonic_pair+lax_fallback"}
-
-
-@lru_cache(maxsize=8)
-def _compile_pair_fused(dtype_name: str,
-                        impl: str) -> Callable[..., Any]:
-    """ONE-dispatch device program for 2-word device-resident local
-    sorts: encode + range/dup planning + a ``lax.cond`` tree selecting
-    constant-word 1-word engine / variadic ``lax.sort`` / pair engine
-    (with its residual fallback folded in as a nested cond) — every
-    branch returns the same shapes, so the whole adaptive decision runs
-    on device.  Rationale: each extra dispatch pays a fixed host round
-    trip; on the remote chip of rounds 1-5 that was ~0.15-0.2 s, larger
-    than the pair engine's entire kernel-level win at 2^27 — the
-    host-orchestrated version measured SLOWER end-to-end than the
-    single-jit lax path despite a 1.4x faster device sort."""
-    from jax import lax as jlax
-
-    codec = codec_for(np.dtype(dtype_name))
-    interpret = impl == "bitonic_interpret"
-
-    def lax2w(hi, lo):
-        out = jlax.sort([hi, lo], num_keys=2, is_stable=False)
-        return out[0], out[1]
-
-    def one_w(w):
-        return kernels.local_sort((w,), engine=impl)[0]
-
-    def f(x):
-        with layer("ingest"):
-            hi, lo = codec.encode_jax(x.reshape(-1))
-        d0 = jnp.min(hi) ^ jnp.max(hi)
-        d1 = jnp.min(lo) ^ jnp.max(lo)
-        n = hi.shape[0]
-        s = min(1024, n)
-        if s > 1:
-            stride = -(-(n - 1) // (s - 1))  # ceil: sample stays <= s picks
-            s_eff = (n - 1) // stride + 1
-            start = (n - 1) - (s_eff - 1) * stride
-            samp = jlax.sort(
-                [jlax.slice(hi, (start,),
-                            (start + (s_eff - 1) * stride + 1,), (stride,))],
-                num_keys=1, is_stable=False)[0]
-            dup = jnp.any(samp[1:] == samp[:-1])
-        else:
-            dup = jnp.zeros((), bool)
-
-        def b_both(h, l):   # both words constant: already sorted
-            return h, l, jnp.int32(0)
-
-        def b_hic(h, l):    # hi constant: 1-word engine on lo
-            return h, one_w(l), jnp.int32(1)
-
-        def b_loc(h, l):    # lo constant: 1-word engine on hi
-            return one_w(h), l, jnp.int32(2)
-
-        def b_lax(h, l):    # sniffed hi duplication: straight to lax
-            hs, ls = lax2w(h, l)
-            return hs, ls, jnp.int32(3)
-
-        def b_pair(h, l):
-            hs, ls, bad = kernels.sort_two_words_bitonic(
-                h, l, interpret=interpret)
-            hs, ls = jlax.cond(bad, lax2w, lambda a, b: (hs, ls), h, l)
-            return hs, ls, jnp.where(bad, jnp.int32(5), jnp.int32(4))
-
-        def b_var(h, l):    # both words vary: sniff decides
-            return jlax.cond(dup, b_lax, b_pair, h, l)
-
-        return jlax.cond(
-            d0 == jnp.uint32(0),
-            lambda a, b: jlax.cond(d1 == jnp.uint32(0), b_both, b_hic, a, b),
-            lambda a, b: jlax.cond(d1 == jnp.uint32(0), b_loc, b_var, a, b),
-            hi, lo)
-
-    return jax.jit(program("pair_fused", "local_sort", f))
-
-
-def _local_pair_sort(x: Any, is_device: bool, codec: KeyCodec,
-                     dtype: np.dtype, mesh: Mesh, tracer: Tracer,
-                     verify_on: bool,
-                     ) -> tuple[tuple[jax.Array, ...], vfy.Fingerprint | None]:
-    """Single-device 64-bit sort orchestration — the MSD-hybrid structure
-    (VERDICT r3 #1), adaptive like the skew fallback:
-
-    1. constant-word shortcut: a word with zero range never needs
-       sorting — narrow-range int64 (values inside one 32-bit window,
-       common in practice) collapses to the plain 1-word bitonic engine
-       on the other word, ~2x faster again than the pair engine.
-    2. hi-duplication sniff: heavy duplication would leave equal-hi runs
-       longer than the pair engine's fixed run fix-up depth — route to
-       the variadic ``lax.sort`` up front (no wasted phase).
-    3. pair engine (``kernels.sort_two_words_bitonic``): key+payload
-       bitonic by hi + segmented odd-even run fix-up.  The residual flag
-       (runs the sniff missed) falls back to ``lax.sort`` — correctness
-       never depends on the sniff.
-
-    Device-resident input runs the whole tree in one fused program.
-    Host input is planned on the host's terms either way: integer keys
-    ship as their own bytes and are split and planned on the device
-    (:func:`_compile_pair_split`) while the host folds their fingerprint;
-    float64 keys are encoded and planned on the host.
-
-    Returns the sorted device word tuple and, for host input with
-    ``verify_on``, the input fingerprint (else None).
-    """
-    engine = _local_engine()
-    impl = _bitonic_impl()
-    if is_device and _f64_known_broken(_device_platform(x), dtype, codec):
-        x, is_device = _f64_host_input(x, tracer), False
-    if is_device:
-        # Device-resident input: the whole adaptive tree runs in ONE
-        # fused dispatch (see _compile_pair_fused) — host-side branching
-        # would cost a host round trip per decision.
-        try:
-            with tracer.span("phase:sort"):
-                hi_s, lo_s, code = traced_call(
-                    tracer, "pair_fused", _compile_pair_fused(dtype.name, impl), x)
-                code = int(code)
-        except jax.errors.JaxRuntimeError as e:
-            if not _is_f64_lowering_gap(e, dtype, codec, _device_platform(x)):
-                raise
-            x, is_device = _f64_host_input(x, tracer), False
-        else:
-            tracer.counters["local_engine"] = _PAIR_CODES[code]
-            tracer.counters["encode_site"] = "device"
-            if code == 3:
-                tracer.count("pair_dup_reroute", 1)
-            elif code == 5:
-                tracer.verbose(
-                    "pair engine left residual runs (hi duplication the "
-                    "sniff missed); lax fallback ran on device")
-                tracer.count("pair_residual_fallback", 1)
-            return (hi_s, lo_s), None
-    fp_in = None
-    dev = mesh.devices.flat[0]
-    if codec.raw_bias is not None:
-        # the keys' own bytes go to the device, which splits and plans
-        # them; the host folds the fingerprint from x (never the device
-        # copy, so the fold covers the transfer) while the DMA runs.
-        # The fold goes first: on a v5e a dispatch made while the
-        # transfer is still starting blocks the host for ~30 ms.
-        tracer.counters["encode_site"] = "device"
-        with jax.enable_x64(True):
-            with tracer.span("phase:device_put"):
-                raw = checked_device_put(np.asarray(x).reshape(-1), dev)
-            if verify_on:
-                with tracer.span("phase:verify"), \
-                        tracer.span("verify.fingerprint"):
-                    fp_in = vfy.fingerprint_raw(x, codec.raw_bias)
-            with tracer.span("phase:encode"), tracer.span("encode.words"):
-                hi, lo, rng, dups = traced_call(
-                    tracer, "pair_split", _compile_pair_split(dtype.name), raw)
-        del raw  # freed once the split has run: the peak stays the sort's
-        words = (hi, lo)
-        with tracer.span("phase:encode"), tracer.span("pair.plan"):
-            rng, dups = jax.device_get((rng, dups))
-    else:
-        tracer.counters["encode_site"] = "host"
-        with tracer.span("phase:encode"):
-            with tracer.span("encode.words"):
-                words_np = codec.encode(np.asarray(x).reshape(-1))
-            with tracer.span("pair.plan"):
-                rng = np.array([words_np[0].min(), words_np[0].max(),
-                                words_np[1].min(), words_np[1].max()])
-                dups = _host_hi_dup_sniff(words_np[0])
-        if verify_on:
-            with tracer.span("phase:verify"), \
-                    tracer.span("verify.fingerprint"):
-                fp_in = vfy.fingerprint_host(words_np)
-        with tracer.span("phase:device_put"):
-            words = tuple(checked_device_put(w, dev) for w in words_np)
-    dups = int(dups)
-    tracer.counters["pair_sniff_dups"] = dups
-    diffs = (int(rng[0]) ^ int(rng[1]), int(rng[2]) ^ int(rng[3]))
-    if diffs == (0, 0):  # all keys identical: already sorted
-        tracer.counters["local_engine"] = "constant"
-        return words, fp_in
-    for const_w, sort_w in ((0, 1), (1, 0)):
-        if diffs[const_w] == 0:
-            # the constant word never moves; 1-word engine on the other
-            tracer.counters["local_engine"] = f"bitonic_1w{sort_w}"
-            with tracer.span("phase:sort"):
-                s_out = traced_call(
-                    tracer, "local_1w", _compile_local(1, engine), words[sort_w])[0]
-            return ((words[0], s_out) if sort_w == 1
-                    else (s_out, words[1])), fp_in
-    if dups > 0:
-        tracer.counters["local_engine"] = "lax"
-        tracer.count("pair_dup_reroute", 1)
-        with tracer.span("phase:sort"):
-            return traced_call(tracer, "local_2w_lax",
-                               _compile_local(2, "lax"), *words), fp_in
-    tracer.counters["local_engine"] = "bitonic_pair"
-    with tracer.span("phase:sort"):
-        hi_s, lo_s, bad = traced_call(tracer, "pair_sort",
-                                      _compile_pair_sort(impl), *words)
-        bad = bool(bad)
-    if bad:
-        tracer.verbose(
-            "pair engine left residual runs (hi duplication the sniff "
-            "missed); falling back to lax.sort")
-        tracer.count("pair_residual_fallback", 1)
-        with tracer.span("phase:sort"):
-            return traced_call(tracer, "local_2w_lax",
-                               _compile_local(2, "lax"), *words), fp_in
-    return (hi_s, lo_s), fp_in
-
-
-def _local_engine() -> str:
-    """Local (single-device) sort engine: the Pallas bitonic kernel
-    (``ops/bitonic.py``) on real TPU backends for large one-word keys —
-    measured 2.0-4.2x ``lax.sort`` at 2^26 on v5e post-relayout (r5) —
-    ``lax.sort`` otherwise.  ``SORT_LOCAL_ENGINE={auto,bitonic,lax,
-    radix_pallas,radix_pallas_interpret}`` overrides; the fused radix
-    family (``ops/radix_pallas.py``) is never chosen by ``auto`` until
-    the first real-TPU re-baseline (the kernels have only ever run
-    under interpret)."""
-    return supervision.local_engine_knob()
-
-
-def _use_bitonic(engine: str, n_words: int, n: int) -> bool:
-    if n_words > 2:
-        return False  # wider keys keep the variadic lax.sort
-    if engine == "bitonic":
-        return True
-    return engine == "auto" and jax.default_backend() == "tpu" and (
-        n >= (1 << bitonic.MIN_SORT_LOG2)
-    )
-
-
-def _bitonic_impl() -> str:
-    """Execution form of the bitonic engine: real Mosaic kernels on TPU
-    backends, the Pallas interpreter elsewhere (CPU-mesh tests / forced
-    ``SORT_LOCAL_ENGINE=bitonic`` without a TPU)."""
-    return "bitonic" if jax.default_backend() == "tpu" else "bitonic_interpret"
-
-
-def _use_fused(engine: str, n_words: int, n: int) -> bool:
-    """True when the fused radix family can take this dispatch: the
-    knob asked for it AND the key/size fit the kernel's VMEM-resident
-    envelope.  Never True for ``auto`` — the fused kernels have only
-    ever run under interpret, so auto stays bitonic-on-TPU until the
-    first real-TPU re-baseline."""
-    return (engine.startswith("radix_pallas")
-            and n_words <= radix_pallas.FUSED_MAX_WORDS
-            and n <= radix_pallas.FUSED_MAX_ELEMS)
-
-
-def _resolve_local_engine(engine: str, n_words: int, n: int) -> str:
-    """Concrete local-sort engine for one dispatch: the fused radix
-    family resolves to real Mosaic on TPU backends and the Pallas
-    interpreter elsewhere (and to ``lax`` when the dispatch falls
-    outside its envelope); the bitonic family keeps its PR 5 rules;
-    everything else is ``lax``."""
-    if engine.startswith("radix_pallas"):
-        if not _use_fused(engine, n_words, n):
-            return "lax"
-        if engine == "radix_pallas_interpret" or \
-                jax.default_backend() != "tpu":
-            return "radix_pallas_interpret"
-        return "radix_pallas"
-    if _use_bitonic(engine, n_words, n):
-        return _bitonic_impl()
-    return "lax"
-
-
-@lru_cache(maxsize=8)
-def _compile_local_device(dtype_name: str,
-                          engine: str = "auto") -> Callable[..., Any]:
-    """1-device program for device-resident input: fused encode + sort."""
-    codec = codec_for(np.dtype(dtype_name))
-
-    def f(x):
-        with layer("ingest"):
-            words = codec.encode_jax(x)
-        eng = _resolve_local_engine(engine, len(words), x.size)
-        return kernels.local_sort(words, engine=eng)
-
-    return jax.jit(program("local_device_sort", "local_sort", f))
 
 
 @lru_cache(maxsize=16)
@@ -703,38 +320,11 @@ def _compile_encode_pad(dtype_name: str, total: int,
     return jax.jit(body, out_shardings=key_sharding(mesh))
 
 
-@lru_cache(maxsize=16)
-def _compile_local(n_words: int,
-                   engine: str = "auto",
-                   widths: tuple[int, ...] | None = None,
-                   ) -> Callable[..., Any]:
-    """The 1-device specialization: both distributed algorithms degenerate
-    to the local kernel when the mesh has a single device (no exchange, no
-    splitters, no digit passes) — one fused local sort (the Pallas
-    bitonic engine for large 1-word keys on TPU, else ``lax.sort``).
-    The reference run with ``-np 1`` still pays its full protocol; here
-    the program specializes to what the hardware actually needs.
-
-    ``widths`` (per-word significant-bit widths, msw first) compacts the
-    fused radix engine's pass plan for range-narrow inputs; quantizing
-    the host-measured diffs to bit widths keeps this cache's key
-    vocabulary small (<= 33 values per word)."""
-    def f(*words):
-        eng = _resolve_local_engine(engine, len(words), words[0].size)
-        diffs = None
-        if widths is not None and eng.startswith("radix_pallas"):
-            diffs = tuple((1 << w) - 1 for w in widths)
-        return kernels.local_sort(words, engine=eng, diffs=diffs)
-
-    return jax.jit(program("local_sort", "local_sort", f))
-
-
 @lru_cache(maxsize=64)
 def _compile_radix(mesh: Mesh, n_words: int, n: int, digit_bits: int,
                    cap: int, passes: int, pack: str, donate: bool = False,
                    fault_token: str = "",
-                   exchange_engine: str = "lax",
-                   local_engine: str = "lax") -> Callable[..., Any]:
+                   exchange_engine: str = "lax") -> Callable[..., Any]:
     # fault_token: unique per armed exchange fault (mpitest_tpu.faults) —
     # a poisoned trace gets its own cache entry and can never be served
     # to a clean dispatch.  "" = the shared clean compile.
@@ -743,7 +333,7 @@ def _compile_radix(mesh: Mesh, n_words: int, n: int, digit_bits: int,
     def f(*words):
         out, max_cnt = radix_sort.radix_sort_spmd(
             words, n_words, digit_bits, n_ranks, cap, passes, pack=pack,
-            exchange_engine=exchange_engine, local_engine=local_engine,
+            exchange_engine=exchange_engine,
         )
         return out, max_cnt
 
@@ -759,8 +349,7 @@ def _compile_radix(mesh: Mesh, n_words: int, n: int, digit_bits: int,
             # forces pack to the engine's impl via _engine_pack, but e.g.
             # radix_pass_states-style callers can pass pack="xla" with a
             # pallas engine, whose transport still runs pallas kernels).
-            check_vma=(pack == "xla" and exchange_engine == "lax"
-                       and local_engine == "lax"),
+            check_vma=(pack == "xla" and exchange_engine == "lax"),
         ),
         # Donation: the input word shards alias the output word shards
         # (same shape/dtype/sharding), so HBM holds ONE copy of the keys
@@ -1109,9 +698,8 @@ def _finish_plan(tracer: Tracer, plan: "plan_mod.SortPlan | None") -> None:
             plan.actual("engine", local_engine=str(engine))
         else:
             plan.decide("engine", chosen=str(engine))
-        # backend rides the engine actual so the doctor's local-sort
-        # rule can tell "lax on TPU" (a knob away from the fused
-        # engine) from "lax on CPU" (nothing to suggest)
+        # backend rides the engine actual: the same engine name means
+        # a different kernel on a TPU and under the CPU interpreter
         plan.actual("engine", backend=str(jax.default_backend()))
     fallbacks = (int(c.get("pair_residual_fallback", 0))
                  - int(getattr(plan, "fallbacks_base", 0)))
@@ -1378,13 +966,9 @@ def _sort_impl(
     eng0 = _resolve_exchange_engine(exchange_engine)
     _eng = {"v": eng0}
     tracer.counters["exchange_engine"] = eng0
-    # ---- local-sort engine (ISSUE 17): same ONE-mutable-state shape.
-    # _leng holds the KNOB-level value ("radix_pallas" family / bitonic
-    # / auto / lax); each dispatch resolves it per key-width and size
-    # via _resolve_local_engine.  The ladder may degrade the fused
-    # family to lax without touching the exchange engine.
+    # ---- local-sort engine: the KNOB-level value (auto / bitonic /
+    # lax); each dispatch resolves it per key width and size.
     leng0 = _local_engine()
-    _leng = {"v": leng0}
 
     # ---- plan provenance (ISSUE 12): the run's decision record ------
     plan = tracer.plan if isinstance(tracer.plan, plan_mod.SortPlan) \
@@ -1482,28 +1066,37 @@ def _sort_impl(
                 f"fingerprint={bool(fp_ok)})")
         return bool(sorted_ok and fp_ok)
 
-    def _local_device_fp():
-        """Input fingerprint for device-resident single-device input:
-        one tiny fused encode+reduce dispatch.  The known f64 encode
-        lowering gap degrades to sortedness-only verification (fp None)
-        rather than breaking the sort."""
-        try:
-            return vfy.fingerprint_device_input(x.reshape(-1), dtype, tracer)
-        except jax.errors.JaxRuntimeError:
-            tracer.verbose("input fingerprint unavailable on this backend; "
-                           "verifying sortedness only")
-            return None
+    #: the planner's rung zero (ISSUE 14): the profile read fully
+    #: sorted, so the encoded input words ARE a sort candidate
+    passthrough_on = (pchoice is not None and planner_mode == "on"
+                      and verify_on
+                      and pchoice.policy == "verify_passthrough")
 
-    def _finish_local(res_l, fp_l):
+    def _passthrough(words, fp) -> bool:
+        """One verify dispatch in place of the sort: True when the input
+        words pass it.  A miss costs exactly the verify (typed as the
+        planner decision's regret) before the sort runs."""
+        if _check_result(DistributedSortResult(words, N, dtype), fp):
+            tracer.count("planner_passthrough", 1)
+            if plan is not None:
+                plan.decide("ladder", chosen="passthrough")
+            return True
+        tracer.count("planner_passthrough_miss", 1)
+        if plan is not None:
+            plan.actual("planner", misses=1)
+        return False
+
+    def _finish_local(res_l, fp_l, verified: bool):
         """Verify-and-return for the single-device paths.  No ladder
         here (the degradation machinery targets the distributed
-        dispatch); a verification failure is a typed error."""
-        if plan is not None:
+        dispatch); a verification failure is a typed error.  A
+        ``verified`` result is the planner's passthrough hit."""
+        if plan is not None and not verified:
             # single-device runs have no distributed ladder: the rung
             # is the fused local path itself (the engine decision is
             # defaulted from the counters at _finish_plan time)
             plan.decide("ladder", chosen="local")
-        if verify_on and not _check_result(res_l, fp_l):
+        if verify_on and not verified and not _check_result(res_l, fp_l):
             raise SortIntegrityError(
                 "single-device sort result failed verification")
         if return_result:
@@ -1511,135 +1104,16 @@ def _sort_impl(
         with tracer.span("phase:decode"):
             return res_l.to_numpy(tracer=tracer)
 
-    if staged is not None and n_ranks == 1:
-        # 1-device mesh with pre-staged words: one fused local sort of
-        # the padded shard (pads replicate the max key, so they sort to
-        # the tail past n_valid — same contract as the host local path).
-        # The streamed ingest already folded per-word diffs, so the
-        # fused radix engine gets its compacted pass plan for free.
-        s_widths = (tuple(int(d).bit_length() for d in staged.word_diffs)
-                    if leng0.startswith("radix_pallas")
-                    and staged.word_diffs is not None else None)
-        tracer.counters["local_engine"] = _resolve_local_engine(
-            leng0, codec.n_words, staged.words[0].size)
-        tracer.counters["encode_site"] = "host"
-        with tracer.span("phase:sort"):
-            out = traced_call(
-                tracer, "local",
-                _compile_local(codec.n_words, leng0, s_widths),
-                *staged.words)
-        return _finish_local(DistributedSortResult(out, N, dtype),
-                             staged.fingerprint if verify_on else None)
-
-    if staged is None and n_ranks == 1 and algorithm in ("radix", "sample"):
-        engine = leng0
-        if (codec.n_words == 2 and engine != "lax"
-                and N >= (1 << bitonic.MIN_SORT_LOG2)
-                and (engine == "bitonic" or jax.default_backend() == "tpu")):
-            # 64-bit local path: the adaptive pair-engine orchestration
-            # (constant-word shortcut / dup sniff / pair bitonic + run
-            # fix-up / lax fallback) — see _local_pair_sort.
-            fp_in = _local_device_fp() if is_device and verify_on else None
-            out, fp_host = _local_pair_sort(x, is_device, codec, dtype, mesh,
-                                            tracer, verify_on)
-            return _finish_local(DistributedSortResult(out, N, dtype),
-                                 fp_host if fp_in is None else fp_in)
-        tracer.counters["local_engine"] = _resolve_local_engine(
-            engine, codec.n_words, N)
-        if is_device and _f64_known_broken(_device_platform(x), dtype, codec):
-            x, is_device = _f64_host_input(x, tracer), False
-        fp_in = None
-        if is_device:
-            if verify_on:
-                fp_in = _local_device_fp()
-            try:
-                with tracer.span("phase:sort"):
-                    out = traced_call(
-                        tracer, "local_device",
-                        _compile_local_device(dtype.name, engine),
-                        x.reshape(-1))
-            except jax.errors.JaxRuntimeError as e:
-                # float64 device-side encode needs a f64->u32 bitcast some
-                # TPU stacks cannot lower (XLA's x64-rewrite pass lacks the
-                # rule; int64 works).  Degrade to one documented host
-                # round-trip instead of an internal compiler error; every
-                # other runtime failure re-raises untouched.
-                if not _is_f64_lowering_gap(e, dtype, codec,
-                                            _device_platform(x)):
-                    raise
-                x, is_device = _f64_host_input(x, tracer), False
-            else:
-                tracer.counters["encode_site"] = "device"
-        # the planner's passthrough rung (below) and the fused radix
-        # engine's pass widths need the encoded host words
-        passthrough = (pchoice is not None and planner_mode == "on"
-                       and verify_on
-                       and pchoice.policy == "verify_passthrough")
-        if (not is_device and codec.raw_bias is not None
-                and codec.n_words == 1 and not passthrough
-                and not engine.startswith("radix_pallas")):
-            # one-word constant-XOR codec: ship the raw keys and let the
-            # fused encode+sort program encode them on the device; the
-            # host folds the words' fingerprint from the raw keys while
-            # the transfer and the sort run (it reads x, never the
-            # device copy, so the transfer stays covered)
-            tracer.counters["encode_site"] = "device"
-            with tracer.span("phase:device_put"):
-                raw = checked_device_put(x.reshape(-1), mesh.devices.flat[0])
-            with tracer.span("phase:sort"):
-                out = traced_call(
-                    tracer, "local_device",
-                    _compile_local_device(dtype.name, engine), raw)
-            if verify_on:
-                with tracer.span("phase:verify"), \
-                        tracer.span("verify.fingerprint"):
-                    fp_in = vfy.fingerprint_raw(x, codec.raw_bias)
-        elif not is_device:
-            tracer.counters["encode_site"] = "host"
-            with tracer.span("phase:encode"), tracer.span("encode.words"):
-                words_np = codec.encode(x.reshape(-1))
-            if verify_on:
-                with tracer.span("phase:verify"), \
-                        tracer.span("verify.fingerprint"):
-                    fp_in = vfy.fingerprint_host(words_np)
-            with tracer.span("phase:device_put"):
-                words = tuple(
-                    checked_device_put(w, mesh.devices.flat[0])
-                    for w in words_np
-                )
-            # planner rung zero, local edition (ISSUE 14): same contract
-            # as the distributed rung below — the profile read fully
-            # sorted, so the encoded input words ARE a sort candidate;
-            # one verify dispatch replaces the local sort when it
-            # passes, and a miss costs exactly the verify (typed as the
-            # planner decision's regret) before the sort runs.  This is
-            # the only 1-rank site the policy can reach: device-resident
-            # and staged inputs take no host profile, so the scorer
-            # already chose `static` for them.
-            if passthrough and fp_in is not None:
-                cand = DistributedSortResult(words, N, dtype)
-                if _check_result(cand, fp_in):
-                    tracer.count("planner_passthrough", 1)
-                    if plan is not None:
-                        plan.decide("ladder", chosen="passthrough")
-                    if return_result:
-                        return cand
-                    with tracer.span("phase:decode"):
-                        return cand.to_numpy(tracer=tracer)
-                tracer.count("planner_passthrough_miss", 1)
-                if plan is not None:
-                    plan.actual("planner", misses=1)
-            # fused-engine pass compaction: the host words are in hand,
-            # so one cheap max/min pass quantizes the per-word spread
-            # into the compile key's width vocabulary.
-            l_widths = (tuple(int(d).bit_length()
-                              for d in _word_diffs(words_np))
-                        if engine.startswith("radix_pallas") else None)
-            with tracer.span("phase:sort"):
-                out = traced_call(tracer, "local",
-                                  _compile_local(codec.n_words, engine,
-                                                 l_widths), *words)
-        return _finish_local(DistributedSortResult(out, N, dtype), fp_in)
+    if n_ranks == 1:
+        # one device: both algorithms degenerate to one local sort
+        # (models/local.py).  The passthrough reaches only host input:
+        # device-resident and staged inputs take no host profile, so the
+        # scorer already chose `static` for them.
+        out = sort_one_device(
+            x, codec, N, leng0, mesh.devices.flat[0], tracer, verify_on,
+            passthrough=_passthrough if passthrough_on else None)
+        return _finish_local(DistributedSortResult(out.words, N, dtype),
+                             out.fingerprint, out.verified)
 
     #: per-word max^min already known without touching the data again
     #: (streamed ingest folds it chunk-by-chunk); None = plan from
@@ -1925,13 +1399,8 @@ def _sort_impl(
         eng = _eng["v"]
         eff_pack, eff_align = _engine_pack(pack_impl, eng)
         tracer.counters["exchange_engine"] = eng
-        # Local engine inside the radix shards: only the fused family
-        # applies (the first pass's stable digit sort is a counting
-        # sort the fused kernel replaces 1:1); bitonic has no slot in
-        # the digit passes, so everything else stays lax.
-        leng = _resolve_local_engine(_leng["v"], codec.n_words, n)
-        radix_leng = leng if leng.startswith("radix_pallas") else "lax"
-        tracer.counters["local_engine"] = radix_leng
+        # the digit passes sort with lax.sort: bitonic has no slot there
+        tracer.counters["local_engine"] = "lax"
         if plan is not None:
             # keep a planner-predicted pass count (radix_compact) as
             # the prediction this decision is scored against; the
@@ -1961,8 +1430,7 @@ def _sort_impl(
         def attempt(c: int):
             fn = _compile_radix(mesh, codec.n_words, n, db, c, passes,
                                 eff_pack, donate, sup.arm_exchange(),
-                                exchange_engine=eng,
-                                local_engine=radix_leng)
+                                exchange_engine=eng)
             with tracer.span("phase:sort"):
                 out, max_cnt = sup.dispatch(
                     "radix_spmd", fn, live_words, on_retry=mark_dead,
@@ -2055,7 +1523,7 @@ def _sort_impl(
         elif plan is not None:
             plan.decide("cap", chosen=cap_start, trigger="off",
                         cap=cap_start, fair=fair)
-        spmd_engine = _resolve_local_engine(_leng["v"], codec.n_words, n)
+        spmd_engine = _resolve_local_engine(leng0, codec.n_words, n)
         tracer.counters["local_engine"] = spmd_engine
 
         last_need = {"v": None}
@@ -2144,25 +1612,14 @@ def _sort_impl(
     # failure or repeated verification failure moves down.  The ladder
     # ends in a VERIFIED result or a typed error — never a silent wrong
     # answer.
-    fused_local = leng0.startswith("radix_pallas")
-    #: lower-rung local engine: the fused family degrades to lax with
-    #: the rest of the rung; the bitonic/auto/lax values ride every
-    #: rung unchanged (their fallback story predates this ladder).
-    lower_leng = "lax" if fused_local else leng0
-    rungs: list[tuple[str, str, str]] = [(algorithm, eng0, leng0)]
+    rungs: list[tuple[str, str]] = [(algorithm, eng0)]
     if supervision.fallback_enabled():
-        if fused_local:
-            # the LOCAL engine rung (ISSUE 17): a broken fused radix
-            # kernel must not cost the exchange engine or the
-            # algorithm — re-run the same rung on lax local sorts
-            rungs.append((algorithm, eng0, "lax"))
         if eng0 != "lax":
             # the engine rung: a broken pallas kernel must not cost the
             # requested ALGORITHM — re-run it on the XLA collective
-            rungs.append((algorithm, "lax", lower_leng))
-        rungs.append(("sample" if algorithm == "radix" else "radix",
-                      "lax", lower_leng))
-        rungs.append(("host", "lax", "lax"))
+            rungs.append((algorithm, "lax"))
+        rungs.append(("sample" if algorithm == "radix" else "radix", "lax"))
+        rungs.append(("host", "lax"))
     if plan is not None:
         plan.decide("ladder", chosen=rungs[0][0])
 
@@ -2185,37 +1642,12 @@ def _sort_impl(
     # ordinary ladder below sorts for real.  Only in `on` mode and only
     # with the verifier armed: without it the profile is a guess, and a
     # guess must not skip the sort.
-    if (pchoice is not None and planner_mode == "on" and verify_on
-            and pchoice.policy == "verify_passthrough"
-            and input_fp is not None):
-        cand = DistributedSortResult(live_words(), N, dtype)
-        if _check_result(cand, input_fp):
-            tracer.count("planner_passthrough", 1)
-            if plan is not None:
-                plan.decide("ladder", chosen="passthrough")
-            res = cand
-        else:
-            tracer.count("planner_passthrough_miss", 1)
-            if plan is not None:
-                plan.actual("planner", misses=1)
+    if passthrough_on and input_fp is not None:
+        cand_words = live_words()
+        if _passthrough(cand_words, input_fp):
+            res = DistributedSortResult(cand_words, N, dtype)
 
-    for level, rung_eng, rung_leng in (() if res is not None else rungs):
-        if rung_leng != _leng["v"]:
-            tracer.verbose(
-                f"degrading local-sort engine {_leng['v']} -> {rung_leng}")
-            tracer.count("local_engine_degraded", 1)
-            _leng["v"] = rung_leng
-            if plan is not None:
-                # the engine decision keeps its chosen (the pack that
-                # runs); the degrade stamps its trigger, and the regret
-                # rule prices the descent exactly like exchange_engine
-                eng_d = plan.decisions.get("engine")
-                plan.decide(
-                    "engine",
-                    chosen=(eng_d.chosen if eng_d is not None
-                            else rung_leng),
-                    trigger=("pallas_fault" if last_fail == "dispatch"
-                             else "verify_failure"))
+    for level, rung_eng in (() if res is not None else rungs):
         if rung_eng != _eng["v"]:
             tracer.verbose(
                 f"degrading exchange engine {_eng['v']} -> {rung_eng}")

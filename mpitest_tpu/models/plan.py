@@ -55,11 +55,8 @@ PLAN_DECISIONS: dict[str, str] = {
     "restage": ("skew-aware re-stage verdict + trigger (probe/overflow); "
                 "predicted vs post-restage peer ratio"),
     "engine": ("exchange-pack and local-sort engine selection "
-               "(xla/pallas pack, lax/bitonic/radix_pallas local); a "
-               "local-engine degrade (trigger=pallas_fault for "
-               "dispatch faults, verify_failure for failed "
-               "verification) is this decision's regret, beside the "
-               "pair-residual fallbacks"),
+               "(xla/pallas pack, lax/bitonic local); the "
+               "pair-residual fallbacks are this decision's regret"),
     "exchange_engine": ("inter-device exchange engine (ISSUE 13): "
                         "lax collective vs pallas remote-DMA + fused "
                         "pass; a degrade to lax (trigger=pallas_fault "
@@ -274,13 +271,8 @@ class SortPlan:
                      if pred is not None and "waste" in a else 0.0)
             return waste + extra
         if d.name == "engine":
-            # an engine whose residual fallback ran paid both engines;
-            # a local-engine ladder degrade (fused radix -> lax, same
-            # trigger classes as exchange_engine) paid every dispatch
-            # up to the switch on top
-            return (float(a.get("fallbacks", 0) or 0)
-                    + (1.0 if d.trigger in ("pallas_fault",
-                                            "verify_failure") else 0.0))
+            # an engine whose residual fallback ran paid both engines
+            return float(a.get("fallbacks", 0) or 0)
         if d.name == "planner":
             # the planner's own cost: each passthrough miss paid one
             # verify dispatch that proved nothing (the strided profile

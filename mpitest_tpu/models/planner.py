@@ -86,9 +86,8 @@ PLANNER_POLICIES: dict[str, str] = {
     "radix_compact": ("range-narrow input (ISSUE 17): the profile's "
                       "sampled min/max promise few significant key "
                       "bits, so the radix route with its diff-driven "
-                      "pass planner (and the fused local engine's "
-                      "compacted pass plan) sorts in fewer, narrower "
-                      "passes than any comparison path — extends "
+                      "pass planner sorts in fewer, narrower passes "
+                      "than any comparison path — extends "
                       "radix_narrow from dup-heavy to range-narrow"),
     "cap_margin": ("sample-negotiation margin sized from the observed "
                    "estimate-error quantiles in the flight ring "

@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mpitest_tpu.ops import kernels, radix_pallas
+from mpitest_tpu.ops import kernels
 from mpitest_tpu.parallel import collectives as coll
 from mpitest_tpu.parallel.mesh import AXIS
 from mpitest_tpu.utils import spans
@@ -183,7 +183,6 @@ def radix_sort_spmd(
     axis: str = AXIS,
     pack: str = "xla",
     exchange_engine: str = "lax",
-    local_engine: str = "lax",
 ) -> tuple[Words, jax.Array]:
     """Full multi-pass radix sort of the shard. SPMD; call under shard_map.
 
@@ -211,15 +210,6 @@ def radix_sort_spmd(
       H state, never on the payload DMAs.  Both engines are
       bit-identical by construction (same sorts, same segment values,
       same fill contract); the parity gates pin it.
-
-    ``local_engine`` (ISSUE 17) selects the FIRST pass's stable digit
-    sort: ``"radix_pallas"`` / ``"radix_pallas_interpret"`` replace the
-    ``lax.sort`` counting sort with the fused per-pass kernel
-    (``ops/radix_pallas.py``) carrying the key words as payload planes
-    — bit-identical, both are stable sorts by the same digit.  Later
-    passes keep ``lax.sort``: their (digit, slot) key merges the
-    exchange buffer, which is a scatter rather than a sort, and moving
-    it into the kernel is flagged TPU follow-up work.
 
     Returns ``(sorted_words, max_send_cnt_over_passes)`` — the second value
     > cap means an exchange overflowed and the host must retry with at
@@ -259,21 +249,9 @@ def radix_sort_spmd(
                 # stable 1-key sort groups by digit (stability = position
                 # order, exactly the (digit, slot) key of later passes).
                 d = kernels.digit_at(words[w_idx], shift, digit_bits)
-                if local_engine.startswith("radix_pallas"):
-                    # Fused local engine: the stable 1-key digit sort IS
-                    # a counting sort — one kernel launch, the words
-                    # ride as payload planes (diff 0 = never a sort key)
-                    fps = radix_pallas.fused_radix_sort(
-                        (d.astype(jnp.uint32),) + tuple(words),
-                        diffs=(n_bins - 1,) + (0,) * n_words,
-                        interpret=(
-                            local_engine == "radix_pallas_interpret"))
-                    sd = fps[0].astype(jnp.int32)
-                    sorted_words = tuple(fps[1:])
-                else:
-                    ops = lax.sort([d] + list(words), num_keys=1,
-                                   is_stable=True)
-                    sd, sorted_words = ops[0], tuple(ops[1:])
+                ops = lax.sort([d] + list(words), num_keys=1,
+                               is_stable=True)
+                sd, sorted_words = ops[0], tuple(ops[1:])
             else:
                 # Fused pass: merge the pending exchange buffer AND group by
                 # the new digit with ONE sort keyed on (digit, slot) — the

@@ -102,17 +102,6 @@ def exchange_engine_knob() -> str:
     return knobs.get("SORT_EXCHANGE_ENGINE")
 
 
-def local_engine_knob() -> str:
-    """``SORT_LOCAL_ENGINE`` (default auto): the local-sort engine the
-    ladder's first rung runs — resolution to a concrete impl (auto →
-    bitonic on TPU backends; the radix_pallas family → real Mosaic on
-    TPU, the interpreter elsewhere, lax outside its size/width
-    envelope) lives in ``models/api.py``, which knows the backend; the
-    fused-family radix_pallas → lax rung below it is this module's
-    ladder contract, mirroring :func:`exchange_engine_knob`."""
-    return knobs.get("SORT_LOCAL_ENGINE")
-
-
 def verify_enabled() -> bool:
     """``SORT_VERIFY`` (default on): the always-on output verifier."""
     return knobs.get("SORT_VERIFY")

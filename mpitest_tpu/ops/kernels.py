@@ -20,8 +20,7 @@ Words = tuple[jax.Array, ...]
 
 
 @tagged("local_sort")
-def local_sort(words: Words, engine: str = "lax",
-               diffs: tuple[int, ...] | None = None) -> Words:
+def local_sort(words: Words, engine: str = "lax") -> Words:
     """Lexicographic stable sort of multi-word keys (msw first).
 
     ``lax.sort`` with ``num_keys=len(words)`` compares word tuples
@@ -41,26 +40,11 @@ def local_sort(words: Words, engine: str = "lax",
     kernels (see tests/test_aot_topology.py).  Wider keys always use
     ``lax.sort``.
 
-    ``engine="radix_pallas"`` routes any key width up to
-    ``radix_pallas.FUSED_MAX_WORDS`` through the fused per-pass radix
-    kernel (one ``pallas_call`` per pass — no sort/searchsorted/gather
-    chain); ``"radix_pallas_interpret"`` is its interpreter twin.
-    ``diffs`` (msw-first per-word value spreads, host-static) lets the
-    fused engine compact the pass plan for range-narrow keys; it is
-    ignored by every other engine.  Bit-identity with the lax form is
-    exact: each fused pass is a stable counting sort.
-
     Stability note: ``words`` is always the FULL key (no payload
     operands), so stability is unobservable in the output — equal key
     tuples are indistinguishable — and the unstable bitonic engines are
     exact drop-ins for the stable ``lax.sort`` form.
     """
-    if engine.startswith("radix_pallas"):
-        from mpitest_tpu.ops import radix_pallas  # local import: optional path
-
-        return radix_pallas.fused_radix_sort(
-            words, diffs=diffs,
-            interpret=engine == "radix_pallas_interpret")
     if engine.startswith("bitonic") and len(words) == 1:
         from mpitest_tpu.ops import bitonic  # local import: optional path
 
@@ -70,7 +54,7 @@ def local_sort(words: Words, engine: str = "lax",
         # 64-bit pair engine with its residual fallback folded in as an
         # on-device cond (usable under shard_map, where host-side
         # fallback orchestration does not exist).  The adaptive sniffs
-        # of the single-device path live in models/api.py; here the
+        # of the single-device path live in models/local.py; here the
         # cond alone guarantees correctness for any duplication.
         interpret = engine == "bitonic_interpret"
         hi, lo = words

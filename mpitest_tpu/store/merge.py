@@ -141,41 +141,9 @@ class _Cursor:
 def _order_for(kws: tuple, rid: np.ndarray,
                pos: np.ndarray) -> np.ndarray:
     """Sort order of one merge round: lexicographic over the key words
-    (msw first) with the stable ``(run, pos)`` tiebreaks.
-
-    Host ``np.lexsort`` by default.  Under the fused local-sort engine
-    (``SORT_LOCAL_ENGINE=radix_pallas*``, ISSUE 17) the round's inner
-    loop runs on device instead — the rank-by-comparison kernel
-    ``ops/radix_pallas.merge_order`` over the same planes, bit-identical
-    because the (kws, rid, pos) key is unique per record.  The bounded
-    read-ahead / safe-boundary logic stays up in :func:`merge_runs`
-    either way; only the order computation moves.  Rounds above the
-    kernel's O(n^2) envelope, and any device failure (loudly counted as
-    a degrade), fall back to the host path — the merge must survive a
-    dead backend exactly like the sort ladder's host rung.
-    """
-    from mpitest_tpu.utils import knobs
-
-    eng = knobs.get("SORT_LOCAL_ENGINE")
-    n = int(rid.size)
-    if eng.startswith("radix_pallas") and 1 < n:
-        from mpitest_tpu.ops import radix_pallas as rp
-
-        if n <= rp.MERGE_MAX_ELEMS:
-            try:
-                import jax
-
-                interpret = (eng == "radix_pallas_interpret"
-                             or jax.default_backend() != "tpu")
-                return np.asarray(rp.merge_order(
-                    tuple(kws) + (rid, pos), interpret=interpret))
-            except Exception as e:  # pragma: no cover - device loss
-                import warnings
-
-                warnings.warn(
-                    "device merge-order kernel failed "
-                    f"({type(e).__name__}: {e}); degrading this merge "
-                    "to the host lexsort", RuntimeWarning)
+    (msw first) with the stable ``(run, pos)`` tiebreaks, by the host
+    ``np.lexsort``.  The (kws, rid, pos) key is unique per record, so
+    the order is total."""
     # np.lexsort: LAST key is primary -> (pos, rid, lsw..msw)
     return np.lexsort((pos, rid) + tuple(reversed(kws)))
 

@@ -319,15 +319,9 @@ register("SORT_OVERSAMPLE", "int", None, "an integer >= 1 (default: 2P-1)",
          _parse_oversample)
 
 register("SORT_LOCAL_ENGINE", "enum", "auto",
-         "auto | bitonic | lax | radix_pallas | radix_pallas_interpret",
-         "Local (single-device) sort engine; auto = bitonic on TPU. "
-         "radix_pallas = fused per-pass radix kernel "
-         "(ops/radix_pallas.py, one pallas_call per pass, planner-"
-         "compacted pass plans); never chosen by auto until the first "
-         "real-TPU re-baseline.",
-         _enum("SORT_LOCAL_ENGINE",
-               ("auto", "bitonic", "lax", "radix_pallas",
-                "radix_pallas_interpret")))
+         "auto | bitonic | lax",
+         "Local (single-device) sort engine; auto = bitonic on TPU.",
+         _enum("SORT_LOCAL_ENGINE", ("auto", "bitonic", "lax")))
 
 register("SORT_EXCHANGE_ENGINE", "enum", "auto",
          "auto | lax | pallas | pallas_interpret",

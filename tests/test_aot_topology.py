@@ -130,7 +130,7 @@ def test_pair_local_fused_one_chip(one_chip):
     """The fused device-resident 64-bit program (encode + range + sniff
     + lax.cond over 1-word engine / lax / pair engine): every cond
     branch lowers through the TPU compiler."""
-    from mpitest_tpu.models.api import _compile_pair_fused
+    from mpitest_tpu.models.local import _compile_pair_fused
 
     x = jax.ShapeDtypeStruct((1 << 14,), jnp.int64, sharding=one_chip)
     with jax.enable_x64(True):
@@ -143,7 +143,7 @@ def test_pair_split_2p27_one_chip(one_chip, dtype):
     two 512 MiB words out): it lowers, and writes the words straight
     from the chip's 64-bit halves, with no interleaved temporary (a
     ``bitcast_convert_type`` split holds 1 GiB of one)."""
-    from mpitest_tpu.models.api import _compile_pair_split
+    from mpitest_tpu.models.local import _compile_pair_split
 
     with jax.enable_x64(True):
         x = jax.ShapeDtypeStruct((1 << 27,), dtype, sharding=one_chip)
@@ -177,7 +177,7 @@ def test_sample_pallas_exchange_2p24_per_chip(mesh4):
     assert _has_kernel(c)
 
 
-def test_radix_pallas_exchange_2p24_per_chip(mesh4):
+def test_radix_over_pallas_exchange_2p24_per_chip(mesh4):
     """A 16-bit-digit radix pass over the remote-DMA exchange and the
     fused pass pack, at 2^24 keys per chip (the two-pass loop with its
     overlapped lane-slot plane is compiled small, below)."""

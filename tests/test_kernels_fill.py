@@ -81,7 +81,7 @@ def test_device_resident_float64_host_fallback(n_mesh, rng, monkeypatch):
     backend lowers the bitcast fine)."""
     import jax.errors
 
-    from mpitest_tpu.models import api
+    from mpitest_tpu.models import api, local
     from mpitest_tpu.utils.trace import Tracer
 
     calls = {"n": 0}
@@ -95,9 +95,9 @@ def test_device_resident_float64_host_fallback(n_mesh, rng, monkeypatch):
                 "types: %bitcast-convert injected")
         return f
 
-    monkeypatch.setattr(api, "_f64_encode_broken_platforms", set())
+    monkeypatch.setattr(local, "_f64_encode_broken_platforms", set())
     monkeypatch.setattr(api, "_compile_encode_pad", boom)
-    monkeypatch.setattr(api, "_compile_local_device", boom)
+    monkeypatch.setattr(local, "_compile_local_device", boom)
     x = (rng.standard_normal(8 * 200 + 3) * 1e9).astype(np.float64)
     with jax.enable_x64(True):
         x_dev = jnp.asarray(x)
@@ -127,7 +127,7 @@ def test_device_resident_float64_host_fallback(n_mesh, rng, monkeypatch):
     for msg in ("RESOURCE_EXHAUSTED: injected",
                 "some other bitcast-convert failure",
                 "X64 element types trouble elsewhere"):
-        monkeypatch.setattr(api, "_f64_encode_broken_platforms", set())
+        monkeypatch.setattr(local, "_f64_encode_broken_platforms", set())
 
         def other(*a, _msg=msg, **k):
             def f(*args):
@@ -135,12 +135,12 @@ def test_device_resident_float64_host_fallback(n_mesh, rng, monkeypatch):
             return f
 
         monkeypatch.setattr(api, "_compile_encode_pad", other)
-        monkeypatch.setattr(api, "_compile_local_device", other)
+        monkeypatch.setattr(local, "_compile_local_device", other)
         with jax.enable_x64(True):
             with pytest.raises(jax.errors.JaxRuntimeError,
                                match=msg.split()[0].split(":")[0]):
                 sort(jnp.asarray(x), algorithm="radix", mesh=make_mesh(n_mesh))
-        assert not api._f64_encode_broken_platforms
+        assert not local._f64_encode_broken_platforms
 
 
 @pytest.mark.parametrize("algo", ["radix", "sample"])

@@ -106,6 +106,7 @@ def test_split_span_names_are_registered(name):
 
 
 @pytest.mark.parametrize("path", ["mpitest_tpu/models/api.py",
+                                  "mpitest_tpu/models/local.py",
                                   "mpitest_tpu/models/verify.py",
                                   "mpitest_tpu/models/ingest.py",
                                   "mpitest_tpu/utils/programs.py"])

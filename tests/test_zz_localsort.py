@@ -1,13 +1,8 @@
-"""Fused local-sort engine tests (ISSUE 17): the per-pass radix kernel,
-the device merge-order kernel, planner key-width compaction, ladder
-degradation and provenance.
-
-The Mosaic kernels have never lowered on a real TPU (interpret mode is
-the oracle — ``ops/radix_pallas.py`` module docstring); on this CPU
-mesh the ``radix_pallas`` knob value resolves to the interpreter form,
-which runs the histogram/rank/scatter arithmetic for real.  Named
-``test_zz_*`` to sort late: the parity cells compile shard_map
-programs on the mesh8 fixture.
+"""Local-sort engine tests: the SORT_LOCAL_ENGINE knob, its resolution
+per dispatch, the engine in the plan record, and the planner's
+key-width compaction of the radix pass count.  Named ``test_zz_*`` to
+sort late: the mesh cells compile shard_map programs on the mesh8
+fixture.
 """
 
 from __future__ import annotations
@@ -19,15 +14,12 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
 
-from mpitest_tpu.models.api import (  # noqa: E402
-    _resolve_local_engine, _use_fused, sort)
-from mpitest_tpu.ops import radix_pallas as rp  # noqa: E402
-from mpitest_tpu.ops.keys import codec_for  # noqa: E402
+from mpitest_tpu.models.api import sort  # noqa: E402
+from mpitest_tpu.models.local import _resolve_local_engine  # noqa: E402
 from mpitest_tpu.utils import knobs  # noqa: E402
 from mpitest_tpu.utils.trace import Tracer  # noqa: E402
 
@@ -39,8 +31,7 @@ def test_local_engine_knob_validation():
     with knobs.scoped_env(SORT_LOCAL_ENGINE="warp9"):
         with pytest.raises(knobs.KnobError, match="SORT_LOCAL_ENGINE"):
             knobs.get("SORT_LOCAL_ENGINE")
-    for ok in ("auto", "bitonic", "lax", "radix_pallas",
-               "radix_pallas_interpret"):
+    for ok in ("auto", "bitonic", "lax"):
         with knobs.scoped_env(SORT_LOCAL_ENGINE=ok):
             assert knobs.get("SORT_LOCAL_ENGINE") == ok
     assert knobs.get("SORT_LOCAL_ENGINE") == "auto"  # default
@@ -61,265 +52,28 @@ def test_local_engine_knob_fail_fast_in_cli_and_server():
 
 
 def test_local_engine_resolution_on_cpu():
-    """The fused family resolves to the interpreter off-TPU, falls to
-    lax outside the kernel envelope, and auto NEVER chooses it (the
-    never-lowered-on-TPU caveat: auto flips only after a real-TPU
-    re-baseline)."""
-    assert _resolve_local_engine("radix_pallas", 2, 4096) == \
-        "radix_pallas_interpret"
-    assert _resolve_local_engine("radix_pallas_interpret", 2, 4096) == \
-        "radix_pallas_interpret"
-    # outside the envelope: too many words / too many elements
-    assert _resolve_local_engine(
-        "radix_pallas", rp.FUSED_MAX_WORDS + 1, 4096) == "lax"
-    assert _resolve_local_engine(
-        "radix_pallas", 2, rp.FUSED_MAX_ELEMS + 1) == "lax"
-    assert _use_fused("radix_pallas", 2, 4096)
-    assert not _use_fused("radix_pallas", 2, rp.FUSED_MAX_ELEMS + 1)
-    assert not _use_fused("lax", 2, 4096)
-    # auto never resolves into the fused family
+    """Off-TPU, auto and lax resolve to lax and a forced bitonic to its
+    interpreter form; keys wider than two words always keep lax."""
     for n in (64, 4096, 1 << 18):
-        assert not _resolve_local_engine("auto", 2, n).startswith(
-            "radix_pallas")
+        assert _resolve_local_engine("auto", 2, n) == "lax"
     assert _resolve_local_engine("lax", 2, 4096) == "lax"
+    assert _resolve_local_engine("bitonic", 1, 4096) == "bitonic_interpret"
+    assert _resolve_local_engine("bitonic", 3, 4096) == "lax"
 
 
-# ------------------------------------------------------ pass-plan units
-
-def test_pass_plan_full_width_and_compaction():
-    full = rp.pass_plan(None, 2)
-    assert len(full) == 8  # 2 words x 32 bits / 8-bit digits
-    # lsw-first: word index 1 (least significant) planned before 0
-    assert [w for w, _s, _b in full] == [1, 1, 1, 1, 0, 0, 0, 0]
-    assert all(b == rp.DIGIT_BITS for _w, _s, b in full)
-    # 20-bit low word, constant high word: 3 passes, high word skipped
-    plan = rp.pass_plan((0, (1 << 20) - 1), 2)
-    assert len(plan) == 3
-    assert all(w == 1 for w, _s, _b in plan)
-    assert plan[-1] == (1, 16, 4)  # the top partial digit is narrow
-    # all-constant input sorts in zero passes
-    assert rp.pass_plan((0, 0), 2) == ()
-    with pytest.raises(ValueError, match="diffs"):
-        rp.pass_plan((1,), 2)
-
-
-# ------------------------------------------------------- kernel parity
-
-@pytest.mark.parametrize("dtype", [np.int32, np.uint64, np.float32])
-@pytest.mark.parametrize("kind,n", [("uniform", 2048), ("dup", 2048),
-                                    ("sorted", 2048), ("tiny", 5),
-                                    ("nondiv", 1537)])
-def test_fused_kernel_matches_lexsort(dtype, kind, n, rng):
-    """fused_radix_sort (interpret) is word-for-word the np.lexsort
-    oracle across dtype x input-class cells."""
-    if np.dtype(dtype).kind == "f":
-        x = rng.normal(size=n).astype(dtype)
-    else:
-        info = np.iinfo(dtype)
-        hi = 5 if kind == "dup" else info.max
-        x = rng.integers(info.min if kind != "dup" else 0, hi,
-                         size=n, dtype=dtype, endpoint=True)
-    if kind == "sorted":
-        x = np.sort(x)
-    words = codec_for(dtype).encode(x)
-    ref = np.lexsort(tuple(reversed(words)))
-    got = rp.fused_radix_sort(tuple(np.asarray(w) for w in words),
-                              interpret=True)
-    for g, w in zip(got, words):
-        np.testing.assert_array_equal(np.asarray(g), w[ref])
-
-
-def test_fused_kernel_compacted_plan_parity(rng):
-    """A compacted (range-narrow) plan sorts identically in fewer
-    passes, launch-counted: exactly one pallas_call per planned pass."""
-    x = rng.integers(0, 1 << 20, size=2048, dtype=np.int64)
-    words = tuple(np.asarray(w) for w in codec_for(np.int64).encode(x))
-    diffs = tuple(int(w.max()) - int(w.min()) for w in words)
-    plan = rp.pass_plan(diffs, len(words))
-    assert len(plan) < len(rp.pass_plan(None, len(words)))
-    before = rp.pass_launches()
-    got = rp.fused_radix_sort(words, diffs=diffs, interpret=True)
-    np.asarray(got[0])
-    assert rp.pass_launches() - before == len(plan)
-    ref = np.lexsort(tuple(reversed(words)))
-    for g, w in zip(got, words):
-        np.testing.assert_array_equal(np.asarray(g), w[ref])
-
-
-def test_fused_lowering_has_no_sort_chain(rng):
-    """The perf claim in HLO terms: the fused pass lowers with NO
-    sort/searchsorted chain — the old per-pass lax.sort is gone from
-    the program the engine runs."""
-    x = rng.integers(0, 1 << 16, size=1024, dtype=np.int32)
-    words = tuple(jnp.asarray(w)
-                  for w in codec_for(np.int32).encode(x))
-
-    def run(*ws):
-        return rp.fused_radix_sort(ws, interpret=True)
-
-    txt = jax.jit(run).lower(*words).as_text()
-    assert " sort(" not in txt
-
-
-# ------------------------------------------------------- merge kernel
-
-@pytest.mark.parametrize("n", [1, 2, 37, 300, 1000, 4096])
-def test_merge_order_matches_lexsort(n, rng):
-    """merge_order == np.lexsort on dup-heavy (run, pos)-tied planes —
-    the exact planes store/merge.py hands it."""
-    kw = rng.integers(0, 7, size=n).astype(np.uint32)  # dup-heavy keys
-    rid = rng.integers(0, 4, size=n).astype(np.uint32)
-    pos = np.arange(n, dtype=np.uint32)
-    rng.shuffle(pos)
-    order = np.asarray(rp.merge_order((kw, rid, pos), interpret=True))
-    ref = np.lexsort((pos, rid, kw))
-    np.testing.assert_array_equal(order, ref)
-    # two-word keys through the same path
-    kw2 = rng.integers(0, 3, size=n).astype(np.uint32)
-    order = np.asarray(rp.merge_order((kw2, kw, rid, pos),
-                                      interpret=True))
-    np.testing.assert_array_equal(order, np.lexsort((pos, rid, kw, kw2)))
-
-
-def test_merge_order_envelope_is_typed():
-    n = rp.MERGE_MAX_ELEMS + 1
-    planes = (np.zeros(n, np.uint32), np.arange(n, dtype=np.uint32))
-    with pytest.raises(ValueError, match="merge_order"):
-        rp.merge_order(planes, interpret=True)
-
-
-def test_store_merge_order_for_device_vs_host(rng):
-    """store/merge._order_for under the fused knob is bit-identical to
-    the host lexsort (and falls back to it above the envelope)."""
-    from mpitest_tpu.store.merge import _order_for
-
-    n = 600
-    kws = (rng.integers(0, 9, size=n).astype(np.uint32),)
-    rid = rng.integers(0, 3, size=n).astype(np.uint32)
-    pos = np.arange(n, dtype=np.uint32)
-    want = np.lexsort((pos, rid) + tuple(reversed(kws)))
-    with knobs.scoped_env(SORT_LOCAL_ENGINE="radix_pallas_interpret"):
-        got = _order_for(kws, rid, pos)
-    np.testing.assert_array_equal(got, want)
-    # above MERGE_MAX_ELEMS: the host path, same bytes
-    n = rp.MERGE_MAX_ELEMS + 8
-    kws = (rng.integers(0, 9, size=n).astype(np.uint32),)
-    rid = np.zeros(n, np.uint32)
-    pos = np.arange(n, dtype=np.uint32)
-    with knobs.scoped_env(SORT_LOCAL_ENGINE="radix_pallas_interpret"):
-        got = _order_for(kws, rid, pos)
-    np.testing.assert_array_equal(
-        got, np.lexsort((pos, rid) + tuple(reversed(kws))))
-
-
-# ------------------------------------------------- parity on the mesh
-
-@pytest.mark.parametrize("dtype", [np.int32, np.uint64, np.float32])
-@pytest.mark.parametrize("algo", ["radix", "sample"])
-def test_lax_vs_fused_parity_mesh8(algo, dtype, mesh8, rng):
-    """Bit-identical output across the local-engine knob, both
-    algorithms, 1- and 2-word codecs and the float totalOrder codec.
-    SORT_FALLBACK=0 pins the engine: a broken fused path would
-    silently degrade and the comparison would pass vacuously."""
-    if np.dtype(dtype).kind == "f":
-        x = rng.normal(size=1 << 12).astype(dtype)
-    else:
-        info = np.iinfo(dtype)
-        x = rng.integers(info.min, info.max, size=1 << 12,
-                         dtype=dtype, endpoint=True)
-    with knobs.scoped_env(SORT_FALLBACK="0", SORT_LOCAL_ENGINE="lax"):
-        a = sort(x, algorithm=algo, mesh=mesh8)
-    t = Tracer()
-    with knobs.scoped_env(SORT_FALLBACK="0",
-                          SORT_LOCAL_ENGINE="radix_pallas"):
-        b = sort(x, algorithm=algo, mesh=mesh8, tracer=t)
-    assert str(t.counters["local_engine"]).startswith("radix_pallas")
-    assert "local_engine_degraded" not in t.counters
-    assert a.dtype == b.dtype == np.dtype(dtype)
-    assert a.tobytes() == b.tobytes()
-
-
-def test_fused_single_device_parity(rng):
-    """The 1-device dispatch path (no mesh) through the fused engine."""
-    x = rng.integers(-(2**62), 2**62, size=3000, dtype=np.int64)
-    with knobs.scoped_env(SORT_FALLBACK="0", SORT_LOCAL_ENGINE="lax"):
-        a = sort(x)
-    t = Tracer()
-    with knobs.scoped_env(SORT_FALLBACK="0",
-                          SORT_LOCAL_ENGINE="radix_pallas"):
-        b = sort(x, tracer=t)
-    assert t.counters["local_engine"] == "radix_pallas_interpret"
-    assert a.tobytes() == b.tobytes() == np.sort(x).tobytes()
-
-
-# ------------------------------------------- ladder + plan provenance
-
-def test_ladder_degrades_fused_to_lax_verified(mesh8, rng):
-    """A fused-kernel failure re-runs the SAME algorithm and exchange
-    engine on the lax LOCAL rung; the result is verified and the
-    degrade is a plan decision + counter, never a silent engine swap.
-
-    Odd key count (3311): the injected fault fires at TRACE time, so
-    this test must miss every compile-cache entry the parity cells
-    populated."""
-    x = rng.integers(-(2**31), 2**31 - 1, size=3311, dtype=np.int32)
-    orig = rp.fused_radix_sort
-
-    def boom(*a, **kw):
-        raise jax.errors.JaxRuntimeError(
-            "INTERNAL: injected fused local-sort fault (test)")
-
-    rp.fused_radix_sort = boom
-    try:
-        with knobs.scoped_env(SORT_MAX_RETRIES="0", SORT_FALLBACK="1",
-                              SORT_LOCAL_ENGINE="radix_pallas"):
-            t = Tracer()
-            out = sort(x, algorithm="radix", mesh=mesh8, tracer=t)
-    finally:
-        rp.fused_radix_sort = orig
-    np.testing.assert_array_equal(out, np.sort(x))
-    assert t.counters["local_engine"] == "lax"
-    assert t.counters["local_engine_degraded"] == 1
-    assert t.counters["verify_runs"] >= 1
-    assert "degraded_to" not in t.counters  # same algorithm, local rung
-    assert "exchange_engine_degraded" not in t.counters
-    d = t.plan.decisions["engine"]
-    assert d.trigger == "pallas_fault"
-    assert d.regret == 1.0
-    assert d.actual.get("local_engine") == "lax"
-
-
-def test_ladder_pinned_fused_engine_fails_loudly(mesh8, rng):
-    """SORT_FALLBACK=0 pins the engine: a fused-kernel failure is a
-    typed error, never a silent lax re-run."""
-    from mpitest_tpu.models.api import SortRetryExhausted
-
-    x = rng.integers(0, 100, size=997, dtype=np.int32)
-    orig = rp.fused_radix_sort
-
-    def boom(*a, **kw):
-        raise jax.errors.JaxRuntimeError("INTERNAL: injected (test)")
-
-    rp.fused_radix_sort = boom
-    try:
-        with knobs.scoped_env(SORT_MAX_RETRIES="0", SORT_FALLBACK="0",
-                              SORT_LOCAL_ENGINE="radix_pallas"):
-            with pytest.raises(SortRetryExhausted):
-                sort(x, algorithm="radix", mesh=mesh8)
-    finally:
-        rp.fused_radix_sort = orig
-
+# ------------------------------------------------------ plan provenance
 
 def test_plan_actual_carries_local_engine_and_backend(mesh8, rng):
     """The engine decision's actual record names the resolved local
-    engine AND the backend — the doctor's local_sort_lax rule keys on
-    exactly these two fields."""
+    engine AND the backend: sample sort's per-shard sorts on the forced
+    bitonic engine."""
     x = rng.integers(0, 1 << 16, size=1 << 12, dtype=np.int32)
     t = Tracer()
-    with knobs.scoped_env(SORT_LOCAL_ENGINE="radix_pallas"):
-        sort(x, algorithm="radix", mesh=mesh8, tracer=t)
+    with knobs.scoped_env(SORT_LOCAL_ENGINE="bitonic"):
+        out = sort(x, algorithm="sample", mesh=mesh8, tracer=t)
+    np.testing.assert_array_equal(out, np.sort(x))
     a = t.plan.decisions["engine"].actual
-    assert str(a.get("local_engine")).startswith("radix_pallas")
+    assert a.get("local_engine") == "bitonic_interpret"
     assert a.get("backend") == str(jax.default_backend())
 
 
@@ -392,36 +146,3 @@ def test_planner_lying_profile_stamps_passes_regret(mesh8, rng):
     assert out.tobytes() == np.sort(x).tobytes()
     d = t.plan.decisions["passes"]
     assert d.trigger == "planner" and (d.regret or 0.0) > 0.0
-
-
-# ------------------------------------------------------ doctor's rule
-
-def test_doctor_rule_local_sort_lax():
-    """Sort-dominant timeline + a TPU-backend plan that ran the lax
-    local engine -> the SORT_LOCAL_ENGINE suggestion; CPU backends and
-    non-sort critical paths stay silent."""
-    from mpitest_tpu import doctor
-
-    def ev(backend, phase="sort", engine="lax"):
-        e = doctor.empty_evidence()
-        e["timeline"] = {"critical_path_phase": phase,
-                         "phases": {"sort": 2.0, "decode": 0.5}}
-        e["plans"] = [{"decisions": {"engine": {
-            "chosen": "xla",
-            "actual": {"local_engine": engine, "backend": backend}}}}]
-        return e
-
-    fs = [f for f in doctor.diagnose(ev("tpu"))
-          if f.rule == "local_sort_lax"]
-    assert len(fs) == 1
-    f = fs[0]
-    assert f.knob == "SORT_LOCAL_ENGINE"
-    assert "radix_pallas" in f.direction
-    assert f.threshold == doctor.LOCAL_SORT_PHASE_GATE
-    assert f.value == pytest.approx(0.8)
-    assert any("critical_path_phase=sort" in c for c in f.evidence)
-    # cpu backend / fused engine / decode-dominated: silent
-    for quiet in (ev("cpu"), ev("tpu", engine="radix_pallas"),
-                  ev("tpu", phase="decode")):
-        assert not [f for f in doctor.diagnose(quiet)
-                    if f.rule == "local_sort_lax"]

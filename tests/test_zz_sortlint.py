@@ -177,8 +177,7 @@ def test_doctor_registry_vocabulary():
     assert {"skew_imbalance", "cap_thrash", "compile_storm",
             "window_misfit", "spill_bound",
             "verify_overhead_regression", "breaker_flap",
-            "deadline_burn", "local_sort_lax",
-            "spill_churn"} == set(doctor_mod.DOCTOR_RULES)
+            "deadline_burn", "spill_churn"} == set(doctor_mod.DOCTOR_RULES)
     # every vocabulary key has a registered diagnosis function
     assert set(doctor_mod.DOCTOR_RULES) == set(doctor_mod._RULES)
     assert all(s in doctor_mod.SEVERITIES

@@ -58,7 +58,7 @@ COLUMNS: tuple[tuple[str, str, str, bool], ...] = (
 LABEL_COLUMNS: tuple[tuple[str, str], ...] = (
     ("exchange_engine", "engine"),
     # ISSUE 17: the local-sort engine the row measured under (lax /
-    # bitonic family / radix_pallas family) — pinned on measured rows
+    # bitonic family) — pinned on measured rows
     # via setdefault; pre-r06 rounds render "-".
     ("local_engine", "local"),
     # ISSUE 14: the planner mode the row measured under — pinned "off"

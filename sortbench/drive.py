@@ -9,9 +9,15 @@ output.
 Set-up makes one call at the cell's exact shape, on the first array of
 the pool.  The window meets the others unseen, as a user's fresh data:
 a program that compiles per input compiles there, and the check counts
-it (``window_compiles``).  The window starts calls until ``seconds``
-have passed and ends at the last call's return (a call boundary); every
-started call finishes.
+it (``window_compiles``).
+
+The window is the calls alone: its length is the sum of the calls'
+durations (``t1 - t0`` on the host's clock), and it starts calls until
+that sum reaches ``seconds``; every started call finishes.  Between two
+calls, outside the window, the answer just returned is compared with
+the reference of its input and dropped, as a caller that consumes each
+answer before asking for the next.  In a closed loop with one caller
+the sum differs from start-to-last-return only by those gaps.
 """
 
 from __future__ import annotations
@@ -32,16 +38,18 @@ def warm_up(sort_fn: Callable, pool: list[np.ndarray], compiles: Any,
         + (f", error {c.error}" if c.error else ""))
 
 
-def window(sort_fn: Callable, pool: list[np.ndarray], seconds: float,
-           compiles: Any) -> tuple[list, float, float]:
-    """The measured window: returns (calls, start, end) on the host's
-    ``perf_counter`` clock."""
+def window(sort_fn: Callable, pool: list[np.ndarray],
+           refs: list[np.ndarray], seconds: float, compiles: Any,
+           ex: Any) -> list:
+    """The measured window: call ``i`` sorts ``pool[i % len(pool)]`` and
+    is checked against ``refs`` of the same index as it returns."""
     from sortbench.harness import one_call
 
-    calls = []
-    t_start = time.perf_counter()
-    deadline = t_start + seconds
-    while time.perf_counter() < deadline:
+    calls: list = []
+    spent = 0.0
+    while spent < seconds:
         j = len(calls) % len(pool)
-        calls.append(one_call(sort_fn, pool[j], len(calls), j, compiles))
-    return calls, t_start, calls[-1].t1
+        c = one_call(sort_fn, pool[j], len(calls), j, compiles, refs[j], ex)
+        calls.append(c)
+        spent += c.t1 - c.t0
+    return calls

@@ -1,6 +1,7 @@
 """Keys of the window's completed, correct calls over the window's
-length (start to the last call's return), host keys in to host keys
-out, in millions per second (host clock)."""
+length (the sum of the calls' durations; the check between calls lies
+outside it), host keys in to host keys out, in millions per second
+(host clock)."""
 
 
 def read(run):

@@ -1,10 +1,17 @@
-"""One run of one cell: key pool, one warm-up call, measured window, the check.
+"""One run of one cell: key pool, one warm-up call, references, the
+measured window with each answer checked as its call returns.
 
 The window drives ``sort_fn(keys, tracer=...)`` — in a benchmark run
 ``mpitest_tpu.sort`` with the program's defaults and the config's
-``algorithm`` — on host keys and gets host keys back.  Every returned
-array is kept, and once the window has closed each one is compared
-with the plain reference of its input (:mod:`sortbench.reference`).
+``algorithm`` — on host keys and gets host keys back, as a batch user
+who consumes each answer before asking for the next.  The plain
+reference of every pool array (:mod:`sortbench.reference`) is computed
+once set-up has been read and before the window opens.  When a call
+returns, its answer is compared with the reference of its input at every
+position, and then dropped: at most one answer is alive when a call
+starts, so the host's memory does not grow with the number of calls.
+The window is the calls alone: its length is the sum of the calls'
+durations, and the compare between two calls lies outside it.
 
 Numbers compared, each with its limit (an exact comparison: limit 0):
 
@@ -48,6 +55,13 @@ LIMITS = {"mismatched_keys": 0, "unanswered_calls": 0, "fallback_calls": 0,
 
 SortFn = Callable[..., np.ndarray]
 
+#: keys compared per slice when an answer is checked (4-8 MiB of keys):
+#: the threads share the compare and its temporaries stay small
+CHECK_SLICE = 1 << 20
+
+#: threads that compute the references and compare the answers
+CHECK_THREADS = min(8, os.cpu_count() or 1)
+
 
 class CompileCounter:
     """Counts JAX backend compiles in this process (a listener on JAX's
@@ -73,12 +87,11 @@ class Call:
     n: int
     t0: float
     t1: float
-    out: np.ndarray | None
     error: str | None
     phases: dict[str, float]
     counters: dict[str, Any]
     compiles: int
-    mismatched: int | None = None  # set by check()
+    mismatched: int | None = None  # None: unchecked, or no answer
 
     @property
     def bad_counters(self) -> dict[str, Any]:
@@ -146,8 +159,23 @@ def make_pool(cell: spec.Cell, seed: int, keys: int | None = None
     return pool
 
 
+def mismatches(out: Any, ref: np.ndarray, ex: ThreadPoolExecutor) -> int:
+    """Positions where ``out`` differs from ``ref``: all of them where
+    the shape or dtype differs, else counted in slices across threads
+    (NumPy's compare releases the GIL)."""
+    if out.shape != ref.shape or out.dtype != ref.dtype:
+        return int(ref.size)
+    return sum(ex.map(lambda s: int(np.count_nonzero(
+        out[s:s + CHECK_SLICE] != ref[s:s + CHECK_SLICE])),
+        range(0, ref.size, CHECK_SLICE)))
+
+
 def one_call(sort_fn: SortFn, x: np.ndarray, index: int, pool_index: int,
-             compiles: CompileCounter) -> Call:
+             compiles: CompileCounter, ref: np.ndarray | None = None,
+             ex: ThreadPoolExecutor | None = None) -> Call:
+    """One timed call, host keys in to a host array out.  With ``ref``
+    the answer is compared with it once the call has returned, outside
+    ``t0``..``t1``; the answer itself is not kept."""
     import jax
 
     from mpitest_tpu.utils.trace import Tracer
@@ -158,35 +186,21 @@ def one_call(sort_fn: SortFn, x: np.ndarray, index: int, pool_index: int,
     t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("sortbench.call", call=index):
         try:
-            out = sort_fn(x, tracer=tr)
+            out = np.asarray(sort_fn(x, tracer=tr))
         except Exception as e:  # noqa: BLE001 -- a raising call is counted
             err = f"{type(e).__name__}: {e}"
     t1 = time.perf_counter()
     n_compiles = (compiles.count - c0
                   + int(tr.counters.get("jit_first_calls", 0)))
-    return Call(index, pool_index, int(x.size), t0, t1,
-                None if out is None else np.asarray(out), err,
+    call = Call(index, pool_index, int(x.size), t0, t1, err,
                 dict(tr.phases), dict(tr.counters), n_compiles)
+    if ref is not None and out is not None:
+        call.mismatched = mismatches(out, ref, ex)
+    return call
 
 
-def check(cell: spec.Cell, pool: list[np.ndarray], calls: list[Call]
-          ) -> dict[str, dict[str, int]]:
-    """Compare every call's returned keys with the reference of its input
-    (references computed once per pool array, in threads), then free the
-    returned arrays.  Returns each compared number beside its limit."""
-    used = sorted({c.pool_index for c in calls if c.out is not None})
-    with ThreadPoolExecutor(max(1, len(used))) as ex:
-        refs = dict(zip(used, ex.map(
-            lambda j: cell.reference.reference(pool[j]), used)))
-    for c in calls:
-        if c.out is None:
-            continue
-        ref = refs[c.pool_index]
-        if c.out.shape != ref.shape or c.out.dtype != ref.dtype:
-            c.mismatched = int(ref.size)
-        else:
-            c.mismatched = int(np.count_nonzero(c.out != ref))
-        c.out = None
+def check(calls: list[Call]) -> dict[str, dict[str, int]]:
+    """Sum the calls' own counts: each compared number beside its limit."""
     values = {
         "mismatched_keys": sum(c.mismatched or 0 for c in calls),
         "unanswered_calls": sum(1 for c in calls if c.error),
@@ -194,6 +208,19 @@ def check(cell: spec.Cell, pool: list[np.ndarray], calls: list[Call]
         "window_compiles": sum(c.compiles for c in calls),
     }
     return {k: {"value": v, "limit": LIMITS[k]} for k, v in values.items()}
+
+
+def mem_available_mib() -> float | None:
+    """``MemAvailable`` of ``/proc/meminfo`` in MiB; None where the host
+    has no such file."""
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                if line.startswith("MemAvailable:"):
+                    return int(line.split()[1]) / 1024
+    except OSError:
+        pass
+    return None
 
 
 def device_info(devices: list[Any]) -> dict[str, Any]:
@@ -207,10 +234,12 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              t_process: float, devices: list[Any], sort_fn: SortFn,
              compiles: CompileCounter, keys: int | None = None,
              log: Callable[[str], None] = print) -> Run:
-    """Set-up (pool, one warm-up call), the measured window, then the check.
-    ``t_process`` is the ``perf_counter`` reading at process start, so
-    ``setup_s`` covers JAX's start, the native build and key generation.
-    ``keys`` overrides the config's keys per call (tests only)."""
+    """Set-up (pool, one warm-up call), the references, then the measured
+    window, each call checked as it returns.  ``t_process`` is the
+    ``perf_counter`` reading at process start, so ``setup_s`` covers
+    JAX's start, the native build and key generation; the references
+    come after it and outside the window.  ``keys`` overrides the
+    config's keys per call (tests only)."""
     import jax
 
     t = time.perf_counter()
@@ -218,31 +247,38 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     log(f"pool: {len(pool)} x {pool[0].size} {pool[0].dtype} in "
         f"{time.perf_counter() - t:.6f} s")
     drive.warm_up(sort_fn, pool, compiles, log)
-    if trace:
-        trace_dir = tempfile.mkdtemp(prefix="sortbench-trace-")
-        jax.profiler.start_trace(trace_dir)
     setup_s = time.perf_counter() - t_process
-    try:
-        calls, t_start, t_end = drive.window(sort_fn, pool, seconds,
-                                             compiles)
-    finally:
+    with ThreadPoolExecutor(CHECK_THREADS) as ex:
+        t = time.perf_counter()
+        refs = list(ex.map(cell.reference.reference, pool))
+        log(f"references: {len(refs)} in {time.perf_counter() - t:.6f} s")
+        mem0 = mem_available_mib()
         if trace:
-            jax.profiler.stop_trace()
+            trace_dir = tempfile.mkdtemp(prefix="sortbench-trace-")
+            jax.profiler.start_trace(trace_dir)
+        try:
+            calls = drive.window(sort_fn, pool, refs, seconds, compiles, ex)
+        finally:
+            if trace:
+                jax.profiler.stop_trace()
+        mem1 = mem_available_mib()
+    del refs
+    log(f"MemAvailable: {mem0} MiB at the window's start, {mem1} MiB at "
+        f"its end")
     info = device_info(devices)
     for c in calls:
         log(f"call {c.index} pool[{c.pool_index}] {c.t1 - c.t0:.6f} s "
-            f"phases {({k: round(v, 6) for k, v in c.phases.items()})}"
+            f"mismatched {c.mismatched} "
+            f"phases {({k: round(v, 6) for k, v in c.phases.items()})} "
+            f"counters {c.counters}"
             + (f" error {c.error}" if c.error else ""))
-    run = Run(cell, setup_s, t_end - t_start, calls, info)
+    run = Run(cell, setup_s, sum(c.t1 - c.t0 for c in calls), calls, info)
     if trace:
         t = time.perf_counter()
         run.trace = reduce.reduce_dir(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
         log(f"trace reduced in {time.perf_counter() - t:.6f} s")
-    t = time.perf_counter()
-    run.checks = check(cell, pool, calls)
-    log(f"check: {len(calls)} calls against the reference in "
-        f"{time.perf_counter() - t:.6f} s")
+    run.checks = check(calls)
     return run
 
 

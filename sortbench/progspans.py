@@ -9,6 +9,10 @@ Two marks, both on the trace's own clock:
 * layer tags: each device op carries ``layer="<name>"`` in its HLO text
   (``run.trace.devices[*].ops``).
 
+Every share is of the traced window, the union of the harness's
+``sortbench.call`` intervals (:mod:`sortbench.reduce`): a span or an
+idle instant between two calls counts toward none.
+
 Every helper returns None where the trace holds no such mark — a
 program that opens no annotation or tags no op — so the reader leaves
 its metric out instead of reporting 0.
@@ -16,21 +20,9 @@ its metric out instead of reporting 0.
 
 from __future__ import annotations
 
+from sortbench import reduce
+
 PHASE_PREFIX = "phase:"
-
-
-def _union(iv: list[tuple[float, float]], lo: float, hi: float
-           ) -> list[tuple[float, float]]:
-    """Sorted union of intervals clipped to [lo, hi]."""
-    out: list[list[float]] = []
-    for s, e in sorted((max(s, lo), min(e, hi)) for s, e in iv):
-        if e <= s:
-            continue
-        if out and s <= out[-1][1]:
-            out[-1][1] = max(out[-1][1], e)
-        else:
-            out.append([s, e])
-    return [(s, e) for s, e in out]
 
 
 def annotations(run, match) -> list[tuple[float, float]] | None:
@@ -40,7 +32,7 @@ def annotations(run, match) -> list[tuple[float, float]] | None:
     if tr is None:
         return None
     iv = [(e.start, e.end) for e in tr.host if match(e.name)]
-    return _union(iv, *tr.window) if iv else None
+    return reduce.intersect(reduce.union(iv), tr.window) if iv else None
 
 
 def annotation_share_pct(run, name: str) -> float | None:
@@ -72,8 +64,7 @@ def idle_outside_pct(run, prefix: str = PHASE_PREFIX) -> float | None:
     if covered is None:
         return None
     tr = run.trace
-    lo, hi = tr.window
-    idle = tr.busiest().gaps(lo, hi)
+    idle = tr.busiest().gaps(tr.window)
     inside = 0.0
     j = 0
     for s, e in idle:

@@ -6,8 +6,8 @@ in one process, so set-up (JAX's start, compiles) is paid once.
         --control-seeds 7,8,9 --seconds 5
 
 For each program seed: the cell's pool from the seed, its first array
-sorted once (set-up), a window of ``--seconds``, the check against the
-reference.  For each control seed the same window with the reference's
+sorted once (set-up), the references, a window of ``--seconds`` with
+each answer checked against its reference as its call returns.  For each control seed the same window with the reference's
 ``control`` (one guarantee broken) in the program's place.  One JSON
 line per seed on stdout: the compared numbers (``checks``), calls and
 throughput.  The benchmark's own runs never run this.

@@ -3,10 +3,12 @@
 What it keeps, per device plane (``/device:TPU:<n>``): every event of
 the ``XLA Ops`` and ``Async XLA Ops`` lines, as (start, end, HLO text).
 From the host planes it keeps the harness's ``sortbench.call``
-annotations, which fix the traced window (first call's start to last
-call's end), and the other events of the host line that holds them:
-the calling thread's Python frames (JAX's Python tracer), which name
-what the host was doing in each idle gap.
+annotations, whose union is the traced window, and the other events of
+the host line that holds them: the calling thread's Python frames
+(JAX's Python tracer), which name what the host was doing in each idle
+gap.  The window is the calls alone: what the harness does between two
+calls (the check of the answer) lies outside it, and no reading counts
+an op or a frame there.
 
 All times share the trace's clock (ns from the trace's start).  Busy
 time is the union of op intervals inside the window; idle is the rest.
@@ -18,6 +20,7 @@ hand.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -37,6 +40,65 @@ def short_name(hlo: str) -> str:
     return f"{m.group(1)} {m.group(2)}" if m else hlo[:80]
 
 
+Intervals = list[tuple[float, float]]
+
+
+def union(iv) -> Intervals:
+    """Sorted union of (start, end) intervals; empty ones dropped."""
+    out: list[list[float]] = []
+    for s, e in sorted(iv):
+        if e <= s:
+            continue
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return [(s, e) for s, e in out]
+
+
+def intersect(a: Intervals, b: Intervals) -> Intervals:
+    """Intersection of two sorted lists of disjoint intervals."""
+    out, i, j = [], 0, 0
+    while i < len(a) and j < len(b):
+        s, e = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if e > s:
+            out.append((s, e))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
+
+
+def complement(a: Intervals, window: Intervals) -> Intervals:
+    """The parts of ``window`` that the sorted disjoint ``a`` leaves
+    uncovered."""
+    out, i = [], 0
+    for lo, hi in window:
+        t = lo
+        while i < len(a) and a[i][1] <= lo:
+            i += 1
+        j = i
+        while j < len(a) and a[j][0] < hi:
+            if a[j][0] > t:
+                out.append((t, a[j][0]))
+            t = max(t, a[j][1])
+            j += 1
+        if hi > t:
+            out.append((t, hi))
+    return out
+
+
+def overlap(s: float, e: float, window: Intervals) -> float:
+    """Length of [s, e) inside the sorted disjoint ``window``."""
+    k = max(0, bisect.bisect_right(window, (s, float("inf"))) - 1)
+    total = 0.0
+    while k < len(window) and window[k][0] < e:
+        total += max(0.0, min(e, window[k][1]) - max(s, window[k][0]))
+        k += 1
+    return total
+
+
 @dataclass
 class Op:
     start: float  # ns
@@ -49,31 +111,16 @@ class Device:
     name: str
     ops: list[Op] = field(default_factory=list)
 
-    def merged(self, lo: float, hi: float) -> list[tuple[float, float]]:
-        """Union of op intervals clipped to [lo, hi], sorted."""
-        out: list[list[float]] = []
-        for s, e in sorted((max(o.start, lo), min(o.end, hi))
-                           for o in self.ops):
-            if e <= s:
-                continue
-            if out and s <= out[-1][1]:
-                out[-1][1] = max(out[-1][1], e)
-            else:
-                out.append([s, e])
-        return [(s, e) for s, e in out]
+    def merged(self, window: Intervals) -> Intervals:
+        """Union of op intervals clipped to the window, sorted."""
+        return intersect(union((o.start, o.end) for o in self.ops), window)
 
-    def busy_ns(self, lo: float, hi: float) -> float:
-        return sum(e - s for s, e in self.merged(lo, hi))
+    def busy_ns(self, window: Intervals) -> float:
+        return sum(e - s for s, e in self.merged(window))
 
-    def gaps(self, lo: float, hi: float) -> list[tuple[float, float]]:
-        out, t = [], lo
-        for s, e in self.merged(lo, hi):
-            if s > t:
-                out.append((t, s))
-            t = max(t, e)
-        if hi > t:
-            out.append((t, hi))
-        return out
+    def gaps(self, window: Intervals) -> Intervals:
+        """The window's idle parts: no op ran."""
+        return complement(self.merged(window), window)
 
 
 @dataclass
@@ -87,15 +134,16 @@ class HostEvent:
 class Trace:
     devices: list[Device]
     host: list[HostEvent]
-    window: tuple[float, float]  # ns, trace clock
-    calls: int                   # sortbench.call annotations in the window
+    window: Intervals  # the calls' union: sorted, disjoint, ns, trace clock
+    calls: int         # sortbench.call annotations in the window
 
     @property
     def window_s(self) -> float:
-        return (self.window[1] - self.window[0]) * 1e-9
+        """The window's length: the calls' summed durations."""
+        return sum(e - s for s, e in self.window) * 1e-9
 
     def busy_s(self, dev: Device) -> float:
-        return dev.busy_ns(*self.window) * 1e-9
+        return dev.busy_ns(self.window) * 1e-9
 
     @property
     def busy_s_mean(self) -> float:
@@ -107,8 +155,7 @@ class Trace:
     def op_seconds(self, dev: Device, match) -> float:
         """Device seconds of the ops on ``dev`` whose HLO text ``match``
         accepts, inside the window."""
-        lo, hi = self.window
-        return sum(max(0.0, min(o.end, hi) - max(o.start, lo)) * 1e-9
+        return sum(overlap(o.start, o.end, self.window) * 1e-9
                    for o in dev.ops if match(o.name))
 
     def breakdown(self, top: int = 10) -> dict[str, list[list]]:
@@ -117,16 +164,15 @@ class Trace:
         innermost frame then running on that thread (its self time).
         The ``top`` largest of each, in seconds."""
         dev = self.busiest()
-        lo, hi = self.window
         ops: dict[str, float] = defaultdict(float)
         for o in dev.ops:
-            d = min(o.end, hi) - max(o.start, lo)
+            d = overlap(o.start, o.end, self.window)
             if d > 0:
                 ops[short_name(o.name)] += d * 1e-9
         idle: dict[str, float] = defaultdict(float)
         frames = self_intervals(self.host)
         i = 0
-        for s, e in dev.gaps(lo, hi):
+        for s, e in dev.gaps(self.window):
             covered = 0.0
             while i < len(frames) and frames[i][1] <= s:
                 i += 1
@@ -202,10 +248,10 @@ def reduce_file(path: str) -> Trace:
     if not devices:
         raise ValueError(f"sortbench: no device plane in {path}")
     if calls:
-        window = (min(c.start for c in calls), max(c.end for c in calls))
+        window = union((c.start, c.end) for c in calls)
     else:
         ops = [o for d in devices for o in d.ops]
-        window = (min(o.start for o in ops), max(o.end for o in ops))
+        window = [(min(o.start for o in ops), max(o.end for o in ops))]
     return Trace(devices, host, window, len(calls))
 
 

@@ -7,8 +7,11 @@ In order: JAX's persistent compile cache at the checkout's fixed path;
 the platform must be ``tpu`` with exactly the cell's ``chips`` devices
 (anything else exits 2 with no result); ``native/libencode.so`` is
 built if missing; the cell's key pool comes from ``--seed``; set-up
-sorts the pool's first array once; the window measures for ``--seconds``;
-every answer is then checked against the plain reference.  The last
+sorts the pool's first array once; the plain reference of each pool
+array is computed (after set-up is read, before the window); the window
+runs calls until their summed durations reach ``--seconds``, and each
+answer is checked against its reference as its call returns, outside
+the window, then dropped.  The last
 line of stdout is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, with ``--trace 1`` ``breakdown``,
 and last ``checks``: each compared number beside its limit).  The same

@@ -19,7 +19,9 @@ def trace():
 
 def test_window_is_the_annotated_calls(trace):
     assert trace.calls == 2
-    assert trace.window == (46440035.0, 63337084.0)
+    assert len(trace.window) == 2
+    assert (trace.window[0][0], trace.window[-1][1]) == (46440035.0,
+                                                        63337084.0)
     assert [d.name for d in trace.devices] == ["/device:TPU:0"]
 
 
@@ -28,7 +30,7 @@ def test_busy_union_and_idle_share(trace):
     assert len(dev.ops) == 86
     assert trace.busy_s(dev) == pytest.approx(0.000726896, rel=1e-9)
     assert trace.busy_s_mean == trace.busy_s(dev)
-    idle = sum(e - s for s, e in dev.gaps(*trace.window)) * 1e-9
+    idle = sum(e - s for s, e in dev.gaps(trace.window)) * 1e-9
     assert idle + trace.busy_s(dev) == pytest.approx(trace.window_s)
 
 
@@ -55,9 +57,9 @@ def test_breakdown_shape(trace):
 def test_merge_and_gaps_of_made_up_intervals():
     dev = reduce.Device("d", [reduce.Op(10, 20, "a"), reduce.Op(15, 30, "b"),
                               reduce.Op(40, 50, "c"), reduce.Op(0, 5, "d")])
-    assert dev.merged(2, 45) == [(2, 5), (10, 30), (40, 45)]
-    assert dev.busy_ns(2, 45) == 3 + 20 + 5
-    assert dev.gaps(0, 60) == [(5, 10), (30, 40), (50, 60)]
+    assert dev.merged([(2, 45)]) == [(2, 5), (10, 30), (40, 45)]
+    assert dev.busy_ns([(2, 45)]) == 3 + 20 + 5
+    assert dev.gaps([(0, 60)]) == [(5, 10), (30, 40), (50, 60)]
 
 
 def test_short_names():
@@ -86,3 +88,42 @@ def test_idle_time_split_by_frames(trace):
     idle = trace.window_s - trace.busy_s(trace.busiest())
     assert sum(v for _, v in b["idle_gaps"]) == pytest.approx(idle)
     assert b["idle_gaps"][0][0] == "array.py:631 _value"
+
+
+def test_nothing_between_two_calls_counts():
+    """Two calls, [0, 40) and [60, 100) ns, with the harness's check
+    between them: the op, the frame and the spans inside the gap count
+    toward no reading, and an op across a boundary counts its parts
+    inside the calls."""
+    from types import SimpleNamespace
+
+    from sortbench import progspans, spec
+
+    ev = reduce.HostEvent
+    verify = 'frontend_attributes={layer="verify"}'
+    trace = reduce.Trace(
+        devices=[reduce.Device("/device:TPU:0", [
+            reduce.Op(10, 20, "%a = s32[8] fusion()"),
+            reduce.Op(35, 65, "%s = s32[8] copy()"),
+            reduce.Op(45, 55, f"%g = s32[8] fusion(), {verify}"),
+            reduce.Op(80, 84, f"%v = s32[8] fusion(), {verify}")])],
+        host=[ev(0, 40, "$api.py:1 sort"), ev(0, 30, "phase:sort"),
+              ev(40, 60, "$harness.py:1 mismatches"),
+              ev(42, 58, "phase:verify"), ev(50, 55, "egress.fetch"),
+              ev(60, 100, "$api.py:1 sort"), ev(70, 80, "egress.fetch")],
+        window=[(0, 40), (60, 100)], calls=2)
+    run = SimpleNamespace(trace=trace)
+    assert trace.window_s == pytest.approx(80e-9)
+    assert trace.busy_s(trace.devices[0]) == pytest.approx(24e-9)
+    idle = spec.load_module(spec.HERE / "layers" / "device.idle_pct.py")
+    assert idle.read(run) == pytest.approx(70.0)
+    assert progspans.annotation_share_pct(run, "egress.fetch") == \
+        pytest.approx(12.5)
+    assert progspans.layer_share_pct(run, "verify") == pytest.approx(5.0)
+    # idle [0,10) [20,35) [65,80) [84,100); phase:sort covers [0,30)
+    assert progspans.idle_outside_pct(run) == pytest.approx(45.0)
+    b = trace.breakdown()
+    assert dict(b["device_ops"]) == pytest.approx(
+        {"%a fusion": 10e-9, "%s copy": 10e-9, "%v fusion": 4e-9})
+    assert dict(b["idle_gaps"]) == pytest.approx(
+        {"phase:sort": 20e-9, "api.py:1 sort": 26e-9, "egress.fetch": 10e-9})

@@ -41,7 +41,7 @@ def _made_up():
               ev(15, 20, "pair.plan"), ev(40, 55, "phase:encode"),
               ev(40, 50, "encode.words"), ev(50, 55, "pair.plan"),
               ev(0, 100, "$api.py:1 sort")],
-        window=(0, 100), calls=2)
+        window=[(0, 100)], calls=2)
 
 
 def test_roofline_of_made_up_ops():

@@ -47,11 +47,13 @@ from mpitest_tpu.models import radix_sort, sample_sort
 from mpitest_tpu.models import supervisor as supervision
 from mpitest_tpu.models import verify as vfy
 from mpitest_tpu.models.local import (
+    LocalSort,
     _f64_host_input,
     _f64_known_broken,
     _is_f64_lowering_gap,
     _local_engine,
     _resolve_local_engine,
+    decode_on_device,
     sort_one_device,
 )
 from mpitest_tpu.models.supervisor import (  # re-exported: public errors
@@ -90,16 +92,32 @@ class DistributedSortResult:
     dtype: np.dtype
     counts: np.ndarray | None = None  # per-shard valid counts (ragged layouts)
     shard_slots: int | None = None    # slots per shard for ragged layouts
+    #: the sorted keys already decoded on the device (one-device
+    #: results bound for the host; ``words`` is then empty): keys of the
+    #: caller's dtype, or 64-bit keys as rows of their uint32 halves
+    #: (``KeyCodec.decode_jax``).  The fetch lands the caller's bytes.
+    keys: jax.Array | None = None
 
     def to_numpy(self, tracer: "Tracer | None" = None) -> np.ndarray:
         """The sorted keys on the host.  With a tracer, the device→host
         copy and the decode record ``egress.fetch`` / ``egress.decode``
-        (the streamed path records its own, per shard)."""
+        (the streamed path records its own, per shard; keys decoded on
+        the device record the fetch alone, and come back read-only
+        where the fetch is a view of the device buffer, as on CPU)."""
         if self.n_valid == 0:
             return np.empty(0, self.dtype)
         codec = codec_for(self.dtype)
 
         stage = tracer.span if tracer is not None else _no_span
+        if self.keys is not None:
+            with stage("egress.fetch", bytes=int(self.keys.nbytes)):
+                host = np.asarray(self.keys)
+            if host.flags.owndata:
+                # a fresh host copy (the chip's fetch) is the caller's
+                # alone: JAX marks it read-only only because it caches
+                # it on `keys`, which is dropped with this result
+                host.flags.writeable = True
+            return host.view(self.dtype).reshape(-1)[: self.n_valid]
         fetched = sum(int(w.nbytes) for w in self.words)
 
         if self.counts is None:
@@ -966,6 +984,9 @@ def _sort_impl(
     eng0 = _resolve_exchange_engine(exchange_engine)
     _eng = {"v": eng0}
     tracer.counters["exchange_engine"] = eng0
+    # where the sorted words become the caller's keys: the host, unless
+    # the one-device finish decodes them on the device
+    tracer.counters["decode_site"] = "host"
     # ---- local-sort engine: the KNOB-level value (auto / bitonic /
     # lax); each dispatch resolves it per key width and size.
     leng0 = _local_engine()
@@ -1086,16 +1107,32 @@ def _sort_impl(
             plan.actual("planner", misses=1)
         return False
 
-    def _finish_local(res_l, fp_l, verified: bool):
+    def _finish_local(out: LocalSort):
         """Verify-and-return for the single-device paths.  No ladder
         here (the degradation machinery targets the distributed
         dispatch); a verification failure is a typed error.  A
-        ``verified`` result is the planner's passthrough hit."""
+        ``verified`` result is the planner's passthrough hit.
+
+        Results bound for the host whose codec is a constant XOR decode
+        on the device, and the verifier then reads the decoded keys: the
+        bytes the caller gets are the bytes it checked.  A passthrough
+        hit was checked as words, so it keeps the host decode.  ``out``
+        is the only reference to the sorted words, so they are freed
+        once the decode has read them."""
+        res_l = DistributedSortResult(out.words, N, dtype)
+        fp_l, verified = out.fingerprint, out.verified
+        del out
         if plan is not None and not verified:
             # single-device runs have no distributed ladder: the rung
             # is the fused local path itself (the engine decision is
             # defaulted from the counters at _finish_plan time)
             plan.decide("ladder", chosen="local")
+        if not (return_result or verified or codec.raw_bias is None):
+            tracer.counters["decode_site"] = "device"
+            with tracer.span("phase:decode"), tracer.span("egress.decode"):
+                keys = decode_on_device(res_l.words, codec,
+                                        _donation_enabled(), tracer)
+            res_l = DistributedSortResult((), N, dtype, keys=keys)
         if verify_on and not verified and not _check_result(res_l, fp_l):
             raise SortIntegrityError(
                 "single-device sort result failed verification")
@@ -1109,11 +1146,9 @@ def _sort_impl(
         # (models/local.py).  The passthrough reaches only host input:
         # device-resident and staged inputs take no host profile, so the
         # scorer already chose `static` for them.
-        out = sort_one_device(
+        return _finish_local(sort_one_device(
             x, codec, N, leng0, mesh.devices.flat[0], tracer, verify_on,
-            passthrough=_passthrough if passthrough_on else None)
-        return _finish_local(DistributedSortResult(out.words, N, dtype),
-                             out.fingerprint, out.verified)
+            passthrough=_passthrough if passthrough_on else None))
 
     #: per-word max^min already known without touching the data again
     #: (streamed ingest folds it chunk-by-chunk); None = plan from

@@ -23,7 +23,9 @@ the codec, ``N``, the local-engine knob and the backend — and
 Both device routes degrade to ``host`` on the known float64 lowering gap.
 For 2-word keys on the pair engine, ``raw`` and ``host`` share one
 plan → engine step (:func:`_pair_by_plan`).  The caller
-(``models/api.py``) verifies the returned words and decodes them.
+(``models/api.py``) verifies the returned words and decodes them; for
+the constant-XOR codecs the decode runs on the device
+(:func:`decode_on_device`) and the verifier reads the decoded keys.
 """
 
 from __future__ import annotations
@@ -193,6 +195,34 @@ def _compile_local_device(dtype_name: str,
         return kernels.local_sort(words, engine=eng)
 
     return jax.jit(program("local_device_sort", "local_sort", f))
+
+
+@lru_cache(maxsize=8)
+def _compile_decode(dtype_name: str, donate: bool) -> Callable[..., Any]:
+    """The caller's keys from the sorted words
+    (:meth:`KeyCodec.decode_jax`), in a program of its own: the
+    verifier re-encodes the keys in another, so it checks the bytes the
+    fetch hands back (inside one program XLA would fold
+    ``encode(decode(w))`` back to ``w``).  ``donate``: 1-word keys take
+    the words' buffer, so the device peak does not grow."""
+    codec = codec_for(np.dtype(dtype_name))
+
+    def f(*words):
+        return codec.decode_jax(words)
+
+    return jax.jit(program("decode", "egress", f),
+                   donate_argnums=(0,) if donate and codec.n_words == 1
+                   else ())
+
+
+def decode_on_device(words: Words, codec: KeyCodec, donate: bool,
+                     tracer: Tracer) -> jax.Array:
+    """Dispatch the device decode of the sorted ``words`` (a
+    constant-XOR codec's) into the caller's keys, or rows of the native
+    bytes of 64-bit keys.  ``donate`` gives 1-word words to the
+    program, which frees them."""
+    return traced_call(tracer, "decode",
+                       _compile_decode(codec.dtype.name, donate), *words)
 
 
 @lru_cache(maxsize=4)

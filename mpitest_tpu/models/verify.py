@@ -39,6 +39,7 @@ corruption).
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from functools import lru_cache
 from typing import TYPE_CHECKING, Callable
@@ -192,31 +193,63 @@ def _compile_contig(n_words: int, n_valid: int, total: int,
     slices, no index arrays, so there is nothing to overflow at any
     scale (the int32-iota hazard of ADVICE r3 #1 never arises)."""
     import jax
-    import jax.numpy as jnp
 
     def f(*words):
-        xors, sums = [], []
-        for w in words:
-            pad = w[n_valid:total]
-            xors.append(_xor_reduce_1d(w) ^ _xor_reduce_1d(pad))
-            sums.append(jnp.sum(w, dtype=jnp.uint32)
-                        - jnp.sum(pad, dtype=jnp.uint32))
-        if not check_sorted:
-            return jnp.ones((), bool), tuple(xors), tuple(sums)
-        # lexicographic adjacency over the full array (pads = max extend
-        # the order, so they never mask a violation among real keys):
-        # pair ok iff the first differing word (msw first) increases,
-        # or all words tie.
-        lt = jnp.zeros((max(total - 1, 0),), bool)
-        eq = jnp.ones_like(lt)
-        for w in words:
-            a, b = w[:-1], w[1:]
-            lt = lt | (eq & (a < b))
-            eq = eq & (a == b)
-        ok = jnp.all(lt | eq)
-        return ok, tuple(xors), tuple(sums)
+        return _contig(words, n_valid, total, check_sorted)
 
     return jax.jit(program("verify_contig", "verify", f))
+
+
+def _contig(words: "tuple[jax.Array, ...]", n_valid: int, total: int,
+            check_sorted: bool) -> "tuple[jax.Array, tuple, tuple]":
+    """The body of :func:`_compile_contig`: ``(ok, xors, sums)``."""
+    import jax.numpy as jnp
+
+    xors, sums = [], []
+    for w in words:
+        pad = w[n_valid:total]
+        xors.append(_xor_reduce_1d(w) ^ _xor_reduce_1d(pad))
+        sums.append(jnp.sum(w, dtype=jnp.uint32)
+                    - jnp.sum(pad, dtype=jnp.uint32))
+    if not check_sorted:
+        return jnp.ones((), bool), tuple(xors), tuple(sums)
+    # lexicographic adjacency over the full array (pads = max extend
+    # the order, so they never mask a violation among real keys):
+    # pair ok iff the first differing word (msw first) increases,
+    # or all words tie.
+    lt = jnp.zeros((max(total - 1, 0),), bool)
+    eq = jnp.ones_like(lt)
+    for w in words:
+        a, b = w[:-1], w[1:]
+        lt = lt | (eq & (a < b))
+        eq = eq & (a == b)
+    return jnp.all(lt | eq), tuple(xors), tuple(sums)
+
+
+@lru_cache(maxsize=64)
+def _compile_keys_contig(dtype_name: str, n_valid: int,
+                         total: int) -> "Callable[..., object]":
+    """:func:`_compile_contig`'s check of a contiguous result decoded on
+    the device (``DistributedSortResult.keys``), with the encode
+    (``codec.encode_jax``) as its prologue: the words it checks are
+    re-derived from the bytes the caller gets.  64-bit keys (rows of
+    their uint32 halves, ``KeyCodec.decode_jax``) are bitcast to
+    ``dtype`` first, so the program is traced and called under
+    ``jax_enable_x64``."""
+    import jax
+
+    from mpitest_tpu.ops.keys import codec_for
+
+    codec = codec_for(np.dtype(dtype_name))
+
+    def f(keys):
+        if keys.ndim == 2:
+            keys = jax.lax.bitcast_convert_type(
+                keys.reshape(keys.shape[0], -1, 2), codec.dtype)
+        words = tuple(w.reshape(-1) for w in codec.encode_jax(keys))
+        return _contig(words, n_valid, total, True)
+
+    return jax.jit(program("verify_keys", "verify", f))
 
 
 @lru_cache(maxsize=64)
@@ -348,26 +381,38 @@ def verify_result(res: "DistributedSortResult",
                   tracer: "Tracer") -> tuple[bool, bool]:
     """Verify a DistributedSortResult on device: returns
     ``(sorted_ok, fp_ok)``.  ``fp_ok`` is True when no input fingerprint
-    is available (nothing to compare — sortedness still gates).  The
-    programs run under ``tracer``'s compile-vs-execute spans."""
+    is available (nothing to compare — sortedness still gates).  A
+    result decoded on the device is checked through its keys
+    (:func:`_compile_keys_contig`).  The programs run under
+    ``tracer``'s compile-vs-execute spans."""
     n_words = len(res.words)
-    if res.counts is None:
+    if res.keys is not None:
+        import jax
+
+        rows = res.keys.ndim == 2
+        total = int(res.keys.size) // (2 if rows else 1)
+        with jax.enable_x64(True) if rows else contextlib.nullcontext():
+            ok, xors, sums = traced_call(
+                tracer, "verify_keys",
+                _compile_keys_contig(np.dtype(res.dtype).name,
+                                     min(res.n_valid, total), total),
+                res.keys)
+        n_in = res.n_valid
+    elif res.counts is None:
         total = int(res.words[0].shape[0])
         ok, xors, sums = traced_call(
             tracer, "verify_contig",
             _compile_contig(n_words, min(res.n_valid, total), total, True),
             *res.words)
-        out_fp = Fingerprint(res.n_valid,
-                             tuple(int(x) for x in xors),
-                             tuple(int(s) for s in sums))
+        n_in = res.n_valid
     else:
         n_ranks = len(res.counts)
         ok, n_in, xors, sums = traced_call(
             tracer, "verify_ragged",
             _compile_ragged(n_words, res.n_valid, res.shard_slots, n_ranks),
             np.asarray(res.counts, np.int32), *res.words)
-        out_fp = Fingerprint(int(n_in),
-                             tuple(int(x) for x in xors),
-                             tuple(int(s) for s in sums))
+    out_fp = Fingerprint(int(n_in),
+                         tuple(int(x) for x in xors),
+                         tuple(int(s) for s in sums))
     fp_ok = input_fp is None or out_fp == input_fp
     return bool(ok), fp_ok

@@ -45,6 +45,9 @@ def _halves(x: np.ndarray) -> np.ndarray:
 #: All-ones uint32 word — exchange-lane fill that sorts to the tail.
 MAX_WORD = 0xFFFFFFFF
 
+#: 64-bit keys per row of :meth:`KeyCodec.decode_jax`'s output
+DECODE_ROW = 128
+
 
 @dataclass(frozen=True)
 class KeyCodec:
@@ -180,6 +183,35 @@ class KeyCodec:
                 hi = hi2
             return (hi, lo)
         raise TypeError(f"device-side encode unsupported for {self.dtype}")
+
+    def decode_jax(self, words):
+        """Device-side decode of the constant-XOR codecs (``raw_bias``),
+        the inverse of :meth:`encode_jax`: each word XOR its bias.  One
+        word is then bitcast to the key dtype.
+
+        Two words come back as the native bytes of the 64-bit keys:
+        their uint32 halves in host byte order, :data:`DECODE_ROW` keys
+        to a row (``[ceil(N / DECODE_ROW), 2 * DECODE_ROW]``, the tail
+        padded with the maximum key), which the host views as ``dtype``
+        with no pass over them.  On a v5e a 64-bit array fetches about
+        4.5 s per GiB, an ``[N, 2]`` array lands as two planes and a
+        flat ``[2N]`` one does not compile (its interleave would pad
+        64-fold); these rows fetch in about 0.32 s per GiB."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        if self.raw_bias is None:
+            raise TypeError(f"device-side decode unsupported for {self.dtype}")
+        if self.n_words == 1:
+            return lax.bitcast_convert_type(
+                words[0] ^ jnp.uint32(self.raw_bias[0]), self.dtype)
+        pad = (0, -words[0].shape[0] % DECODE_ROW)
+        fill = jnp.uint32(MAX_WORD)
+        halves = [None, None]
+        halves[_HI], halves[_LO] = (
+            jnp.pad(v, pad, constant_values=fill).reshape(-1, DECODE_ROW)
+            ^ jnp.uint32(b) for v, b in zip(words, self.raw_bias))
+        return jnp.stack(halves, -1).reshape(-1, 2 * DECODE_ROW)
 
     def max_sentinel(self) -> tuple[int, ...]:
         """Word values that encode the maximum representable key (sorts

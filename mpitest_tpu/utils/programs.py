@@ -27,7 +27,7 @@ import threading
 from typing import Any, Callable
 
 #: the layers an op may be tagged with (PERF.md §3)
-LAYERS = ("local_sort", "exchange", "verify", "ingest")
+LAYERS = ("local_sort", "exchange", "verify", "ingest", "egress")
 
 #: JAX's monitoring event for one XLA backend compile or persistent-cache
 #: load (``jax._src.dispatch.BACKEND_COMPILE_EVENT``)

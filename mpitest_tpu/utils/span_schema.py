@@ -142,7 +142,8 @@ SPAN_NAMES: dict[str, str] = {
     "ingest.transfer": "host→device DMA of one chunk's shard pieces",
     "ingest.pipeline": "whole streamed-ingest wall interval",
     "egress.fetch": "device→host fetch of one result shard",
-    "egress.decode": "codec-decode one fetched shard",
+    "egress.decode": ("codec-decode one fetched shard, or dispatch the "
+                      "device decode of a one-device result"),
 }
 
 #: Ingest/egress stage split used by the report overlap tables: host-side

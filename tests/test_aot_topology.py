@@ -151,6 +151,28 @@ def test_pair_split_2p27_one_chip(one_chip, dtype):
     assert c.memory_analysis().temp_size_in_bytes <= 1 << 20
 
 
+@pytest.mark.parametrize("dtype", ["int32", "uint32", "int64", "uint64"])
+def test_device_decode_and_keys_verifier_2p27_one_chip(one_chip, dtype):
+    """The egress decode and the verifier of the decoded keys at 2^27:
+    both lower, a 1-word decode writes into the donated words' buffer,
+    and 64-bit keys come out as rows of their uint32 halves, with no
+    padded interleave (a flat ``[2N]`` one asks for 64 GiB)."""
+    from mpitest_tpu.models import verify
+    from mpitest_tpu.models.local import _compile_decode
+
+    n, two = 1 << 27, dtype.endswith("64")
+    w = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
+    c = _compile_decode(dtype, True).lower(*(w,) * (1 + two)).compile()
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes == (0 if two else 4 * n)
+    assert m.temp_size_in_bytes <= (16 if two else 0) * n
+    keys = jax.ShapeDtypeStruct((n // 128, 256) if two else (n,),
+                                jnp.uint32 if two else jnp.dtype(dtype),
+                                sharding=one_chip)
+    with jax.enable_x64(two):
+        assert verify._compile_keys_contig(dtype, n, n).lower(keys).compile()
+
+
 def test_bitonic_kernels_carry_names_and_layer(one_chip):
     """Every 1-word bitonic kernel is named (``bitonic.*``) and tagged
     ``layer="local_sort"``, so the chip trace can tell it apart; no

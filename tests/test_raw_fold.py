@@ -64,6 +64,13 @@ N_PAIR = 1 << 14
 #: what _finish_local adds after every route: verify, then decode
 FINISH = ["phase:verify", "verify.check", "verify_contig",
           "phase:decode", "egress.fetch", "egress.decode"]
+#: ... and for the constant-XOR codecs: the device decode's dispatch,
+#: the verifier on the decoded keys, then the fetch alone
+DEVICE_FINISH = ["phase:decode", "egress.decode", "decode",
+                 "phase:verify", "verify.check", "verify_keys",
+                 "phase:decode", "egress.fetch"]
+#: the dtypes whose one-device results decode on the device
+DEVICE_DECODED = (np.int32, np.uint32, np.int64, np.uint64)
 #: the route's span and program order, before FINISH (program calls
 #: appear as their traced_call labels)
 SPANS = {
@@ -172,7 +179,10 @@ def test_one_device_host_input_encode_site(dtype, site, engine, route,
     np.testing.assert_array_equal(got, np.sort(x))
     assert tr.counters["encode_site"] == encode_site
     assert tr.counters["local_engine"] == local_engine
-    assert _span_order(tr) == SPANS[route] + FINISH
+    device = dtype in DEVICE_DECODED
+    assert tr.counters["decode_site"] == ("device" if device else "host")
+    assert _span_order(tr) == SPANS[route] + (DEVICE_FINISH if device
+                                              else FINISH)
 
 
 def test_local_module_imports_point_one_way():
@@ -237,7 +247,8 @@ def test_host_pair_plan_picks_the_engine(case, engine, sort):
 @pytest.mark.parametrize("hit", [True, False])
 def test_planner_passthrough_on_one_device(hit):
     """The planner's passthrough takes the host route: one verify of the
-    put words, then the decode on a hit, or the sort on a miss."""
+    put words, then the host decode of those verified words on a hit, or
+    the sort and the device decode, verified as keys, on a miss."""
     from mpitest_tpu.parallel.mesh import make_mesh
 
     x = np.arange(1 << 13, dtype=np.int32)
@@ -253,11 +264,14 @@ def test_planner_passthrough_on_one_device(hit):
     if hit:
         assert tr.counters["planner_passthrough"] == 1
         assert tr.plan.decisions["ladder"].chosen == "passthrough"
+        assert tr.counters["decode_site"] == "host"
         assert _span_order(tr) == host + check + FINISH[3:]
     else:
         assert tr.counters["planner_passthrough_miss"] == 1
         assert tr.plan.decisions["ladder"].chosen == "local"
-        assert _span_order(tr) == host + check + SPANS["host"][-2:] + FINISH
+        assert tr.counters["decode_site"] == "device"
+        assert _span_order(tr) == (host + check + SPANS["host"][-2:]
+                                   + DEVICE_FINISH)
 
 
 def test_raw_route_fold_covers_the_transfer(monkeypatch):

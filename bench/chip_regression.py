@@ -117,7 +117,7 @@ def main() -> int:
 
     @jax.jit
     def pair_agree(h, l):
-        hs, ls, bad = kernels.sort_two_words_bitonic(h, l)
+        hs, ls, bad, _ = kernels.sort_two_words_bitonic(h, l)
         ref = jax.lax.sort([h, l], num_keys=2, is_stable=False)
         return jnp.all(hs == ref[0]) & jnp.all(ls == ref[1]) & ~bad
 

@@ -73,7 +73,7 @@ def main() -> int:
 
     @jax.jit
     def sort_words(h, l):
-        hs, ls, bad = kernels.sort_two_words_bitonic(h, l)
+        hs, ls, bad, _ = kernels.sort_two_words_bitonic(h, l)
         hs, ls = jax.lax.cond(
             bad,
             lambda a, b: tuple(jax.lax.sort([a, b], num_keys=2,

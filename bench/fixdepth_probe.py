@@ -85,7 +85,7 @@ def main() -> int:
         """The b_pair branch shape: pair path at ``passes``, residual ->
         on-device lax fallback (what the fused jit runs)."""
         def f(h, l):
-            hs, ls, bad = kernels.sort_two_words_bitonic(
+            hs, ls, bad, _ = kernels.sort_two_words_bitonic(
                 h, l, fix_passes=passes)
             hs, ls = jax.lax.cond(
                 bad, lambda a, b: tuple(jax.lax.sort([a, b], num_keys=2,
@@ -117,7 +117,7 @@ def main() -> int:
         def make_check(passes):
             @jax.jit
             def check(h, l):
-                hs, ls, bad = kernels.sort_two_words_bitonic(
+                hs, ls, bad, _ = kernels.sort_two_words_bitonic(
                     h, l, fix_passes=passes)
                 ref = jax.lax.sort([h, l], num_keys=2, is_stable=False)
                 return jnp.all(hs == ref[0]) & jnp.all(ls == ref[1]), bad

@@ -227,10 +227,14 @@ def decode_on_device(words: Words, codec: KeyCodec, donate: bool,
 
 @lru_cache(maxsize=4)
 def _compile_pair_sort(impl: str) -> Callable[..., Any]:
+    """The pair engine on 2-word keys: the sorted words and int32
+    ``[residual, hi ties]``, so one readback fetches both."""
     interpret = impl == "bitonic_interpret"
 
     def f(hi, lo):
-        return kernels.sort_two_words_bitonic(hi, lo, interpret=interpret)
+        hs, ls, bad, ties = kernels.sort_two_words_bitonic(
+            hi, lo, interpret=interpret)
+        return hs, ls, jnp.stack([bad.astype(jnp.int32), ties])
 
     return jax.jit(program("pair_sort", "local_sort", f))
 
@@ -322,7 +326,7 @@ def _compile_pair_fused(dtype_name: str,
             return hs, ls, jnp.int32(3)
 
         def b_pair(h, l):
-            hs, ls, bad = kernels.sort_two_words_bitonic(
+            hs, ls, bad, _ = kernels.sort_two_words_bitonic(
                 h, l, interpret=interpret)
             hs, ls = jlax.cond(bad, lax2w, lambda a, b: (hs, ls), h, l)
             return hs, ls, jnp.where(bad, jnp.int32(5), jnp.int32(4))
@@ -567,7 +571,9 @@ def _pair_by_plan(words: Words, rng: Any, dups: Any,
     3. pair engine (``kernels.sort_two_words_bitonic``): key+payload
        bitonic by hi + segmented odd-even run fix-up.  The residual flag
        (runs the sniff missed) falls back to ``lax.sort`` — correctness
-       never depends on the sniff.
+       never depends on the sniff.  Its readback (span ``pair.residual``)
+       also brings the count of equal adjacent hi words of the output
+       (counter ``pair_hi_ties``).
 
     ``rng`` is ``[min hi, max hi, min lo, max lo]``; ``dups`` the sniff
     count."""
@@ -595,10 +601,12 @@ def _pair_by_plan(words: Words, rng: Any, dups: Any,
                                          _compile_local(2, "lax"), *words), fp)
     tracer.counters["local_engine"] = "bitonic_pair"
     with tracer.span("phase:sort"):
-        hi_s, lo_s, bad = traced_call(tracer, "pair_sort",
-                                      _compile_pair_sort(_bitonic_impl()),
-                                      *words)
-        bad = bool(bad)
+        hi_s, lo_s, flags = traced_call(tracer, "pair_sort",
+                                        _compile_pair_sort(_bitonic_impl()),
+                                        *words)
+        with tracer.span("pair.residual"):
+            bad, ties = (int(v) for v in jax.device_get(flags))
+    tracer.counters["pair_hi_ties"] = ties
     if bad:
         tracer.verbose(
             "pair engine left residual runs (hi duplication the sniff "

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mpitest_tpu.utils.programs import tagged
+from mpitest_tpu.utils.programs import stage, tagged
 
 Words = tuple[jax.Array, ...]
 
@@ -58,7 +58,8 @@ def local_sort(words: Words, engine: str = "lax") -> Words:
         # cond alone guarantees correctness for any duplication.
         interpret = engine == "bitonic_interpret"
         hi, lo = words
-        hi_s, lo_s, bad = sort_two_words_bitonic(hi, lo, interpret=interpret)
+        hi_s, lo_s, bad, _ = sort_two_words_bitonic(hi, lo,
+                                                    interpret=interpret)
 
         def _lax2w(h, l):
             out = lax.sort([h, l], num_keys=2, is_stable=False)
@@ -155,7 +156,10 @@ def sort_two_words_bitonic(hi: jax.Array, lo: jax.Array,
     path (2.7x); 16 -> 32 costs +9% always for the narrower 17..32
     class.  16 is the shipped default (VERDICT r4 weak #3 mid-tier).
 
-    Returns ``(hi_sorted, lo_sorted, residual)``.
+    The fix-up phase and its reduction carry ``stage="pair_fixup"``.
+    Returns ``(hi_sorted, lo_sorted, residual, hi_ties)``: ``hi_ties``
+    is the int32 count of equal adjacent hi words of the output, the
+    work the fix-up phase has.
     """
     from mpitest_tpu.ops import bitonic  # local import: optional path
 
@@ -166,7 +170,8 @@ def sort_two_words_bitonic(hi: jax.Array, lo: jax.Array,
     # shapes lose to lax.sort's exact-n cost
     if n < (1 << bitonic.MIN_SORT_LOG2) or n * 10 < n_pow2 * 6:
         out = lax.sort([hi, lo], num_keys=2, is_stable=False)
-        return out[0], out[1], jnp.zeros((), bool)
+        ties = jnp.sum(out[0][1:] == out[0][:-1], dtype=jnp.int32)
+        return out[0], out[1], jnp.zeros((), bool), ties
     b_log2 = min(bitonic.PAIR_BLOCK_LOG2, t)
     if n_pow2 != n:
         # (max, max) pad pairs sort to the global tail; real elements
@@ -178,11 +183,18 @@ def sort_two_words_bitonic(hi: jax.Array, lo: jax.Array,
         lo = jnp.concatenate([lo, pad])
     hi_s, lo_r = bitonic.sort_pairs_padded(hi, lo, n_pow2, b_log2,
                                            interpret=interpret)
-    lo_s = bitonic.fix_runs_pairs(hi_s, lo_r, fix_passes, b_log2,
-                                  interpret=interpret)
-    lo_s = _fix_boundary(hi_s, lo_s, fix_passes, 1 << b_log2)
-    residual = jnp.any((hi_s[1:] == hi_s[:-1]) & (lo_s[1:] < lo_s[:-1]))
-    return hi_s[:n], lo_s[:n], residual
+    with stage("pair_fixup"):
+        lo_s = bitonic.fix_runs_pairs(hi_s, lo_r, fix_passes, b_log2,
+                                      interpret=interpret)
+        lo_s = _fix_boundary(hi_s, lo_s, fix_passes, 1 << b_log2)
+        same = hi_s[1:] == hi_s[:-1]
+        # two int32 sums over the same compare fuse into one reduction
+        # (an ``any`` beside a sum does not); the pad pairs sort to the
+        # tail, so ties among them are not the output's
+        residual = jnp.sum(same & (lo_s[1:] < lo_s[:-1]),
+                           dtype=jnp.int32) > 0
+        ties = jnp.sum(same[:n - 1], dtype=jnp.int32)
+    return hi_s[:n], lo_s[:n], residual, ties
 
 
 def digit_at(word: jax.Array, shift: int, bits: int) -> jax.Array:

@@ -10,7 +10,9 @@ Three things the device trace needs from the program, in one place:
   shows it (``frontend_attributes={...,layer="verify"}``).  Inner tags
   override outer ones, so a program tagged ``exchange`` that calls the
   local sort keeps ``local_sort`` on the sort's ops.  The values are the
-  layers of PERF.md: :data:`LAYERS`.
+  layers of PERF.md: :data:`LAYERS`.  :func:`stage` adds a second
+  attribute, ``stage=<name>``, to a part of one layer's program that a
+  metric reads on its own (:data:`STAGES`); the ``layer`` tag stays.
 * **Compile truth.**  :func:`traced_call` runs one jitted call under a
   ``jit_execute`` span and renames it ``jit_compile_execute`` when JAX
   reported a backend compile (or a persistent-cache load) on the calling
@@ -29,6 +31,11 @@ from typing import Any, Callable
 #: the layers an op may be tagged with (PERF.md §3)
 LAYERS = ("local_sort", "exchange", "verify", "ingest", "egress")
 
+#: the stages an op may be tagged with inside its layer (PERF.md §3):
+#: ``pair_fixup`` is the pair engine's run fix-up and its residual and
+#: high-word tie reduction
+STAGES = ("pair_fixup",)
+
 #: JAX's monitoring event for one XLA backend compile or persistent-cache
 #: load (``jax._src.dispatch.BACKEND_COMPILE_EVENT``)
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -45,6 +52,16 @@ def layer(name: str) -> Any:
     from jax.experimental.xla_metadata import set_xla_metadata
 
     return set_xla_metadata(layer=name)
+
+
+def stage(name: str) -> Any:
+    """Context manager: tag every op traced inside with ``stage=<name>``
+    beside the ``layer`` tag it already carries."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; use one of {STAGES}")
+    from jax.experimental.xla_metadata import set_xla_metadata
+
+    return set_xla_metadata(stage=name)
 
 
 def tagged(layer_name: str) -> Callable[[Callable[..., Any]],

@@ -75,6 +75,10 @@ SPAN_NAMES: dict[str, str] = {
                   "for integer keys the readback of the device split's "
                   "plan (holding the transfer the fold leaves uncovered), "
                   "inside phase:encode"),
+    # models/local.py — the part of phase:sort on the pair engine
+    "pair.residual": ("readback of the pair engine's residual flag and "
+                      "high-word tie count, inside phase:sort (holds the "
+                      "wait for the pair sort)"),
     # scale-out vocabulary (ISSUE 7)
     "exchange_balance": ("negotiated exchange capacity + per-rank "
                          "send/recv byte balance (host count probe)"),

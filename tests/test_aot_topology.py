@@ -126,6 +126,36 @@ def test_pair_engine_2p26_one_chip(one_chip):
     assert _has_kernel(c)
 
 
+def test_pair_sort_2p28_one_chip(one_chip):
+    """The one-chip route's pair engine program (``pair_sort``) at
+    2 x 2^28 words, the shape of ``uniform_i64.1chip``: it compiles with
+    about one more copy of the words in temporaries; the run fix-up
+    kernel carries ``stage="pair_fixup"`` beside ``layer="local_sort"``
+    and the pair network does not; the residual and the high-word tie
+    count come out of one tagged reduction."""
+    import re
+
+    from mpitest_tpu.models.local import _compile_pair_sort
+
+    n = 1 << 28
+    x = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
+    lowered = _compile_pair_sort("bitonic").lower(x, x)
+    lines = lowered.as_text().splitlines()
+    fix = [ln for ln in lines if 'kernel_name = "bitonic.fix_runs"' in ln]
+    assert fix and all('stage = "pair_fixup"' in ln
+                       and 'layer = "local_sort"' in ln for ln in fix)
+    net = [ln for ln in lines if "tpu_custom_call" in ln
+           and 'kernel_name = "bitonic.fix_runs"' not in ln]
+    assert net and not any("pair_fixup" in ln for ln in net)
+    c = lowered.compile()
+    assert _has_kernel(c)
+    assert c.memory_analysis().temp_size_in_bytes <= 8 * n + (1 << 24)
+    sums = r"= \(s32\[\]\{[^}]*\}, s32\[\]\{[^}]*\}\) fusion\("
+    two_sums = re.compile(sums)
+    assert [ln for ln in c.as_text().splitlines()
+            if two_sums.search(ln) and 'stage="pair_fixup"' in ln]
+
+
 def test_pair_local_fused_one_chip(one_chip):
     """The fused device-resident 64-bit program (encode + range + sniff
     + lax.cond over 1-word engine / lax / pair engine): every cond
@@ -151,6 +181,17 @@ def test_pair_split_2p27_one_chip(one_chip, dtype):
     assert c.memory_analysis().temp_size_in_bytes <= 1 << 20
 
 
+def test_pair_split_2p28_one_chip(one_chip):
+    """The split and plan of ``uniform_i64.1chip``'s 2^28 raw int64 keys
+    (2 GiB in): no interleaved temporary at this size either."""
+    from mpitest_tpu.models.local import _compile_pair_split
+
+    with jax.enable_x64(True):
+        x = jax.ShapeDtypeStruct((1 << 28,), jnp.int64, sharding=one_chip)
+        c = _compile_pair_split("int64").lower(x).compile()
+    assert c.memory_analysis().temp_size_in_bytes <= 1 << 20
+
+
 @pytest.mark.parametrize("dtype", ["int32", "uint32", "int64", "uint64"])
 def test_device_decode_and_keys_verifier_2p27_one_chip(one_chip, dtype):
     """The egress decode and the verifier of the decoded keys at 2^27:
@@ -171,6 +212,26 @@ def test_device_decode_and_keys_verifier_2p27_one_chip(one_chip, dtype):
                                 sharding=one_chip)
     with jax.enable_x64(two):
         assert verify._compile_keys_contig(dtype, n, n).lower(keys).compile()
+
+
+def test_int64_decode_and_keys_verifier_2p28_one_chip(one_chip):
+    """``uniform_i64.1chip``'s egress at 2^28 int64 keys: the decode into
+    ``[N/128, 256]`` rows with at most 16 B a key of temporaries, and the
+    verifier of those rows, each within half of one v5e's 16 GB."""
+    from mpitest_tpu.models import verify
+    from mpitest_tpu.models.local import _compile_decode
+
+    n = 1 << 28
+    w = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
+    c = _compile_decode("int64", True).lower(w, w).compile()
+    rows = jax.ShapeDtypeStruct((n // 128, 256), jnp.uint32,
+                                sharding=one_chip)
+    assert c.memory_analysis().temp_size_in_bytes <= 16 * n
+    with jax.enable_x64(True):
+        v = verify._compile_keys_contig("int64", n, n).lower(rows).compile()
+    for m in (c.memory_analysis(), v.memory_analysis()):
+        assert (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes) <= 8 << 30
 
 
 def test_bitonic_kernels_carry_names_and_layer(one_chip):

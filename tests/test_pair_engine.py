@@ -139,7 +139,8 @@ def test_property_sort_two_words_contract(data):
     exact lexicographic sort with residual=False, or residual=True;
     the pair multiset is preserved in every case, and residual=False
     is GUARANTEED when all runs are <= fix_passes (16).  The
-    correctness contract the api fallback relies on."""
+    correctness contract the api fallback relies on.  The tie count is
+    NumPy's count of equal adjacent hi words in every case."""
     import jax.numpy as jnp
 
     from mpitest_tpu.ops import bitonic, kernels
@@ -165,12 +166,14 @@ def test_property_sort_two_words_contract(data):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(bitonic, "MIN_SORT_LOG2", 8)
         mp.setattr(bitonic, "PAIR_BLOCK_LOG2", 9)
-        hs, ls, bad = kernels.sort_two_words_bitonic(
+        hs, ls, bad, ties = kernels.sort_two_words_bitonic(
             jnp.asarray(hi), jnp.asarray(lo), interpret=True)
     hs, ls, bad = np.asarray(hs), np.asarray(ls), bool(bad)
     key_in = (hi.astype(np.uint64) << 32) | lo
     key_out = (hs.astype(np.uint64) << 32) | ls
     np.testing.assert_array_equal(np.sort(key_out), np.sort(key_in))
+    hi_sorted = np.sort(hi)
+    assert int(ties) == np.count_nonzero(hi_sorted[1:] == hi_sorted[:-1])
     if max(lens) <= 16:  # the round-5 default fix depth
         assert not bad
     if not bad:

@@ -78,20 +78,21 @@ SPANS = {
             "phase:verify", "verify.fingerprint"],
     "raw_pair": ["phase:device_put", "phase:verify", "verify.fingerprint",
                  "phase:encode", "encode.words", "pair_split",
-                 "phase:encode", "pair.plan", "phase:sort", "pair_sort"],
+                 "phase:encode", "pair.plan", "phase:sort", "pair_sort",
+                 "pair.residual"],
     "host": ["phase:encode", "encode.words", "phase:verify",
              "verify.fingerprint", "phase:device_put", "phase:sort",
              "local"],
     "host_pair": ["phase:encode", "encode.words", "pair.plan",
                   "phase:verify", "verify.fingerprint", "phase:device_put",
-                  "phase:sort", "pair_sort"],
+                  "phase:sort", "pair_sort", "pair.residual"],
     "device": ["verify_encode_fp", "phase:sort", "local_device"],
     "device_pair": ["verify_encode_fp", "phase:sort", "pair_fused"],
     "staged": ["phase:sort", "local"],
 }
 #: the children of the phases, as the span log names them
 CHILDREN = {"verify.fingerprint", "verify.check", "encode.words",
-            "pair.plan", "egress.fetch", "egress.decode"}
+            "pair.plan", "pair.residual", "egress.fetch", "egress.decode"}
 
 
 def _route_cells():
@@ -226,7 +227,8 @@ def _f64_plan_class(case: str) -> np.ndarray:
     ("const_hi", "bitonic_1w1", ["phase:sort", "local_1w"]),
     ("const_lo", "bitonic_1w0", ["phase:sort", "local_1w"]),
     ("dup_hi", "lax", ["phase:sort", "local_2w_lax"]),
-    ("uniform", "bitonic_pair", ["phase:sort", "pair_sort"]),
+    ("uniform", "bitonic_pair", ["phase:sort", "pair_sort",
+                                 "pair.residual"]),
 ])
 def test_host_pair_plan_picks_the_engine(case, engine, sort):
     """Host-planned 2-word keys (float64 on the pair engine) take the
@@ -241,7 +243,7 @@ def test_host_pair_plan_picks_the_engine(case, engine, sort):
     np.testing.assert_array_equal(got, np.sort(x))
     assert tr.counters["local_engine"] == engine
     assert tr.counters.get("pair_dup_reroute", 0) == (engine == "lax")
-    assert _span_order(tr) == SPANS["host_pair"][:-2] + sort + FINISH
+    assert _span_order(tr) == SPANS["host_pair"][:-3] + sort + FINISH
 
 
 @pytest.mark.parametrize("hit", [True, False])
